@@ -48,24 +48,15 @@ import json
 import os
 import time
 
-from bench_util import archive_rows
+from bench_util import archive_rows, emit_row
 
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
-_flags = os.environ.get("XLA_FLAGS", "")
-if "xla_force_host_platform_device_count" not in _flags:
-    os.environ["XLA_FLAGS"] = (
-        _flags + " --xla_force_host_platform_device_count=8").strip()
+os.environ.setdefault("JAX_PLATFORMS", "cpu")  # for the shard processes
 
 import jax  # noqa: E402
 
 jax.config.update("jax_platforms", "cpu")
-try:
-    # newer jax spells the device-count override as a config option; on
-    # older versions the XLA_FLAGS env set above applies as long as no
-    # backend has been initialized yet (same dance as tests/conftest.py)
-    jax.config.update("jax_num_cpu_devices", 8)
-except AttributeError:
-    pass
+jax.config.update("jax_num_cpu_devices", 8)
+_PLATFORM = "cpu"  # pinned above; stamped on every row this script prints
 
 import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
@@ -153,7 +144,7 @@ def bucket_sweep(mesh, layers, dim, iters):
             "ms_per_step_local_only": round(t_local * 1e3, 2),
             "mesh": "dcn2_dp4" if "dcn" in mesh.axis_names else "dp8",
         })
-        print(json.dumps(out[-1]), flush=True)
+        emit_row(out[-1], _PLATFORM)
     return out
 
 
@@ -205,7 +196,7 @@ def eager_priority_order(mesh, n_tensors, mbytes, iters):
         "tensors": n_tensors,
         "mbytes_each": mbytes,
     }
-    print(json.dumps(res), flush=True)
+    emit_row(res, _PLATFORM)
     return res
 
 
@@ -253,7 +244,7 @@ def delayed_vs_sync(mesh, layers, dim, iters):
         "overlap_speedup": round(t_sync / t_del, 3),
         "staleness": "updates lag their gradients by exactly 1 step",
     }
-    print(json.dumps(res), flush=True)
+    emit_row(res, _PLATFORM)
     return res
 
 
@@ -306,7 +297,7 @@ def jit_bucket_order(mesh, layers, dim, iters):
         "reversed_ms": round(t_rev * 1e3, 2),
         "vs_reversed": round(t_rev / t_sched, 3),
     }
-    print(json.dumps(res), flush=True)
+    emit_row(res, _PLATFORM)
     return res
 
 
@@ -414,7 +405,7 @@ def pipelined_wire(mb=8, part_kb=1024, shards=4, delay_ms=5.0, reps=8,
                 "tool": "bench_comm.py",
             }
             rows.append(row)
-            print(json.dumps(row), flush=True)
+            emit_row(row, _PLATFORM)
     finally:
         set_config(saved_cfg)
         for pr in procs:
@@ -516,7 +507,7 @@ def transport_ab(mb=1, reps=24, archive=True):
                     "tool": "bench_comm.py",
                 }
                 rows.append(row)
-                print(json.dumps(row), flush=True)
+                emit_row(row, _PLATFORM)
     finally:
         set_config(saved_cfg)
         if proc is not None:
@@ -644,7 +635,7 @@ def hierarchical_ab(workers=4, mb=2, delay_ms=5.0, steps=3, shards=2,
             "tool": "bench_comm.py",
         }
         rows.append(row)
-        print(json.dumps(row), flush=True)
+        emit_row(row, _PLATFORM)
     finally:
         set_config(saved_cfg)
         for px in proxies:
@@ -785,7 +776,7 @@ def zero_ab(world=2, mb=2, delay_ms=2.0, steps=5, shards=2, reps=3,
             "tool": "bench_comm.py",
         }
         rows.append(row)
-        print(json.dumps(row), flush=True)
+        emit_row(row, _PLATFORM)
     finally:
         set_config(saved_cfg)
         for px in proxies:
@@ -865,7 +856,7 @@ def registered_recv_ab(kb=64, reps=2000, archive=True):
                 "tool": "bench_comm.py",
             }
             rows.append(row)
-            print(json.dumps(row), flush=True)
+            emit_row(row, _PLATFORM)
     finally:
         a.close()
         b.close()

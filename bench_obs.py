@@ -50,7 +50,7 @@ import os
 import tempfile
 import time
 
-from bench_util import archive_rows
+from bench_util import archive_rows, emit_row
 
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
@@ -301,11 +301,11 @@ def main(argv=None) -> int:
     rows = []
     if not args.serve_only:
         rows.append(bench_wire(steps=args.steps, pairs=args.pairs))
-        print(json.dumps(rows[-1]), flush=True)
+        emit_row(rows[-1], jax.devices()[0].platform)
     if not args.wire_only:
         rows.append(bench_serve_path(requests=args.requests,
                                      tokens=args.tokens, pairs=args.pairs))
-        print(json.dumps(rows[-1]), flush=True)
+        emit_row(rows[-1], jax.devices()[0].platform)
     if not args.no_archive:
         archive_rows(rows, args.out)
     return 0

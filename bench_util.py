@@ -30,3 +30,12 @@ def archive_rows(rows, path, legacy_keys=()):
     with open(path, "w") as f:
         json.dump(doc, f, indent=1)
     print(f"archived {len(rows)} rows -> {path}", flush=True)
+
+
+def emit_row(row, platform):
+    """Print one result row as a JSON line, stamped with the platform it
+    was measured on (``jax.devices()[0].platform``, passed in to keep
+    this module jax-free) — a host timing must never read as a chip's.
+    Stamps in place so the archived copy carries it too."""
+    row.setdefault("platform", platform)
+    print(json.dumps(row), flush=True)

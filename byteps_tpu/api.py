@@ -27,6 +27,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from .common import logging as bps_log
+from .common.compile_cache import configure_compile_cache
 from .common.config import get_config, reset_config
 from .engine import dispatcher as _dispatcher
 from .ops.compression import Compression
@@ -156,6 +157,7 @@ def init(
     with _state.lock:
         if _state.initialized:
             return
+        configure_compile_cache()
         _maybe_distributed_init()
         cfg = get_config()
         _validate_local_contract(cfg)

@@ -94,8 +94,8 @@ class ResNet(nn.Module):
     axis_name: Any = None  # set to sync BN stats across a mesh axis
     # dtype of BN scale/bias and running stats (None = fp32, the safe
     # default).  bf16 halves the BN state stream and drops the
-    # fp32<->bf16 converts around every BN (scripts/resnet_bn_dtype_ab.py
-    # measures what that buys on the bench chip — docs/performance.md).
+    # fp32<->bf16 converts around every BN (what that buys on the chip
+    # is not measured on the current toolchain).
     # CAVEAT: flax stores stats in fp32 unless force_float32_reductions
     # is off, so bf16 here also computes the batch mean/var reductions
     # in bf16 — over ~800k elements at stage 1 that costs real variance

@@ -183,7 +183,7 @@ class QuantDense(nn.Module):
     ``inference.quantize_params``) it dequantizes *inside* the matmul —
     ``kernel.astype(dtype) * scale`` fuses into the dot's operand read, so
     HBM streams int8 bytes.  That halves decode's weight traffic, which is
-    the whole cost of bandwidth-bound generation (docs/performance.md).
+    the whole cost of bandwidth-bound generation.
     ``init`` never creates ``scale``: quantization is a property of the
     parameter tree, not the module.
 

@@ -10,6 +10,7 @@ API:
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
@@ -21,6 +22,7 @@ from ..common import logging as bps_log
 
 _HERE = os.path.dirname(os.path.abspath(__file__))
 _SO = os.path.join(_HERE, "libbyteps_native.so")
+_STAMP = os.path.join(_HERE, "libbyteps_native.stamp")
 _CSRC = os.path.normpath(os.path.join(_HERE, "..", "..", "csrc"))
 _SRCS = [
     os.path.join(_CSRC, "byteps_native.cc"),
@@ -33,20 +35,52 @@ _lock = threading.Lock()
 _build_failed = False
 
 
-def _build() -> bool:
-    """Compile the native lib in place (g++ is in the baked image)."""
+# No -march=native: the chip tool copies the working tree (this .so
+# included — it is git-ignored, not copy-ignored) to a machine with a
+# different CPU, where host-tuned code can fault with SIGILL.
+_CXXFLAGS = ["-O3", "-fopenmp", "-pthread", "-fPIC", "-std=c++17",
+             "-shared"]
+
+
+def _source_digest() -> str:
+    """Hash of what the binary is built FROM (source bytes + flags).
+    Staleness is decided by content, never mtime: a checkout, an
+    archive export and a copied tree all stamp files with arbitrary
+    times."""
+    h = hashlib.sha256(" ".join(_CXXFLAGS).encode())
+    for src in _SRCS:
+        if os.path.exists(src):
+            with open(src, "rb") as f:
+                h.update(f.read())
+    return h.hexdigest()
+
+
+def _build(digest: str) -> bool:
+    """Compile the native lib in place (g++ is in the baked image) and
+    stamp it with the digest of its inputs."""
     srcs = [s for s in _SRCS if os.path.exists(s)]
-    cmd = [
-        os.environ.get("CXX", "g++"),
-        "-O3", "-march=native", "-fopenmp", "-pthread", "-fPIC",
-        "-std=c++17", "-shared", "-o", _SO, *srcs,
-    ]
+    # build beside the target, then rename: a concurrent process (tests
+    # spawn many) must never dlopen a half-written file
+    tmp = f"{_SO}.{os.getpid()}.tmp"
+    cmd = [os.environ.get("CXX", "g++"), *_CXXFLAGS, "-o", tmp, *srcs]
     try:
         subprocess.run(cmd, check=True, capture_output=True, timeout=120)
-        return True
-    except Exception as e:  # pragma: no cover
+        os.replace(tmp, _SO)
+        with open(tmp, "w") as f:
+            f.write(digest)
+        os.replace(tmp, _STAMP)
+    except (OSError, subprocess.SubprocessError) as e:
         bps_log.warning("native build failed (%s); using numpy fallback", e)
         return False
+    return True
+
+
+def _stamp() -> str:
+    try:
+        with open(_STAMP) as f:
+            return f.read().strip()
+    except OSError:
+        return ""
 
 
 def _load() -> Optional[ctypes.CDLL]:
@@ -56,12 +90,9 @@ def _load() -> Optional[ctypes.CDLL]:
             return _lib
         if _build_failed:
             return None
-        stale = os.path.exists(_SO) and any(
-            os.path.exists(s) and os.path.getmtime(s) > os.path.getmtime(_SO)
-            for s in _SRCS
-        )
-        if not os.path.exists(_SO) or stale:
-            if not os.path.exists(_SRC) or not _build():
+        digest = _source_digest()
+        if not os.path.exists(_SO) or _stamp() != digest:
+            if not os.path.exists(_SRC) or not _build(digest):
                 _build_failed = True
                 return None
         try:

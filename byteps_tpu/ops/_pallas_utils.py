@@ -3,30 +3,57 @@ fused_cross_entropy): backend auto-detection and block-size fitting."""
 
 from __future__ import annotations
 
+import functools
 import math
 
 import jax
 
 
-def tpu_compiler_params(**kwargs):
-    """Version-portable ``pltpu.CompilerParams``: older jax (<=0.4.x)
-    spells it ``TPUCompilerParams``, newer jax renamed it.  Every kernel
-    builds its params through this shim so the ops import (and run in
-    interpret mode on CPU) on both."""
-    from jax.experimental.pallas import tpu as pltpu
+class KernelRefusedError(RuntimeError):
+    """A Pallas kernel cannot run where it was asked to.  Names the
+    kernel and carries the reason.  Raised today for one cause: the
+    process has no TPU and was not explicitly put on the CPU, so the
+    interpreter would silently stand in for Mosaic.  It is also the type
+    to raise, with the compiler's message as the reason, for a variant
+    Mosaic refuses — under libtpu 0.0.34 every variant compiles
+    (``chip_smoke.py`` kernel leg), so no such refusal exists."""
 
-    cls = getattr(pltpu, "CompilerParams", None)
-    if cls is None:
-        cls = getattr(pltpu, "TPUCompilerParams")
-    return cls(**kwargs)
+    def __init__(self, kernel: str, reason: str):
+        self.kernel = kernel
+        self.reason = reason
+        super().__init__(f"{kernel}: {reason}")
 
 
-def resolve_interpret(interpret) -> bool:
-    """None = auto: interpret mode off TPU (CPU tests / virtual meshes),
-    compiled Mosaic kernels on TPU."""
-    if interpret is None:
-        return jax.default_backend() != "tpu"
-    return bool(interpret)
+@functools.lru_cache(maxsize=None)
+def _log_mode_once(interpret: bool, backend: str) -> None:
+    from ..common import logging as bps_log
+
+    bps_log.info("pallas kernels: %s (backend %s)",
+                 "INTERPRET mode" if interpret else "Mosaic-compiled",
+                 backend)
+
+
+def resolve_interpret(interpret, kernel: str = "pallas kernel") -> bool:
+    """None = by platform: compiled Mosaic kernels on a TPU backend, the
+    Pallas interpreter ONLY when the process was explicitly put on the
+    CPU (``JAX_PLATFORMS=cpu`` / ``jax_platforms`` — tests and virtual
+    meshes).  A process that merely *landed* on the CPU because no
+    accelerator was found raises: interpreting there would let every
+    kernel "pass" on a machine that never compiled one."""
+    if interpret is not None:
+        return bool(interpret)
+    backend = jax.default_backend()
+    requested = (jax.config.jax_platforms or "").split(",")[0].strip()
+    interpret = backend != "tpu"
+    if interpret and not (backend == "cpu" and requested == "cpu"):
+        raise KernelRefusedError(
+            kernel,
+            f"backend is {backend!r} but jax_platforms={requested!r}: no "
+            f"TPU to compile for and the CPU was not requested explicitly. "
+            f"Set JAX_PLATFORMS=cpu to run Pallas kernels in interpret "
+            f"mode (tests, virtual meshes), or pass interpret=True")
+    _log_mode_once(interpret, backend)
+    return interpret
 
 
 def fit_block(block: int, size: int, what: str = "dimension") -> int:

@@ -1,8 +1,8 @@
 """Pallas TPU decode-attention kernel (single-token q vs KV cache).
 
-The r4 decomposition (docs/performance.md) showed MHA long-context decode
-bound by the cached-attention read running at ~310-610 GB/s effective —
-well under the chip's ~700-790 GB/s streaming rate — and a first fused
+An earlier on-chip decomposition (round 4, TPU v5e) showed MHA
+long-context decode bound by the cached-attention read running at
+~310-610 GB/s effective — well under the chip's ~700-790 GB/s streaming rate — and a first fused
 kernel (grid ``(B, k-blocks)``, per-KV-group thin dots) measured 2x
 *slower* than XLA's dense path: per-group ``[1, D] x [D, BS]`` matvecs
 starve the MXU.  This is the named v2 design: a **head-parallel
@@ -59,7 +59,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ._pallas_utils import resolve_interpret, tpu_compiler_params
+from ._pallas_utils import resolve_interpret
 
 # Default S-chunk. 512 rows x KV*D lanes of bf16 K + V double-buffered
 # stays well inside VMEM at any sane KV*D (H=12 MHA: 2 * 2 * 512*768*2B
@@ -196,7 +196,7 @@ def decode_attention(q, ck, cv, pos, *, k_scale=None, v_scale=None,
         raise ValueError(f"q heads {H} not a multiple of kv heads {KV}")
     G = H // KV
     KVD = KV * D
-    interpret = resolve_interpret(interpret)
+    interpret = resolve_interpret(interpret, "decode_attention")
     # The chunk size need not divide S: the grid is ceil(S/bs) and the
     # last chunk's out-of-range rows are always masked (kidx <= pos <= S-1),
     # so Mosaic's OOB-read padding never reaches the softmax.  (fit_block
@@ -277,9 +277,10 @@ def decode_attention(q, ck, cv, pos, *, k_scale=None, v_scale=None,
     oacc = pl.pallas_call(
         functools.partial(_decode_kernel, ns=ns, bs=bs, S=S,
                           window=window, quant=quant, cdt=q.dtype),
+        name="flat_decode_attention",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hp, KVD), q.dtype),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(pos_arr, *operands)

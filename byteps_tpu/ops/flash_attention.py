@@ -42,7 +42,7 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ._pallas_utils import fit_block as _fit_block_impl, resolve_interpret, tpu_compiler_params
+from ._pallas_utils import fit_block as _fit_block_impl, resolve_interpret
 
 # Tuned on TPU v5e at T=4096 bf16 (D=64 and D=128): (1024, 1024) beats
 # (512, 1024) by ~3-4% fwd+bwd and (128, 128) by >4x — big blocks amortize
@@ -53,12 +53,11 @@ DEFAULT_BLOCK_Q = 1024
 DEFAULT_BLOCK_K = 1024
 # The two backward kernels tune independently of the forward (r4 verdict
 # #6) — each carries three live [BQ, BK] fp32 temps (s, dp, ds) where the
-# fwd holds one, so a different optimum was plausible.  The on-chip
-# per-kernel sweep (scripts/flash_bwd_sweep.py) found 1024x1024 optimal
-# for BOTH anyway (every smaller/rectangular shape loses 2-70%, larger
-# VMEM-fails), and that by executed-dot count the bwd already runs at
-# 0.61 of peak vs the fwd's 0.65 — the machinery stays so a future chip
-# can retune per kernel.  Applied only when the caller left
+# fwd holds one, so a different optimum was plausible.  An on-chip
+# per-kernel sweep (round 4, TPU v5e, an earlier toolchain) found
+# 1024x1024 optimal for BOTH anyway (every smaller/rectangular shape
+# loses 2-70%, larger VMEM-fails) — the machinery stays so a future
+# chip can retune per kernel.  Applied only when the caller left
 # block_q/block_k at the fwd defaults (an explicit caller choice is
 # respected for all three kernels).
 DEFAULT_BWD_DQ_BLOCKS = (1024, 1024)   # (block_q, block_k) of _bwd_dq
@@ -74,10 +73,6 @@ def _fwd_blocks(block_q, block_k):
     by the independently swept bwd defaults."""
     return (DEFAULT_BLOCK_Q if block_q is None else block_q,
             DEFAULT_BLOCK_K if block_k is None else block_k)
-
-
-def _resolve_interpret(interpret) -> bool:
-    return resolve_interpret(interpret)
 
 
 def _fit_block(block: int, T: int) -> int:
@@ -225,7 +220,7 @@ def _check_band_args(causal, window, alibi_slopes, H):
 
 def _flash_forward(q, k, v, causal, scale, block_q, block_k, interpret,
                    segment_ids=None, window=None, alibi_slopes=None):
-    interpret = _resolve_interpret(interpret)
+    interpret = resolve_interpret(interpret, "flash_attention forward")
     B, T, H, D = q.shape
     H, Hkv, group = _gqa_group(q, k)
     _check_band_args(causal, window, alibi_slopes, H)
@@ -287,6 +282,7 @@ def _flash_forward(q, k, v, causal, scale, block_q, block_k, interpret,
         has_alibi=alibi_slopes is not None, window=window)
     o, lse = pl.pallas_call(
         kernel,
+        name="flash_fwd",
         grid=(B * H, T // bq, nk),
         in_specs=in_specs,
         out_specs=[
@@ -304,7 +300,7 @@ def _flash_forward(q, k, v, causal, scale, block_q, block_k, interpret,
             pltpu.VMEM((bq, 1), jnp.float32),   # running max
             pltpu.VMEM((bq, 1), jnp.float32),   # running sum
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -532,14 +528,13 @@ def _flash_backward(q, k, v, o, lse, do, dlse, causal, scale, block_q,
 
     ``dq_blocks``/``dkv_blocks`` override (block_q, block_k) per kernel —
     the two kernels' VMEM pressure differs (3 live [BQ, BK] fp32 temps
-    each, but different stationary operands), so they tune independently
-    (scripts/flash_bwd_sweep.py; r4 verdict #6).
+    each, but different stationary operands), so they tune independently.
 
     GQA backward materializes per-q-head k/v (one [B, T, H, D] transient
     each — the forward stays repeat-free) and group-sums dk/dv back to
     the Hkv heads; the dkv kernel's grid row owns its k block exclusively,
     which a shared kv row would break."""
-    interpret = _resolve_interpret(interpret)
+    interpret = resolve_interpret(interpret, "flash_attention backward")
     B, T, H, D = q.shape
     H, Hkv, group = _gqa_group(q, k)
     _check_band_args(causal, window, alibi_slopes, H)
@@ -566,7 +561,7 @@ def _flash_backward(q, k, v, o, lse, do, dlse, causal, scale, block_q,
 
     nk1, nq1 = T // bk1, T // bq1
     nk2, nq2 = T // bk2, T // bq2
-    arb = tpu_compiler_params(
+    arb = pltpu.CompilerParams(
         dimension_semantics=("parallel", "parallel", "arbitrary"))
 
     if causal:
@@ -626,6 +621,7 @@ def _flash_backward(q, k, v, o, lse, do, dlse, causal, scale, block_q,
         functools.partial(_bwd_dq_kernel, nk=nk1, causal=causal, scale=scale,
                           has_seg=has_seg, has_alibi=has_alibi,
                           window=window),
+        name="flash_bwd_dq",
         grid=(B * H, nq1, nk1),
         in_specs=dq_specs,
         out_specs=pl.BlockSpec((1, bq1, D), lambda b, i, j: (b, i, 0)),
@@ -662,6 +658,7 @@ def _flash_backward(q, k, v, o, lse, do, dlse, causal, scale, block_q,
         functools.partial(_bwd_dkv_kernel, nq=nq2, causal=causal, scale=scale,
                           has_seg=has_seg, has_alibi=has_alibi,
                           window=window),
+        name="flash_bwd_dkv",
         grid=(B * H, nk2, nq2),
         in_specs=dkv_specs,
         out_specs=[

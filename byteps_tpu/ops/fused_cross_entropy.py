@@ -49,7 +49,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ._pallas_utils import fit_block as _fit, resolve_interpret as _resolve_interpret, tpu_compiler_params
+from ._pallas_utils import fit_block as _fit, resolve_interpret
 
 # tuned on v5e at H=768, V=32k; explicit user blocks bypass the VMEM caps
 DEFAULT_BLOCK_N = 512
@@ -171,7 +171,8 @@ def _dw_kernel(w_ref, x_ref, tgt_ref, lse_ref, dl_ref, dw_ref, acc_ref,
 
 
 def _fce_forward(x, w, targets, block_n, block_v, interpret):
-    interpret = _resolve_interpret(interpret)
+    interpret = resolve_interpret(
+        interpret, "fused_linear_cross_entropy forward")
     N, H = x.shape
     H2, V = w.shape
     assert H == H2, (x.shape, w.shape)
@@ -183,6 +184,7 @@ def _fce_forward(x, w, targets, block_n, block_v, interpret):
 
     lse, tl = pl.pallas_call(
         functools.partial(_fwd_kernel, nv=nv, block_v=bv),
+        name="fused_ce_fwd",
         grid=(N // bn, nv),
         in_specs=[
             pl.BlockSpec((bn, H), lambda i, j: (i, 0)),   # x block
@@ -202,7 +204,7 @@ def _fce_forward(x, w, targets, block_n, block_v, interpret):
             pltpu.VMEM((bn, 1), jnp.float32),
             pltpu.VMEM((bn, 1), jnp.float32),
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -246,7 +248,8 @@ def _fce_fwd_rule(x, w, targets, block_n, block_v, interpret):
 
 def _fce_bwd_rule(block_n, block_v, interpret, res, dloss):
     x, w, targets, lse = res
-    interpret_b = _resolve_interpret(interpret)
+    interpret_b = resolve_interpret(
+        interpret, "fused_linear_cross_entropy backward")
     N, H = x.shape
     V = w.shape[1]
     block_n, block_v = _auto_blocks(H, block_n, block_v)
@@ -259,11 +262,12 @@ def _fce_bwd_rule(block_n, block_v, interpret, res, dloss):
     # (softmax - onehot) * 0 — no gradient flows from them to x or W
     valid = (tgt >= 0) & (tgt < V)
     dl = dloss.astype(jnp.float32).reshape(N, 1) * valid
-    arb = tpu_compiler_params(
+    arb = pltpu.CompilerParams(
         dimension_semantics=("parallel", "arbitrary"))
 
     dx = pl.pallas_call(
         functools.partial(_dx_kernel, nv=nv, block_v=bv),
+        name="fused_ce_bwd_dx",
         grid=(nn, nv),
         in_specs=[
             pl.BlockSpec((bn, H), lambda i, j: (i, 0)),
@@ -281,6 +285,7 @@ def _fce_bwd_rule(block_n, block_v, interpret, res, dloss):
 
     dw = pl.pallas_call(
         functools.partial(_dw_kernel, nn=nn, block_v=bv),
+        name="fused_ce_bwd_dw",
         grid=(nv, nn),
         in_specs=[
             pl.BlockSpec((H, bv), lambda vi, i: (0, vi)),
@@ -292,7 +297,7 @@ def _fce_bwd_rule(block_n, block_v, interpret, res, dloss):
         out_specs=pl.BlockSpec((H, bv), lambda vi, i: (0, vi)),
         out_shape=jax.ShapeDtypeStruct((H, V), w.dtype),
         scratch_shapes=[pltpu.VMEM((H, bv), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret_b,

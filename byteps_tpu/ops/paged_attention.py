@@ -68,7 +68,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ._pallas_utils import resolve_interpret, tpu_compiler_params
+from ._pallas_utils import resolve_interpret
 
 _NEG_INF = -1e30
 
@@ -205,7 +205,7 @@ def paged_decode_attention(q, ck, cv, table, pos, *,
             f"dividing {H} query heads")
     G = H // KV
     nb = table.shape[-1]
-    interpret = resolve_interpret(interpret)
+    interpret = resolve_interpret(interpret, "paged_decode_attention")
 
     quant = k_scale is not None or v_scale is not None
     if quant:
@@ -286,9 +286,10 @@ def paged_decode_attention(q, ck, cv, table, pos, *,
     oacc = pl.pallas_call(
         functools.partial(_paged_kernel, nb=nb, bs=bs, tq=tq, H=H,
                           window=window, quant=quant, cdt=q.dtype),
+        name="paged_decode_attention",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Rp, KVD), q.dtype),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(tab_arr, pos_arr, *operands)
@@ -316,10 +317,13 @@ def paged_decode_attention_sharded(q, ck, cv, table, pos, *,
     (``grp = repeat(arange(KV), G)``), so query-head slice
     ``[s*H/tp, (s+1)*H/tp)`` interacts with exactly KV-group slice
     ``[s*KV/tp, (s+1)*KV/tp)`` and no other — shard ``s``'s kernel
-    call performs bit-for-bit the same per-row arithmetic (same chunk
-    order, same online-softmax carries) as the corresponding row slice
-    of the unsharded call, and the head-axis concat reassembles the
-    unsharded output exactly.  One static Python loop, ``tp`` kernel
+    call performs the same per-row arithmetic (same chunk order, same
+    online-softmax carries) as the corresponding row slice of the
+    unsharded call, and the head-axis concat reassembles the unsharded
+    output.  Equal to a few ulps, not bit-for-bit: the per-shard dot
+    contracts ``KV*D/tp`` columns where the unsharded one contracts
+    ``KV*D`` (the rest exact zeros), and a backend may sum the two in a
+    different order.  One static Python loop, ``tp`` kernel
     calls per step; under a real tp mesh each call's operands live on
     shard ``s``'s device and the loop is the per-device program
     (docs/parallel.md — the o-projection's row-parallel psum merges

@@ -36,20 +36,14 @@ from jax import lax
 from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
-try:  # jax >= 0.6
-    from jax import shard_map as _shard_map_mod
+def shard_map(f, mesh, in_specs, out_specs, check_vma=False):
+    """``jax.shard_map`` with this repo's positional convention and
+    replication checking off by default (the bucketed push_pull returns
+    values it knows are replicated but the checker cannot prove)."""
+    return jax.shard_map(
+        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+        check_vma=check_vma)
 
-    def shard_map(f, mesh, in_specs, out_specs, check_vma=False):
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=check_vma
-        )
-except Exception:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _legacy_shard_map
-
-    def shard_map(f, mesh, in_specs, out_specs, check_vma=False):
-        return _legacy_shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=False
-        )
 
 from ..common import partition as partition_mod
 from ..common.partition import BucketPlan

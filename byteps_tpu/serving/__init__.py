@@ -105,6 +105,7 @@ from .frontend import (  # noqa: F401
     ServeClient,
     ServeConnectionError,
     ServeReplyError,
+    build_engine_from_env,
     serve,
     serve_from_env,
 )

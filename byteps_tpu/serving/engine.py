@@ -410,9 +410,16 @@ class ServingEngine:
             from ..ops.paged_attention import paged_attention_usable
 
             tq_max = (spec_k if spec_k and spec_k > 0 else 0) + 1
-            pk = ("auto" if paged_attention_usable(
-                (n_slots, tq_max, cfg.num_heads, cfg.d_head), block,
-                cfg.kv_heads * cfg.d_head) else "off")
+            if not paged_attention_usable(
+                    (n_slots, tq_max, cfg.num_heads, cfg.d_head), block,
+                    cfg.kv_heads * cfg.d_head):
+                bps_log.warning(
+                    "serving engine: paged_kernel='auto' fails the fused "
+                    "kernel's VMEM estimate (tq=%d, heads=%d, block=%d, "
+                    "kv_heads*d_head=%d) — serving through the XLA "
+                    "gather instead", tq_max, cfg.num_heads, block,
+                    cfg.kv_heads * cfg.d_head)
+                pk = "off"
         self.paged_kernel = self.paged and (
             pk == "on"
             or (pk == "auto" and jax.default_backend() == "tpu"))
@@ -570,6 +577,24 @@ class ServingEngine:
         else:
             self.pool = SlotPool(cfg, n_slots, self.max_seq,
                                  kv_quant=kv_quant, layout=cache_layout)
+        # what actually serves decode, read back from the pool that was
+        # built (not from the request): reported once here and on every
+        # STATS reply, so a benchmark never has to infer the path from
+        # the knobs it set
+        self.attention_path = (
+            ("paged_fused" if self.paged_kernel else "paged_gather")
+            if self.paged else
+            ("dense_flat_kernel" if self.pool.caches[0]["k"].ndim == 3
+             else "dense_xla"))
+        dev0 = jax.devices()[0]
+        self.device = {"platform": dev0.platform,
+                       "device_kind": dev0.device_kind,
+                       "count": len(jax.devices())}
+        bps_log.info(
+            "serving engine: attention_path=%s (paged=%s, paged_kernel="
+            "%r, cache_layout=%r) on %s %r x%d", self.attention_path,
+            self.paged, paged_kernel, cache_layout, dev0.platform,
+            dev0.device_kind, self.device["count"])
         # prefix-reuse KV cache: True builds a private store, or pass a
         # PrefixCache to share one across engines with IDENTICAL pool
         # geometry (entries are full cache-row buffers).  Every key is

@@ -95,7 +95,8 @@ OP_KV_BLOCKS = 6
 
 __all__ = ["ServeClient", "ServeFrontend", "RemoteServeClient",
            "ServeConnectionError", "ServeReplyError", "serve",
-           "serve_from_env", "OP_SUBMIT", "OP_STATS", "OP_PING",
+           "serve_from_env", "build_engine_from_env", "OP_SUBMIT",
+           "OP_STATS", "OP_PING",
            "OP_STREAM", "OP_CANCEL", "OP_JOURNAL", "OP_KV_BLOCKS"]
 
 
@@ -374,6 +375,11 @@ class _ServeHandler(socketserver.BaseRequestHandler):
                              # compares before trusting this replica
                              # with resumes (serving/router.py)
                              "weights_fingerprint": engine.weights_fp,
+                             # what ran, not what was asked for: the
+                             # device JAX reports and the attention
+                             # path the engine resolved to
+                             "device": engine.device,
+                             "attention_path": engine.attention_path,
                              "compile_counts": engine.compile_counts(),
                              "occupancy": engine.pool.occupancy(),
                              "queue_depth": engine.scheduler.depth,
@@ -1114,15 +1120,18 @@ def _model_from_env(cfg_str: str):
     return model, variables
 
 
-def serve_from_env(env=None) -> int:
-    """Entry point for the launcher's ``serve`` role: build the engine
-    from ``BYTEPS_SERVE_*`` and block on the TCP frontend.  An explicit
-    ``env`` mapping overrides the process environment for the
-    ``BYTEPS_*``/``DMLC_*`` keys it carries; either way the cached
-    process config is reset first, so knobs set after an earlier
-    ``get_config()`` call are honored."""
+def build_engine_from_env(env=None) -> ServingEngine:
+    """Build the serving engine from ``BYTEPS_SERVE_*`` — the first half
+    of the launcher's ``serve`` role, split out so an in-process caller
+    (``chip_smoke.py``, tests) can put the SAME engine behind
+    ``serve(..., in_thread=True)``.  An explicit ``env`` mapping
+    overrides the process environment for the ``BYTEPS_*``/``DMLC_*``
+    keys it carries; either way the cached process config is reset
+    first, so knobs set after an earlier ``get_config()`` call are
+    honored."""
     import os
 
+    from ..common.compile_cache import configure_compile_cache
     from ..common.config import get_config, reset_config
 
     if env is not None:
@@ -1130,13 +1139,14 @@ def serve_from_env(env=None) -> int:
                            if k.startswith(("BYTEPS_", "DMLC_"))})
     reset_config()
     cfg = get_config()
+    configure_compile_cache()
     model, variables = _model_from_env(cfg.serve_model)
     if cfg.serve_checkpoint:
         from ..training.checkpoint import restore_checkpoint
 
         variables = {"params": restore_checkpoint(
             cfg.serve_checkpoint, variables["params"], broadcast=False)}
-    engine = ServingEngine(
+    return ServingEngine(
         model, variables,
         n_slots=cfg.serve_slots,
         max_seq=(cfg.serve_max_seq or model.cfg.max_seq_len),
@@ -1156,5 +1166,14 @@ def serve_from_env(env=None) -> int:
         paged_kernel=cfg.serve_paged_kernel,
         spec_k=(cfg.serve_spec_k if cfg.serve_spec else 0),
         spec_ngram=cfg.serve_spec_ngram)
-    serve(engine, cfg.serve_port)
+
+
+def serve_from_env(env=None) -> int:
+    """Entry point for the launcher's ``serve`` role: build the engine
+    from the environment (:func:`build_engine_from_env`) and block on
+    the TCP frontend."""
+    from ..common.config import get_config
+
+    engine = build_engine_from_env(env)
+    serve(engine, get_config().serve_port)
     return 0

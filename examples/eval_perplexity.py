@@ -1,7 +1,7 @@
 """Perplexity evaluation with the fused LM-head kernel — the fused
 cross-entropy's winning configuration (forward-only: faster than the
 naive path AND never allocates the [N, vocab] logits; see
-docs/performance.md).  Evaluates a causal LM over a token stream::
+ops/fused_cross_entropy.py).  Evaluates a causal LM over a token stream::
 
     python examples/eval_perplexity.py --seq-len 1024 --batches 8
     python examples/eval_perplexity.py --tiny     # CPU smoke

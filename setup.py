@@ -28,7 +28,7 @@ class BuildWithNative(build_py):
         if srcs:
             cmd = [
                 os.environ.get("CXX", "g++"),
-                "-O3", "-march=native", "-fopenmp", "-pthread", "-fPIC",
+                "-O3", "-fopenmp", "-pthread", "-fPIC",
                 "-std=c++17", "-shared", "-o", out, *srcs,
             ]
             try:

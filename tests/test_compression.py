@@ -51,9 +51,14 @@ def test_roundtrip_shape_dtype_finite(name):
     assert out.shape == x.shape
     assert out.dtype == x.dtype
     assert bool(jnp.isfinite(out).all())
-    # jit traces to the same values as eager
+    # jit traces to the same values as eager — to a few f32 ulps, not
+    # bit-for-bit: eager runs each op as its own program while jit fuses
+    # them, and XLA is free to order a fused reduction (onebit's
+    # mean|x| scale) differently.  Selection and signs are exact in
+    # every scheme, so 4 ulps bounds everything a scale can move.
     jout = jax.jit(lambda v: s.roundtrip(v, key=key, ratio=0.05))(x)
-    np.testing.assert_array_equal(np.asarray(out), np.asarray(jout))
+    np.testing.assert_allclose(np.asarray(out), np.asarray(jout),
+                               rtol=4 * np.finfo(np.float32).eps, atol=0)
 
 
 def test_onebit_is_sign_times_mean_abs():

@@ -164,12 +164,15 @@ def test_gqa_train_grads_flow():
 
     params = vs["params"]
     opt = tx.init(params)
-    l0, grads = jax.value_and_grad(loss)(params)
+    # jitted once: six op-by-op eager backward passes ran ~15-17 s,
+    # inside reach of the 20 s tier-1 per-test budget under host load
+    vg = jax.jit(jax.value_and_grad(loss))
+    l0, grads = vg(params)
     gnorms = [float(jnp.linalg.norm(g))
               for g in jax.tree_util.tree_leaves(grads)]
     assert all(n > 0 for n in gnorms)
     for _ in range(5):
-        _, grads = jax.value_and_grad(loss)(params)
+        _, grads = vg(params)
         updates, opt = tx.update(grads, opt, params)
         params = optax.apply_updates(params, updates)
     assert float(loss(params)) < float(l0)

@@ -73,9 +73,7 @@ def test_flash_composes_with_tensor_parallel():
         tok = jax.device_put(
             jax.random.randint(jax.random.PRNGKey(1), (4, 16), 0, 64),
             NamedSharding(mesh, P("dp", None)))
-        ctx = (jax.sharding.use_mesh(mesh)
-               if hasattr(jax.sharding, "use_mesh") else mesh)
-        with ctx:
+        with jax.set_mesh(mesh):
             return jax.jit(
                 lambda p, t: model.apply({"params": p}, t))(params, tok)
 

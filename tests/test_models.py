@@ -22,10 +22,12 @@ def test_resnet50_forward_shapes():
 def test_resnet_train_mode_updates_stats():
     model = ResNet18(num_classes=4, num_filters=8)
     x = jax.random.normal(jax.random.PRNGKey(1), (2, 32, 32, 3))
-    variables = model.init(jax.random.PRNGKey(0), x, train=False)
-    logits, new_state = model.apply(
-        variables, x, train=True, mutable=["batch_stats"]
-    )
+    # jitted: op-by-op eager init/apply is ~1000 tiny compiles (~15 s,
+    # over the tier-1 per-test budget under host load)
+    variables = jax.jit(
+        lambda k, x: model.init(k, x, train=False))(jax.random.PRNGKey(0), x)
+    logits, new_state = jax.jit(lambda v, x: model.apply(
+        v, x, train=True, mutable=["batch_stats"]))(variables, x)
     assert logits.shape == (2, 4)
     old = jax.tree_util.tree_leaves(variables["batch_stats"])
     new = jax.tree_util.tree_leaves(new_state["batch_stats"])

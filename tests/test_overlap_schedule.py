@@ -111,8 +111,7 @@ def test_sync_step_buckets_straddle_backward(mesh):
     scheduler regression (collectives sunk to ~the end of the entry
     schedule — PARITY.md) and silently xpassed once the build moved on.
     The mark is dropped so a real schedule regression fails loudly
-    again; the delayed-grad variant below still genuinely xfails on
-    this build and keeps its mark."""
+    again (the delayed-grad variant below lost its mark the same way)."""
     tx = optax.sgd(0.1, momentum=0.9)
     step = make_data_parallel_step(_loss_fn, tx, mesh)
     state = jax.eval_shape(lambda p: create_train_state(p, step.tx), _PARAMS)
@@ -127,17 +126,15 @@ def test_sync_step_buckets_straddle_backward(mesh):
         f"({after} compute ops after) — no overlap in the schedule")
 
 
-@pytest.mark.xfail(
-    strict=False,
-    reason="XLA:CPU scheduler placement divergence (documented in "
-    "PARITY.md): 1 compute op scheduled after the grad reduce chain vs "
-    "the >=3 the assertion demands.  Structural independence is still "
-    "proven by test_overlap.py; the TPU schedule proof is archived in "
-    "docs/overlap_proof.md.")
 def test_delayed_step_collectives_straddle_whole_batch_compute(mesh):
     """Delayed-grad step: the *entire* reduce chain — including the final
     all-gather — is scheduled with this batch's compute still pending,
-    which is impossible for a synchronous step (its update is terminal)."""
+    which is impossible for a synchronous step (its update is terminal).
+
+    History: carried ``xfail(strict=False)`` for an XLA:CPU scheduler
+    placement divergence (1 compute op after the reduce chain where the
+    assertion demands >= 3 — PARITY.md).  jaxlib 0.9.0 schedules it as
+    asserted, so the mark is dropped and a regression fails loudly."""
     tx = optax.sgd(0.1, momentum=0.9)
     step = make_delayed_grad_step(_loss_fn, tx, mesh)
     state = jax.eval_shape(

@@ -109,11 +109,19 @@ def test_tp_refusal_messages(tiny):
 # ------------------------------------------------ op-level bit-exactness
 
 
-def test_sharded_kernel_bit_identical_to_unsharded():
+def test_sharded_kernel_matches_unsharded():
     """The head-slice exactness argument, pinned at the op: per-shard
-    kernel calls over the per-shard pools, concatenated over heads, are
-    BIT-identical to the unsharded kernel on the unsharded pool —
-    attention is exactly partitioned by KV head (docs/parallel.md)."""
+    kernel calls over the per-shard pools, concatenated over heads,
+    equal the unsharded kernel on the unsharded pool — attention is
+    exactly partitioned by KV head (docs/parallel.md).
+
+    Compared at a few f32 ulps, not bit-for-bit: the two are different
+    programs.  The unsharded block-diagonal dot contracts KV*D columns
+    (the other heads' columns are exact zeros), the per-shard dot
+    contracts KV*D/tp, and the backend may sum a 32-long and a 16-long
+    contraction in a different order (jaxlib 0.9.0 does: 36 of 96
+    outputs differ by half an ulp).  Token-level parity across tp
+    widths is pinned separately, through the engine."""
     rng = np.random.RandomState(0)
     B, H, D, KV, blk, mb, nb, tp = 3, 4, 8, 4, 4, 6, 16, 2
     pos = np.array([3, 9, 17], np.int32)
@@ -135,7 +143,9 @@ def test_sharded_kernel_bit_identical_to_unsharded():
     out = paged_decode_attention_sharded(q, spk, spv, tables,
                                          jnp.asarray(pos),
                                          interpret=True)
-    np.testing.assert_array_equal(np.asarray(base), np.asarray(out))
+    np.testing.assert_allclose(np.asarray(base), np.asarray(out),
+                               rtol=4 * np.finfo(np.float32).eps,
+                               atol=4 * np.finfo(np.float32).eps)
 
 
 def test_sharded_kernel_int8_bit_identical():
