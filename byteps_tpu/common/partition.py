@@ -26,6 +26,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .tracing import SCOPE_UNPACK, bucket_scope
+
 
 def partition_offsets(nbytes: int, bound: int) -> List[Tuple[int, int]]:
     """Split ``nbytes`` into (offset, length) parts each <= bound.
@@ -221,25 +223,27 @@ def gather_buckets(tree, plan: BucketPlan) -> List[jax.Array]:
     """Materialize bucket payloads (1-D arrays) from a pytree.  Traceable."""
     flat = jax.tree_util.tree_leaves(tree)
     out = []
-    for b in plan.buckets:
-        parts = []
-        for s in b.slices:
-            leaf = flat[s.leaf_index].reshape(-1)
-            parts.append(jax.lax.dynamic_slice_in_dim(leaf, s.leaf_start, s.length))
-        out.append(parts[0] if len(parts) == 1 else jnp.concatenate(parts))
+    for i, b in enumerate(plan.buckets):
+        with jax.named_scope(bucket_scope("pack", i)):
+            parts = []
+            for s in b.slices:
+                leaf = flat[s.leaf_index].reshape(-1)
+                parts.append(jax.lax.dynamic_slice_in_dim(leaf, s.leaf_start, s.length))
+            out.append(parts[0] if len(parts) == 1 else jnp.concatenate(parts))
     return out
 
 
 def scatter_buckets(bucket_arrays: Sequence[jax.Array], plan: BucketPlan):
     """Inverse of gather_buckets: rebuild the pytree from bucket payloads."""
     pieces: Dict[int, List[Tuple[int, jax.Array]]] = {}
-    for b, arr in zip(plan.buckets, bucket_arrays):
-        for s in b.slices:
-            chunk = jax.lax.dynamic_slice_in_dim(arr, s.bucket_start, s.length)
-            pieces.setdefault(s.leaf_index, []).append((s.leaf_start, chunk))
     flat = []
-    for leaf in plan.leaves:
-        chunks = sorted(pieces[leaf.index], key=lambda t: t[0])
-        vec = chunks[0][1] if len(chunks) == 1 else jnp.concatenate([c for _, c in chunks])
-        flat.append(vec.reshape(leaf.shape).astype(leaf.dtype))
+    with jax.named_scope(SCOPE_UNPACK):
+        for b, arr in zip(plan.buckets, bucket_arrays):
+            for s in b.slices:
+                chunk = jax.lax.dynamic_slice_in_dim(arr, s.bucket_start, s.length)
+                pieces.setdefault(s.leaf_index, []).append((s.leaf_start, chunk))
+        for leaf in plan.leaves:
+            chunks = sorted(pieces[leaf.index], key=lambda t: t[0])
+            vec = chunks[0][1] if len(chunks) == 1 else jnp.concatenate([c for _, c in chunks])
+            flat.append(vec.reshape(leaf.shape).astype(leaf.dtype))
     return jax.tree_util.tree_unflatten(plan.treedef, flat)

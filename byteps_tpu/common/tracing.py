@@ -10,10 +10,18 @@ docs/timeline.md) plus TRACE-level queue logging.  Here:
     Enable with ``BYTEPS_TRACE_PATH=/tmp/bps_trace.json`` (the analog of
     ``BYTEPS_SERVER_PROFILE_OUTPUT_PATH``); filter to one key with
     ``BYTEPS_SERVER_KEY_TO_PROFILE``-style arg to ``Tracer(key_filter=)``.
-  * ``annotate`` — ``jax.profiler.TraceAnnotation`` wrapper so jitted-step
-    stages show up named in TPU XProf traces (the SURVEY §5 prescription:
-    "jax.profiler traces + per-stage named XLA computations").
-  * on-device step timing helpers for the bench harness.
+  * ``SCOPE_*`` / ``bucket_scope`` — the one table of ``jax.named_scope``
+    names the jitted train step wraps its stages in (the SURVEY §5
+    prescription: "jax.profiler traces + per-stage named XLA
+    computations").  A scope is HLO metadata: every device op of a
+    ``jax.profiler`` trace carries it in its ``op_name``, at no run-time
+    cost and with no switch (docs/timeline.md "Scopes in the device
+    trace").
+  * ``annotate`` — the host-side counterpart, a
+    ``jax.profiler.TraceAnnotation`` span on the host thread's line of
+    the same trace and the same clock.  It times host code (a dispatch,
+    a wait); it cannot name a region *inside* a jitted step — that is
+    what the scopes are for.
 
 Timestamps are **wall-clock anchored**: a fixed ``time.time() -
 perf_counter()`` epoch captured at construction maps monotonic
@@ -373,9 +381,40 @@ def reset_tracer() -> None:
         _tracer = None
 
 
+# ---------------------------------------------------------------------------
+# Scopes of the jitted train step (device trace).  Used as
+# ``jax.named_scope(SCOPE_X)`` where the stage is traced; the name lands in
+# each HLO instruction's ``op_name`` — ``jit(local_step)/.../jvp(bps.model)/
+# block_0/...`` on the forward side, ``.../transpose(jvp(bps.model))/...`` on
+# the backward side — and from there in every ``XLA Ops`` event of a
+# profiler trace.  benchmark/harness/scopes.py reads them back.
+# ---------------------------------------------------------------------------
+
+SCOPE_MODEL = "bps.model"                # loss_fn: forward, and its backward
+SCOPE_HEAD = "bps.head"                  # LM head: weight cast + CE kernels
+SCOPE_PUSH_PULL = "bps.push_pull"        # parent of the three bucket stages
+SCOPE_UNPACK = SCOPE_PUSH_PULL + "/unpack"   # buckets sliced back into leaves
+SCOPE_OPTIMIZER = "bps.optimizer"        # inner update + parameter write
+SCOPE_STEP_METRICS = "bps.step_metrics"  # loss / model-state psums
+
+BUCKET_STAGES = ("pack", "reduce")
+
+
+def bucket_scope(stage: str, i: int) -> str:
+    """``bps.push_pull/<stage>/b<iii>`` for bucket ``i`` of the plan:
+    ``pack`` (concatenating the bucket's leaf slices) or ``reduce`` (its
+    reduce-scatter / cross-axis psum / all-gather, wire casts included)."""
+    if stage not in BUCKET_STAGES:
+        raise ValueError(f"bucket stage {stage!r} not in {BUCKET_STAGES}")
+    return f"{SCOPE_PUSH_PULL}/{stage}/b{i:03d}"
+
+
 @contextmanager
 def annotate(name: str):
-    """Named region in TPU XProf traces (jax.profiler.TraceAnnotation)."""
+    """Host-side span in a ``jax.profiler`` trace
+    (``jax.profiler.TraceAnnotation``): on the calling thread's line, on
+    the device ops' clock.  The device-side names are the ``SCOPE_*``
+    table above."""
     import jax.profiler
 
     with jax.profiler.TraceAnnotation(name):

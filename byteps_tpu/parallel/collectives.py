@@ -47,6 +47,7 @@ def shard_map(f, mesh, in_specs, out_specs, check_vma=False):
 
 from ..common import partition as partition_mod
 from ..common.partition import BucketPlan
+from ..common.tracing import bucket_scope
 
 
 def _axis_size(axes) -> int:
@@ -197,13 +198,14 @@ def push_pull_tree(
     buckets = partition_mod.gather_buckets(grads, plan)
     reduced: List[Optional[jax.Array]] = [None] * len(buckets)
     for i in plan.schedule_order():
-        reduced[i] = push_pull_shard(
-            buckets[i],
-            scatter_axis=scatter_axis,
-            sum_axes=sum_axes,
-            average=average,
-            wire_dtype=wire_dtype,
-        )
+        with jax.named_scope(bucket_scope("reduce", i)):
+            reduced[i] = push_pull_shard(
+                buckets[i],
+                scatter_axis=scatter_axis,
+                sum_axes=sum_axes,
+                average=average,
+                wire_dtype=wire_dtype,
+            )
     return partition_mod.scatter_buckets(reduced, plan)
 
 

@@ -24,6 +24,7 @@ import optax
 
 from ..common.config import get_config
 from ..common.partition import BucketPlan
+from ..common.tracing import SCOPE_OPTIMIZER
 from ..ops.compression import Compression
 from ..parallel.collectives import push_pull_tree
 
@@ -98,6 +99,20 @@ def sgd_momentum_update(m, g, lr: float, momentum: float):
     and both legs must share one arithmetic, not two lowerings of it."""
     m = momentum * m + g
     return m, (-lr) * m
+
+
+def scoped_update(tx: optax.GradientTransformation
+                  ) -> optax.GradientTransformation:
+    """``tx`` with its ``update`` traced under ``bps.optimizer`` — the
+    same state, the same program; the device ops of the update carry
+    the scope in their ``op_name`` (common/tracing.py)."""
+    tx = optax.with_extra_args_support(tx)
+
+    def update_fn(updates, state, params=None, **extra_args):
+        with jax.named_scope(SCOPE_OPTIMIZER):
+            return tx.update(updates, state, params, **extra_args)
+
+    return optax.GradientTransformationExtraArgs(tx.init, update_fn)
 
 
 def push_pull_gradients(
@@ -225,7 +240,7 @@ def DistributedOptimizer(
             plan=plan,
             local_axis=local_axis,
         ))
-    links.append(optimizer)
+    links.append(scoped_update(optimizer))
     tx = optax.chain(*links)
     if backward_passes_per_step > 1:
         tx = optax.MultiSteps(tx, every_k_schedule=backward_passes_per_step)

@@ -23,8 +23,10 @@ import optax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..common.config import get_config
+from ..common.tracing import (SCOPE_HEAD, SCOPE_MODEL, SCOPE_OPTIMIZER,
+                              SCOPE_STEP_METRICS)
 from ..ops.compression import Compression
-from .optimizer import DistributedOptimizer
+from .optimizer import DistributedOptimizer, scoped_update
 from ..parallel.collectives import shard_map
 
 
@@ -147,8 +149,9 @@ def make_data_parallel_step(
         # numerics (a compressed multi-worker run and its single-worker
         # debug rerun must not silently diverge).
         comp_tx = _world1_compression_tx(compression)
-        tx = optimizer if comp_tx is None else optax.chain(comp_tx,
-                                                           optimizer)
+        tx = scoped_update(optimizer)
+        if comp_tx is not None:
+            tx = optax.chain(comp_tx, tx)
     else:
         tx = DistributedOptimizer(
             optimizer,
@@ -162,19 +165,26 @@ def make_data_parallel_step(
 
     def local_step(state: TrainState, batch):
         def lf(p):
-            return loss_fn(p, state.model_state, batch)
+            # forward here; the backward carries the same scope inside
+            # ``transpose(jvp(...))``
+            with jax.named_scope(SCOPE_MODEL):
+                return loss_fn(p, state.model_state, batch)
 
         (loss, new_mstate), grads = jax.value_and_grad(lf, has_aux=True)(state.params)
+        # push_pull and the inner update name themselves (``tx``)
         updates, new_opt = tx.update(grads, state.opt_state, state.params)
-        new_params = optax.apply_updates(state.params, updates)
-        n = jax.lax.psum(1, axes)
-        loss = jax.lax.psum(loss, axes) / n
-        # keep mutable model state (BN stats) replicated: average across dp
-        new_mstate = jax.tree_util.tree_map(
-            lambda x: jax.lax.psum(x, axes) / n
-            if jnp.issubdtype(x.dtype, jnp.floating) else x,
-            new_mstate,
-        )
+        with jax.named_scope(SCOPE_OPTIMIZER):
+            new_params = optax.apply_updates(state.params, updates)
+        with jax.named_scope(SCOPE_STEP_METRICS):
+            n = jax.lax.psum(1, axes)
+            loss = jax.lax.psum(loss, axes) / n
+            # keep mutable model state (BN stats) replicated: average
+            # across dp
+            new_mstate = jax.tree_util.tree_map(
+                lambda x: jax.lax.psum(x, axes) / n
+                if jnp.issubdtype(x.dtype, jnp.floating) else x,
+                new_mstate,
+            )
         return (
             TrainState(new_params, new_opt, new_mstate, state.step + 1),
             {"loss": loss},
@@ -233,8 +243,11 @@ def make_zero_step(loss_fn, zero, model_state=None, reduce_grads=None):
 
     ms = {} if model_state is None else model_state
 
-    grad_fn = jax.jit(jax.value_and_grad(
-        lambda p, b: loss_fn(p, ms, b)[0]))
+    def lf(p, b):
+        with jax.named_scope(SCOPE_MODEL):
+            return loss_fn(p, ms, b)[0]
+
+    grad_fn = jax.jit(jax.value_and_grad(lf))
 
     def step(batch):
         loss, grads = grad_fn(zero.params, batch)
@@ -325,31 +338,36 @@ def lm_loss_fn(model, fused_head: bool = False,
         from ..ops.fused_cross_entropy import fused_linear_cross_entropy
 
         h = m.apply({"params": params}, tokens, method=m.hidden)
-        w = _head_weight(params, h)
-        B, T, d = h.shape
-        V = w.shape[-1]
-        flat_t = targets.reshape(-1)
-        per_row = fused_linear_cross_entropy(
-            h.reshape(-1, d), w, flat_t, block_n, block_v,
-        )
-        # mean over *valid* targets only: with padded token streams
-        # (HF -100 convention) a fixed B*(T-1) denominator deflates
-        # the loss; the kernel already zeroes ignored rows
-        valid = jnp.sum((flat_t >= 0) & (flat_t < V))
-        return per_row.sum() / jnp.maximum(valid, 1).astype(per_row.dtype)
+        with jax.named_scope(SCOPE_HEAD):
+            w = _head_weight(params, h)
+            B, T, d = h.shape
+            V = w.shape[-1]
+            flat_t = targets.reshape(-1)
+            per_row = fused_linear_cross_entropy(
+                h.reshape(-1, d), w, flat_t, block_n, block_v,
+            )
+            # mean over *valid* targets only: with padded token streams
+            # (HF -100 convention) a fixed B*(T-1) denominator deflates
+            # the loss; the kernel already zeroes ignored rows
+            valid = jnp.sum((flat_t >= 0) & (flat_t < V))
+            return per_row.sum() / jnp.maximum(valid, 1).astype(
+                per_row.dtype)
 
     def _plain_ce(params, m, tokens, targets):
+        # the head's matmul is inside the model's own call here (its
+        # Flax scope names it); the scope covers the CE over its logits
         logits = m.apply({"params": params}, tokens)
-        t = targets[:, :-1]
-        valid = (t >= 0) & (t < logits.shape[-1])
-        # optax's integer-label CE has no ignore-index: out-of-range
-        # labels produce garbage — clamp them and zero their loss
-        per_tok = optax.softmax_cross_entropy_with_integer_labels(
-            logits[:, :-1], jnp.where(valid, t, 0)
-        )
-        per_tok = jnp.where(valid, per_tok, 0.0)
-        return per_tok.sum() / jnp.maximum(valid.sum(), 1).astype(
-            per_tok.dtype)
+        with jax.named_scope(SCOPE_HEAD):
+            t = targets[:, :-1]
+            valid = (t >= 0) & (t < logits.shape[-1])
+            # optax's integer-label CE has no ignore-index: out-of-range
+            # labels produce garbage — clamp them and zero their loss
+            per_tok = optax.softmax_cross_entropy_with_integer_labels(
+                logits[:, :-1], jnp.where(valid, t, 0)
+            )
+            per_tok = jnp.where(valid, per_tok, 0.0)
+            return per_tok.sum() / jnp.maximum(valid.sum(), 1).astype(
+                per_tok.dtype)
 
     ce = _fused_ce if fused_head else _plain_ce
 
