@@ -4,19 +4,68 @@ The hot op of the transformer stack, written for the MXU/VMEM rather than
 translated from any CUDA kernel.  All three kernels share one structure:
 a 3-D grid ``(batch*heads, outer blocks, inner blocks)`` whose innermost
 dim is declared "arbitrary" so Mosaic pipelines the inner-operand
-HBM→VMEM copies against compute, with the accumulator (online-softmax
-carry, or the dq/dk/dv partials) living in VMEM scratch across inner
-steps — no [T, T] score matrix ever materializes in HBM.  Causal masking
-prunes above-diagonal blocks: ``pl.when`` skips their compute and a
-clamped BlockSpec index map elides their DMAs (an unchanged block index
-between consecutive grid steps performs no copy).
+HBM→VMEM copies against compute — no [T, T] score matrix ever
+materializes in HBM.  Across inner steps the carry (online-softmax acc /
+max / sum, or the dq/dk/dv partials) lives in VMEM scratch; where the
+inner axis has ONE block (T <= the block: the whole sequence in one
+step) there is nothing to carry, no scratch is allocated, and a step
+writes its outputs itself.
+
+Causal and sliding-window masking prune at **two granularities**:
+
+  * *grid block* ``[BQ, BK]`` (1024 x 1024 by default, clamped to T): a
+    block wholly outside the band does nothing, and a clamped BlockSpec
+    index map elides its DMAs (an unchanged block index between
+    consecutive grid steps performs no copy).  That alone prunes nothing
+    when T <= the block — one block a head, the whole score square;
+  * *sub-tile* ``s x s`` inside a grid step (``_SUB_TILE``): the visit
+    rule (``_k_tile_range`` / ``_q_tile_range`` / ``_needs_mask``, plain
+    Python) says which sub-tiles the band touches.  A sub-tile strictly
+    above the diagonal or wholly left of the window is never computed
+    (no QK^T, no exp, no PV / dP / dS matmul); one strictly inside the
+    band is computed without iotas or the mask; only sub-tiles the
+    band's edge crosses (and every tile under ``segment_ids`` / ALiBi)
+    take the mask path.  Non-causal attention visits every sub-tile.
+
+The visited sub-tiles of a step are not walked one by one: they are
+gathered into **strips** (``_strips``) — for the forward and dq kernels a
+band of q rows against ALL the k sub-tiles it visits, for the dk/dv
+kernel a band of k columns against all the q sub-tiles that visit it —
+and a strip is one matmul per product, so each row's softmax bookkeeping
+(max, sum, exp of the carry) runs once a step, as it did for the whole
+block, and the MXU sees the longest operands the band allows.  Rows (or
+columns) that visit the same range the same way share a strip: a block
+wholly inside the band, and every non-causal block, is ONE strip — the
+whole block, exactly the pre-sub-tile kernel.  A step's position enters
+the rule only through the offset ``d = first q row - first k column``
+of its block, so the kernels are specialised at trace time for each
+distinct plan over the grid's offsets (``_step_plans``: for causal
+attention two — the diagonal block's ragged strips and the interior
+block's single strip), selected by ``pl.when`` on ``d`` where the grid
+has more than one block and folded away where it has one.  Walking the
+sub-tiles in ``lax.fori_loop`` / ``lax.cond`` with bounds from the
+program ids, or unrolled tile by tile with the online softmax carried
+across k sub-tiles, was measured and lost (see the tuning notes below).
+Each ``pallas_call`` build records what it will visit in
+``flash.tiles_visited`` / ``flash.tiles_total`` (gauges labelled
+``kernel=fwd|bwd_dq|bwd_dkv``: sub-tiles per head over the whole grid;
+``tile_visits`` is the count, shared with the tests).
+
+The three ``pallas_call`` objects are built once per static configuration
+(``_forward_call`` / ``_dq_call`` / ``_dkv_call``, ``lru_cache``): a
+``pallas_call`` is a ``jit`` of its own, so the layers of a model that
+share a configuration share one trace of the kernel and one lowering to
+Mosaic, where a fresh object a layer re-traced and re-lowered each of the
+3 x 24 kernels of a 24-layer step (1.2 s + 1.6 s of the sandbox's
+trace-and-lower for the whole-block kernels, 1.8 s + 2.0 s for the strip
+kernels, 0.1 s + 0.1 s cached; PR 26).
 
 Backward is a custom_vjp with residuals (q, k, v, o, lse, segment_ids)
 and **two Pallas kernels** (the standard flash-attention-2 split, designed
 for the MXU's preference for large stationary operands over atomics):
 
   * ``_bwd_dq_kernel`` — grid (batch*heads, q blocks, k blocks):
-    recomputes one [BQ, BK] score slice per step and accumulates dq;
+    recomputes the scores strip by strip and accumulates dq;
   * ``_bwd_dkv_kernel`` — grid (batch*heads, k blocks, q blocks):
     accumulates dk/dv for its k block across the q-block dim.
 
@@ -42,27 +91,48 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..observability.metrics import get_registry
 from ._pallas_utils import fit_block as _fit_block_impl, resolve_interpret
 
-# Tuned on TPU v5e at T=4096 bf16 (D=64 and D=128): (1024, 1024) beats
-# (512, 1024) by ~3-4% fwd+bwd and (128, 128) by >4x — big blocks amortize
-# grid-step overhead and keep the MXU fed; the 4 MB f32 score block plus
-# double-buffered operands still fits VMEM at D=128.  Both clamp to T for
-# short sequences.
+# Grid blocks.  (1024, 1024) for all three kernels comes from sweeps at
+# T=4096 bf16 (D=64 and D=128) on a TPU v5e under an EARLIER toolchain
+# (it beat (512, 1024) by ~3-4% and (128, 128) by >4x; every smaller or
+# rectangular backward shape lost 2-70%, larger ones failed VMEM); the
+# grid block has not been re-swept under libtpu 0.0.34.  Both clamp to T,
+# so T <= 1024 is one block a head.  The backward shapes apply only when
+# the caller left block_q/block_k at None (an explicit caller choice
+# binds all three kernels); they are kept apart so a retune can move one
+# kernel alone.
 DEFAULT_BLOCK_Q = 1024
 DEFAULT_BLOCK_K = 1024
-# The two backward kernels tune independently of the forward (r4 verdict
-# #6) — each carries three live [BQ, BK] fp32 temps (s, dp, ds) where the
-# fwd holds one, so a different optimum was plausible.  An on-chip
-# per-kernel sweep (round 4, TPU v5e, an earlier toolchain) found
-# 1024x1024 optimal for BOTH anyway (every smaller/rectangular shape
-# loses 2-70%, larger VMEM-fails) — the machinery stays so a future
-# chip can retune per kernel.  Applied only when the caller left
-# block_q/block_k at the fwd defaults (an explicit caller choice is
-# respected for all three kernels).
 DEFAULT_BWD_DQ_BLOCKS = (1024, 1024)   # (block_q, block_k) of _bwd_dq
 DEFAULT_BWD_DKV_BLOCKS = (1024, 1024)  # (block_q, block_k) of _bwd_dkv
+# Edge s of the square sub-tiles a grid block is cut into for pruning.
+# Swept on one TPU v5e, jax 0.9.0 / libtpu 0.0.34 (PR 26; ms per call,
+# fwd / dq / dkv, device time from the profiler; "whole" = no sub-tiles):
+#   B8 T1024 H16 D64 causal (the train cells; one block a head)
+#     parent 0.438 / 0.585 / 0.821 = 1.844    whole 0.414 / 0.550 / 0.731
+#     s=128  0.385 / 0.351 / 0.969            s=512 0.329 / 0.419 / 0.578
+#     s=256  0.334 / 0.355 / 0.584 = 1.272 (-31 %; visits 10 of 16)
+#   B2 T4096 H8 D128 causal (4 x 4 grid; 4 diagonal blocks of 10 prune)
+#     parent 0.644 / 0.755 / 1.002 = 2.401    s=128 2.412   s=512 2.189
+#     s=256  0.615 / 0.653 / 0.909 = 2.177 (-9 %)
+#   same, window 1024: parent 1.832, s=256 1.416 (-23 %), s=512 1.489
+#   B4 T2048 H16 D64 causal: parent 3.055, s=256 2.624 (-14 %), s=512 2.656
+#   B8 T1024 H16 D64 non-causal: parent 1.962, any s 1.765 (one strip; the
+#     -10 % is the scratch carry that one-block grids no longer keep)
+# No kernel prefers another s by more than 3 % at either shape, so one
+# constant serves all three.  What lost on the way, at B8 T1024 H16 D64:
+# visiting sub-tiles one at a time with the online softmax carried across
+# k sub-tiles (unrolled; s=256) ran the forward at 0.739 ms — the
+# [s, 1] max/sum/rescale pass per TILE costs more than the pruned tiles
+# save — and the same walk under lax.fori_loop / lax.cond from program
+# ids ran T4096 D128 at 6.78 ms against the parent's 2.40.  Strips that
+# still round-trip their state through VMEM scratch: forward 0.561.
+_SUB_TILE = 256
 _NEG_INF = -1e30
+_ARBITRARY_INNER = pltpu.CompilerParams(
+    dimension_semantics=("parallel", "parallel", "arbitrary"))
 
 
 def _fwd_blocks(block_q, block_k):
@@ -85,17 +155,186 @@ def _causal_last_k(qi, block_q: int, block_k: int, nk: int):
     return jnp.minimum((qi * block_q + block_q - 1) // block_k, nk - 1)
 
 
-def _seg_mask(sq_ref, sk_ref, s):
-    """Mask scores where q and k segment ids differ (HF attention-mask /
-    packed-sequence semantics): sq [BQ, 1] int32, sk [BK, 1] int32."""
-    valid = sq_ref[0] == sk_ref[0][:, 0][None, :]   # [BQ, BK]
-    return jnp.where(valid, s, _NEG_INF)
-
-
 def _window_first_k(qi, block_q: int, block_k: int, window: int):
     """First k-block index that intersects the sliding-window band of q
     block ``qi``: floor((qi*BQ - (W-1)) / BK), clamped to 0."""
     return jnp.maximum((qi * block_q - (window - 1)) // block_k, 0)
+
+
+# ---------------------------------------------------------------------------
+# The visit rule: which sub-tiles of a grid block the band touches.  Pure
+# Python on ints — a grid step's position enters only through the offset
+# d = (first q row) - (first k column) of its block, and the kernels are
+# specialised at trace time for each distinct answer (``_step_plans``).
+# ---------------------------------------------------------------------------
+
+def _sub_tile(bq: int, bk: int):
+    """(sq, sk): the sub-tile of a [bq, bk] grid block — the tuned square
+    where its edge divides the block's side; a side it does not divide
+    (short or odd T) is not split."""
+    s = _SUB_TILE
+    return (s if bq % s == 0 else bq, s if bk % s == 0 else bk)
+
+
+def _k_tile_range(r0: int, c_base: int, nks: int, sq: int, sk: int,
+                  causal, window):
+    """``[lo, hi)``: the k sub-tiles (``nks`` of ``sk`` columns, from
+    column ``c_base``) that q rows ``[r0, r0 + sq)`` visit — up to the one
+    holding the last row's diagonal, from the one holding the first row's
+    window start.  Empty (``hi <= lo``) when the block is outside the
+    band."""
+    lo, hi = 0, nks
+    if causal:
+        hi = min(nks, max(r0 + sq - 1 - c_base + sk, 0) // sk)
+        if window is not None:
+            lo = max(r0 - (window - 1) - c_base, 0) // sk
+    return lo, hi
+
+
+def _q_tile_range(c0: int, r_base: int, nqs: int, sq: int, sk: int,
+                  causal, window):
+    """``[lo, hi)``: the q sub-tiles (``nqs`` of ``sq`` rows, from row
+    ``r_base``) that visit k columns ``[c0, c0 + sk)`` — the transpose of
+    ``_k_tile_range``, for the dk/dv kernel."""
+    lo, hi = 0, nqs
+    if causal:
+        lo = max(c0 - r_base, 0) // sq
+        if window is not None:
+            hi = min(nqs, max(
+                c0 + sk - 1 + (window - 1) - r_base + sq, 0) // sq)
+    return lo, hi
+
+
+def _needs_mask(r0: int, c0: int, sq: int, sk: int, causal, window) -> bool:
+    """Does the band's edge cross the visited tile rows ``[r0, r0+sq)`` x
+    cols ``[c0, c0+sk)`` — i.e. is any of its positions masked?"""
+    if not causal:
+        return False
+    crossed = c0 + sk - 1 > r0
+    if window is not None:
+        crossed = crossed or r0 + sq - 1 - c0 >= window
+    return crossed
+
+
+def tile_visits(T: int, bq: int, bk: int, s, causal: bool, window=None):
+    """The visit rule as a count: ``({(qi, kj): visited}, total)`` — for
+    every grid block of a ``T x T`` score square cut into ``[bq, bk]``
+    blocks, how many of its ``total`` sub-tiles (``s x s``, or ``s =
+    (sq, sk)``) the kernels compute."""
+    sq, sk = (s, s) if isinstance(s, int) else s
+    nqs, nks = bq // sq, bk // sk
+    visited = {}
+    for qi in range(T // bq):
+        for kj in range(T // bk):
+            n = 0
+            for a in range(nqs):
+                lo, hi = _k_tile_range(qi * bq + a * sq, kj * bk, nks, sq,
+                                       sk, causal, window)
+                n += max(hi - lo, 0)
+            visited[(qi, kj)] = n
+    return visited, nqs * nks
+
+
+def _record_tiles(kernel: str, T, bq, bk, sub, causal, window) -> None:
+    """Trace-time record of what this ``pallas_call`` build visits: tiles
+    per head, summed over the grid."""
+    visited, total = tile_visits(T, bq, bk, sub, causal, window)
+    reg = get_registry()
+    reg.gauge("flash.tiles_visited", kernel=kernel).set(
+        sum(visited.values()))
+    reg.gauge("flash.tiles_total", kernel=kernel).set(total * len(visited))
+
+
+def _strips(d: int, n_outer: int, span, always_mask: bool):
+    """The work of a grid step at offset ``d`` as strips ``(t0, t1, lo, hi,
+    masked)``: outer sub-tiles ``[t0, t1)`` against the inner sub-tiles
+    ``[lo, hi)`` they visit (``span(d, t) -> lo, hi, mask flag per inner
+    tile``), computed as ONE matmul each — the softmax bookkeeping runs
+    once per row and the MXU sees the longest operands the band allows.
+    Neighbouring outer tiles that visit the same range the same way share
+    a strip, so a block wholly inside the band (and every non-causal one)
+    is a single strip: the whole block."""
+    strips = []
+    for t in range(n_outer):
+        lo, hi, masked = span(d, t)
+        if hi <= lo:
+            continue
+        masked = tuple(always_mask or m for m in masked)
+        if strips and strips[-1][1] == t and strips[-1][2:] == (lo, hi,
+                                                                 masked):
+            strips[-1] = (strips[-1][0], t + 1, lo, hi, masked)
+        else:
+            strips.append((t, t + 1, lo, hi, masked))
+    return tuple(strips)
+
+
+def _step_plans(nq: int, nk: int, bq: int, bk: int, strips_at):
+    """``[(d_lo, d_hi, strips)]``: the distinct non-empty plans over the
+    grid's offsets ``d = qi*bq - kj*bk``, each with the run of offsets it
+    serves (plans change monotonically with ``d``: skipped, diagonal,
+    interior, window edge, skipped)."""
+    runs = []
+    for d in sorted({qi * bq - kj * bk
+                     for qi in range(nq) for kj in range(nk)}):
+        strips = strips_at(d)
+        if runs and runs[-1][2] == strips:
+            runs[-1] = (runs[-1][0], d, strips)
+        else:
+            runs.append((d, d, strips))
+    return [run for run in runs if run[2]]
+
+
+def _q_major_plans(nq, nk, bq, bk, sub, causal, window, always_mask):
+    """Plans of the forward and dq kernels: a strip is q sub-tiles (rows)
+    against the k sub-tiles they visit."""
+    sq, sk = sub
+
+    def span(d, a):
+        lo, hi = _k_tile_range(d + a * sq, 0, bk // sk, sq, sk, causal,
+                               window)
+        return lo, hi, [_needs_mask(d + a * sq, b * sk, sq, sk, causal,
+                                    window) for b in range(lo, hi)]
+
+    return _step_plans(nq, nk, bq, bk, lambda d: _strips(
+        d, bq // sq, span, always_mask))
+
+
+def _k_major_plans(nq, nk, bq, bk, sub, causal, window, always_mask):
+    """Plans of the dk/dv kernel: a strip is k sub-tiles (columns)
+    against the q sub-tiles that visit them."""
+    sq, sk = sub
+
+    def span(d, b):
+        lo, hi = _q_tile_range(b * sk, d, bq // sq, sq, sk, causal, window)
+        return lo, hi, [_needs_mask(d + a * sq, b * sk, sq, sk, causal,
+                                    window) for a in range(lo, hi)]
+
+    return _step_plans(nq, nk, bq, bk, lambda d: _strips(
+        d, bk // sk, span, always_mask))
+
+
+def _grid_pos(axis: int, n: int):
+    """This step's index along a grid axis: 0 where the axis has one
+    block (a Python int, so conditions on it fold), else the program
+    id."""
+    return 0 if n == 1 else pl.program_id(axis)
+
+
+def _when(cond):
+    """``pl.when`` that folds a trace-time condition."""
+    if isinstance(cond, bool):
+        return lambda f: f() if cond else None
+    return pl.when(cond)
+
+
+def _for_each_strip(plans, d, body):
+    """Run ``body(t0, t1, lo, hi, masked)`` over the strips of the plan
+    that serves offset ``d`` (a Python int, or traced from program ids)."""
+    for d_lo, d_hi, strips in plans:
+        @_when((d >= d_lo) & (d <= d_hi))
+        def _(strips=strips):
+            for strip in strips:
+                body(*strip)
 
 
 def _band_mask(s, row, col, causal: bool, window):
@@ -108,89 +347,137 @@ def _band_mask(s, row, col, causal: bool, window):
     return s
 
 
-def _fwd_kernel(q_ref, k_ref, v_ref, *rest, nk: int, causal: bool,
-                scale: float, has_seg: bool, has_alibi: bool = False,
-                window=None):
-    idx = 0
-    if has_seg:
-        sq_ref, sk_ref = rest[0], rest[1]
-        idx = 2
-    else:
-        sq_ref = sk_ref = None
-    if has_alibi:
-        slope_ref = rest[idx]
-        idx += 1
-    else:
-        slope_ref = None
-    o_ref, lse_ref, acc_ref, m_ref, l_ref = rest[idx:]
-    # grid (BH, nq, nk), k innermost ("arbitrary"): Mosaic pipelines the
-    # K/V HBM→VMEM copies against compute; the online-softmax carry lives
-    # in VMEM scratch across k steps.  q/o blocks: [1, BQ, D]; k/v block:
-    # [1, BK, D]; lse: [1, BQ, 1].
-    #
-    # MXU dtype discipline: the dots run in the INPUT dtype (bf16 inputs →
-    # bf16 MXU passes at full rate) with fp32 accumulation via
-    # preferred_element_type; only the softmax bookkeeping is fp32 —
-    # the standard flash-attention-2 arrangement (p cast back to the value
-    # dtype for the second dot).
-    qi = pl.program_id(1)
-    j = pl.program_id(2)
-    block_q = q_ref.shape[1]
-    block_k = k_ref.shape[1]
+class _Extras:
+    """The optional kernel operands (segment ids of the q and k side, the
+    head's ALiBi slope) peeled off the front of ``*rest``."""
 
-    @pl.when(j == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-
-    # causal: k blocks strictly above the diagonal contribute nothing —
-    # skip compute entirely (their DMA was also elided by the clamped
-    # index map in _flash_forward); sliding window additionally prunes
-    # blocks entirely left of the band
-    compute = (j * block_k <= qi * block_q + block_q - 1) if causal else True
-    if window is not None:
-        compute = compute & (
-            j * block_k + block_k - 1 >= qi * block_q - (window - 1))
-
-    @pl.when(compute)
-    def _step():
-        q = q_ref[0]
-        k = k_ref[0]
-        v = v_ref[0]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * scale  # [BQ, BK] fp32
-        if causal or has_alibi:
-            row = qi * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            col = j * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            if has_alibi:
-                # ALiBi: slope_h * (j - i), 0 on the diagonal, more
-                # negative with distance — computed in-kernel, no bias
-                # tensor ever exists in HBM
-                s = s + slope_ref[0, 0, 0] * (col - row).astype(jnp.float32)
-            s = _band_mask(s, row, col, causal, window)
+    def __init__(self, rest, has_seg: bool, has_alibi: bool,
+                 k_side_first: bool = False):
+        self.sq_ref = self.sk_ref = self.slope_ref = None
+        n = 0
         if has_seg:
-            s = _seg_mask(sq_ref, sk_ref, s)
-        m = m_ref[...]
-        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
-        alpha = jnp.exp(m - m_new)
-        p = jnp.exp(s - m_new)
-        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        m_ref[...] = m_new
-        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
+            self.sq_ref, self.sk_ref = rest[0], rest[1]
+            if k_side_first:
+                self.sq_ref, self.sk_ref = self.sk_ref, self.sq_ref
+            n = 2
+        if has_alibi:
+            self.slope_ref = rest[n]
+            n += 1
+        self.rest = rest[n:]
 
-    @pl.when(j == nk - 1)
-    def _finish():
-        l = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
-        lse_ref[0] = m_ref[...] + jnp.log(l)  # [BQ, 1]
+
+def _strip_scores(q, k, scale, ex: _Extras, rows, cols, r0, c0, masked,
+                  axis: int, tile: int, causal: bool, window):
+    """Scaled scores of one strip, fp32 ``[R, C]``, its first row ``r0``
+    and first column ``c0`` (relative to the k block's first column;
+    ``r0`` is traced where the plan serves several offsets).  ``masked``
+    flags the strip's sub-tiles (``tile`` wide along ``axis``) the band's
+    edge crosses: only those build iotas and select; under segment ids or
+    ALiBi every flag is set and the whole strip takes the mask.
+
+    MXU dtype discipline: the dots run in the INPUT dtype (bf16 inputs →
+    bf16 MXU passes at full rate) with fp32 accumulation via
+    preferred_element_type; only the softmax bookkeeping is fp32 — the
+    standard flash-attention-2 arrangement."""
+    s = lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                        preferred_element_type=jnp.float32) * scale
+
+    def band(x, r, c):
+        row = r + lax.broadcasted_iota(jnp.int32, x.shape, 0)
+        col = c + lax.broadcasted_iota(jnp.int32, x.shape, 1)
+        if ex.slope_ref is not None:
+            # ALiBi: slope_h * (j - i), 0 on the diagonal, more negative
+            # with distance — computed in-kernel, no bias tensor ever
+            # exists in HBM
+            x = x + ex.slope_ref[0, 0, 0] * (col - row).astype(jnp.float32)
+        return _band_mask(x, row, col, causal, window)
+
+    if all(masked):
+        if causal or ex.slope_ref is not None:
+            s = band(s, r0, c0)
+        if ex.sq_ref is not None:
+            # q and k segment ids differ → masked (HF attention-mask /
+            # packed-sequence semantics): sq [R, 1] int32, sk [C, 1] int32
+            valid = (ex.sq_ref[0, rows, :]
+                     == ex.sk_ref[0, cols, :][:, 0][None, :])
+            s = jnp.where(valid, s, _NEG_INF)
+        return s
+    pieces, start = [], 0
+    for t in range(1, len(masked) + 1):   # runs of equal flags
+        if t < len(masked) and masked[t] == masked[start]:
+            continue
+        piece = lax.slice_in_dim(s, start * tile, t * tile, axis=axis)
+        if masked[start]:
+            piece = band(piece, r0 + (start * tile if axis == 0 else 0),
+                         c0 + (start * tile if axis == 1 else 0))
+        pieces.append(piece)
+        start = t
+    return pieces[0] if len(pieces) == 1 else jnp.concatenate(pieces, axis)
+
+
+def _fwd_kernel(q_ref, k_ref, v_ref, *rest, nq: int, nk: int, plans, sub,
+                causal: bool, scale: float, has_seg: bool,
+                has_alibi: bool = False, window=None):
+    ex = _Extras(rest, has_seg, has_alibi)
+    o_ref, lse_ref, *carry = ex.rest
+    # grid (BH, nq, nk), k innermost ("arbitrary"): Mosaic pipelines the
+    # K/V HBM→VMEM copies against compute; the online-softmax carry
+    # (acc, running max, running sum) lives in VMEM scratch across k
+    # steps — and does not exist where one k block holds the whole
+    # sequence (``nk == 1``): a strip then sees its rows' whole softmax
+    # and writes o and lse itself.  q/o blocks: [1, BQ, D]; k/v block:
+    # [1, BK, D]; lse: [1, BQ, 1].
+    qi = _grid_pos(1, nq)
+    j = _grid_pos(2, nk)
+    sq, sk = sub
+
+    def finish(rows, m, l, acc):
+        l = jnp.maximum(l, 1e-30)
+        o_ref[0, rows, :] = (acc / l).astype(o_ref.dtype)
+        lse_ref[0, rows, :] = m + jnp.log(l)          # [R, 1]
+
+    if carry:
+        acc_ref, m_ref, l_ref = carry
+
+        @pl.when(j == 0)
+        def _init():
+            acc_ref[...] = jnp.zeros_like(acc_ref)
+            m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
+            l_ref[...] = jnp.zeros_like(l_ref)
+
+    # a step outside the band has no plan and does nothing (its DMA was
+    # also elided by the clamped index map in _flash_forward)
+    d = qi * q_ref.shape[1] - j * k_ref.shape[1]
+
+    def strip(a0, a1, lo, hi, masked):
+        rows = pl.ds(a0 * sq, (a1 - a0) * sq)
+        cols = pl.ds(lo * sk, (hi - lo) * sk)
+        v = v_ref[0, cols, :]
+        s = _strip_scores(q_ref[0, rows, :], k_ref[0, cols, :], scale, ex,
+                          rows, cols, d + a0 * sq, lo * sk, masked, 1, sk,
+                          causal, window)
+        m_new = jnp.max(s, axis=-1, keepdims=True)
+        if carry:
+            m_old = m_ref[rows, :]
+            m_new = jnp.maximum(m_old, m_new)
+        p = jnp.exp(s - m_new)
+        l = jnp.sum(p, axis=-1, keepdims=True)
+        acc = lax.dot_general(
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        if not carry:
+            return finish(rows, m_new, l, acc)
+        alpha = jnp.exp(m_old - m_new)
+        l_ref[rows, :] = l_ref[rows, :] * alpha + l
+        acc_ref[rows, :] = acc_ref[rows, :] * alpha + acc
+        m_ref[rows, :] = m_new
+
+    _for_each_strip(plans, d, strip)
+
+    if carry:
+        @pl.when(j == nk - 1)
+        def _finish():
+            finish(slice(None), m_ref[...], l_ref[...], acc_ref[...])
 
 
 def _gqa_group(q, k):
@@ -218,19 +505,18 @@ def _check_band_args(causal, window, alibi_slopes, H):
                 f"alibi_slopes must be [H]={H}, got {alibi_slopes.shape}")
 
 
-def _flash_forward(q, k, v, causal, scale, block_q, block_k, interpret,
-                   segment_ids=None, window=None, alibi_slopes=None):
-    interpret = resolve_interpret(interpret, "flash_attention forward")
-    B, T, H, D = q.shape
-    H, Hkv, group = _gqa_group(q, k)
-    _check_band_args(causal, window, alibi_slopes, H)
-    bq = _fit_block(block_q, T)
-    bk = _fit_block(block_k, T)
-    nk = T // bk
-    # fold heads into the batch grid dim; [B, T, H, D] -> [B*H, T, D]
-    qf = q.transpose(0, 2, 1, 3).reshape(B * H, T, D)
-    kf = k.transpose(0, 2, 1, 3).reshape(B * Hkv, T, D)
-    vf = v.transpose(0, 2, 1, 3).reshape(B * Hkv, T, D)
+@functools.lru_cache(maxsize=64)
+def _forward_call(B, T, H, Hkv, D, dtype, bq, bk, sub, causal, scale,
+                  interpret, has_seg, window, has_alibi):
+    """The forward ``pallas_call`` of one static configuration.  Cached:
+    every layer of a model calls the SAME object, so JAX traces the kernel
+    and lowers it to Mosaic once a program, not once a layer (the
+    callable is a ``jit``; a fresh one per layer missed its cache 24
+    times in a 24-layer step)."""
+    group = H // Hkv
+    nq, nk = T // bq, T // bk
+    plans = _q_major_plans(nq, nk, bq, bk, sub, causal, window,
+                           has_seg or has_alibi)
 
     def kv_row(b):
         return (b // H) * Hkv + (b % H) // group
@@ -262,28 +548,21 @@ def _flash_forward(q, k, v, causal, scale, block_q, block_k, interpret,
         pl.BlockSpec((1, bk, D), kv_idx),
         pl.BlockSpec((1, bk, D), kv_idx),
     ]
-    operands = [qf, kf, vf]
-    if segment_ids is not None:
-        seg = segment_ids.astype(jnp.int32)[..., None]   # [B, T, 1]
+    if has_seg:
         in_specs += [
             pl.BlockSpec((1, bq, 1), lambda b, i, j: (b // H, i, 0)),
             pl.BlockSpec((1, bk, 1), sk_idx),
         ]
-        operands += [seg, seg]
-    if alibi_slopes is not None:
-        slopes_f = jnp.tile(alibi_slopes.astype(jnp.float32),
-                            B)[:, None, None]            # [B*H, 1, 1]
+    if has_alibi:
         in_specs += [pl.BlockSpec((1, 1, 1), lambda b, i, j: (b, 0, 0))]
-        operands += [slopes_f]
 
-    kernel = functools.partial(
-        _fwd_kernel, nk=nk, causal=causal, scale=scale,
-        has_seg=segment_ids is not None,
-        has_alibi=alibi_slopes is not None, window=window)
-    o, lse = pl.pallas_call(
-        kernel,
+    return pl.pallas_call(
+        functools.partial(
+            _fwd_kernel, nq=nq, nk=nk, plans=plans, sub=sub, causal=causal,
+            scale=scale, has_seg=has_seg, has_alibi=has_alibi,
+            window=window),
         name="flash_fwd",
-        grid=(B * H, T // bq, nk),
+        grid=(B * H, nq, nk),
         in_specs=in_specs,
         out_specs=[
             pl.BlockSpec((1, bq, D), lambda b, i, j: (b, i, 0)),
@@ -292,19 +571,42 @@ def _flash_forward(q, k, v, causal, scale, block_q, block_k, interpret,
             pl.BlockSpec((1, bq, 1), lambda b, i, j: (b, i, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((B * H, T, D), q.dtype),
+            jax.ShapeDtypeStruct((B * H, T, D), dtype),
             jax.ShapeDtypeStruct((B * H, T, 1), jnp.float32),
         ],
-        scratch_shapes=[
+        scratch_shapes=[] if nk == 1 else [
             pltpu.VMEM((bq, D), jnp.float32),   # acc
             pltpu.VMEM((bq, 1), jnp.float32),   # running max
             pltpu.VMEM((bq, 1), jnp.float32),   # running sum
         ],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
-        ),
+        compiler_params=_ARBITRARY_INNER,
         interpret=interpret,
-    )(*operands)
+    )
+
+
+def _flash_forward(q, k, v, causal, scale, block_q, block_k, interpret,
+                   segment_ids=None, window=None, alibi_slopes=None):
+    interpret = resolve_interpret(interpret, "flash_attention forward")
+    B, T, H, D = q.shape
+    H, Hkv, _ = _gqa_group(q, k)
+    _check_band_args(causal, window, alibi_slopes, H)
+    bq = _fit_block(block_q, T)
+    bk = _fit_block(block_k, T)
+    sub = _sub_tile(bq, bk)
+    _record_tiles("fwd", T, bq, bk, sub, causal, window)
+    # fold heads into the batch grid dim; [B, T, H, D] -> [B*H, T, D]
+    operands = [q.transpose(0, 2, 1, 3).reshape(B * H, T, D),
+                k.transpose(0, 2, 1, 3).reshape(B * Hkv, T, D),
+                v.transpose(0, 2, 1, 3).reshape(B * Hkv, T, D)]
+    if segment_ids is not None:
+        seg = segment_ids.astype(jnp.int32)[..., None]   # [B, T, 1]
+        operands += [seg, seg]
+    if alibi_slopes is not None:
+        operands += [jnp.tile(alibi_slopes.astype(jnp.float32),
+                              B)[:, None, None]]         # [B*H, 1, 1]
+    o, lse = _forward_call(
+        B, T, H, Hkv, D, q.dtype, bq, bk, sub, causal, scale, interpret,
+        segment_ids is not None, window, alibi_slopes is not None)(*operands)
     return o.reshape(B, H, T, D).transpose(0, 2, 1, 3), lse[..., 0]
 
 
@@ -368,153 +670,237 @@ def _fwd_rule(q, k, v, causal, scale, block_q, block_k, interpret,
 
 
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
-                   nk: int, causal: bool, scale: float, has_seg: bool,
-                   has_alibi: bool = False, window=None):
-    """dq accumulation over the k-block grid dim (innermost): recompute
-    the [BQ, BK] score slice, accumulate dq = scale * sum_j ds_j @ k_j in
-    VMEM scratch; same 3-D-grid pipelining as the forward."""
-    idx = 0
-    if has_seg:
-        sq_ref, sk_ref = rest[0], rest[1]
-        idx = 2
-    else:
-        sq_ref = sk_ref = None
-    if has_alibi:
-        slope_ref = rest[idx]
-        idx += 1
-    else:
-        slope_ref = None
-    dq_ref, dq_acc_ref = rest[idx:]
-    qi = pl.program_id(1)
-    j = pl.program_id(2)
-    block_q = q_ref.shape[1]
-    block_k = k_ref.shape[1]
+                   nq: int, nk: int, plans, sub, causal: bool, scale: float,
+                   has_seg: bool, has_alibi: bool = False, window=None):
+    """dq accumulation over the k-block grid dim (innermost): per strip
+    of q rows, recompute the scores of the k columns it visits and
+    accumulate dq = scale * sum_j ds_j @ k_j in VMEM scratch; same
+    3-D-grid pipelining as the forward."""
+    ex = _Extras(rest, has_seg, has_alibi)
+    dq_ref, *carry = ex.rest        # no accumulator where nk == 1
+    qi = _grid_pos(1, nq)
+    j = _grid_pos(2, nk)
+    sq, sk = sub
 
-    @pl.when(j == 0)
-    def _init():
-        dq_acc_ref[...] = jnp.zeros_like(dq_acc_ref)
+    if carry:
+        dq_acc_ref, = carry
 
-    compute = (j * block_k <= qi * block_q + block_q - 1) if causal else True
-    if window is not None:
-        compute = compute & (
-            j * block_k + block_k - 1 >= qi * block_q - (window - 1))
+        @pl.when(j == 0)
+        def _init():
+            dq_acc_ref[...] = jnp.zeros_like(dq_acc_ref)
 
-    @pl.when(compute)
-    def _step():
-        q = q_ref[0]                                  # [BQ, D], input dtype
-        do = do_ref[0]                                # [BQ, D], input dtype
-        lse = lse_ref[0].astype(jnp.float32)          # [BQ, 1]
-        delta = delta_ref[0].astype(jnp.float32)      # [BQ, 1]
-        k = k_ref[0]
-        v = v_ref[0]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * scale  # [BQ, BK] fp32
-        if causal or has_alibi:
-            row = qi * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            col = j * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            if has_alibi:
-                s = s + slope_ref[0, 0, 0] * (col - row).astype(jnp.float32)
-            s = _band_mask(s, row, col, causal, window)
-        if has_seg:
-            s = _seg_mask(sq_ref, sk_ref, s)
-        p = jnp.exp(s - lse)
-        dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )  # [BQ, BK] fp32
-        ds = p * (dp - delta)
-        dq_acc_ref[...] = dq_acc_ref[...] + jax.lax.dot_general(
+    d = qi * q_ref.shape[1] - j * k_ref.shape[1]
+
+    def strip(a0, a1, lo, hi, masked):
+        rows = pl.ds(a0 * sq, (a1 - a0) * sq)
+        cols = pl.ds(lo * sk, (hi - lo) * sk)
+        do = do_ref[0, rows, :]                       # [R, D], input dtype
+        k = k_ref[0, cols, :]                         # [C, D]
+        s = _strip_scores(q_ref[0, rows, :], k, scale, ex, rows, cols,
+                          d + a0 * sq, lo * sk, masked, 1, sk, causal,
+                          window)
+        p = jnp.exp(s - lse_ref[0, rows, :].astype(jnp.float32))
+        dp = lax.dot_general(
+            do, v_ref[0, cols, :], (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)       # [R, C] fp32
+        ds = p * (dp - delta_ref[0, rows, :].astype(jnp.float32))
+        dq = lax.dot_general(
             ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
+            preferred_element_type=jnp.float32)
+        if carry:
+            dq_acc_ref[rows, :] = dq_acc_ref[rows, :] + dq
+        else:
+            dq_ref[0, rows, :] = (dq * scale).astype(dq_ref.dtype)
 
-    @pl.when(j == nk - 1)
-    def _finish():
-        dq_ref[0] = (dq_acc_ref[...] * scale).astype(dq_ref.dtype)
+    _for_each_strip(plans, d, strip)
+
+    if carry:
+        @pl.when(j == nk - 1)
+        def _finish():
+            dq_ref[0] = (dq_acc_ref[...] * scale).astype(dq_ref.dtype)
 
 
 def _bwd_dkv_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, delta_ref, *rest,
-                    nq: int, causal: bool, scale: float, has_seg: bool,
-                    has_alibi: bool = False, window=None):
-    """dk/dv accumulation over the q-block grid dim (innermost; causal
-    pruning skips q blocks above the diagonal): dv = sum_i p_i^T @ do_i,
-    dk = scale * sum_i ds_i^T @ q_i, accumulated in VMEM scratch."""
-    idx = 0
-    if has_seg:
-        sk_ref, sq_ref = rest[0], rest[1]
-        idx = 2
-    else:
-        sq_ref = sk_ref = None
-    if has_alibi:
-        slope_ref = rest[idx]
-        idx += 1
-    else:
-        slope_ref = None
-    dk_ref, dv_ref, dk_acc_ref, dv_acc_ref = rest[idx:]
-    ki = pl.program_id(1)
-    i = pl.program_id(2)
-    block_k = k_ref.shape[1]
-    block_q = q_ref.shape[1]
+                    nk: int, nq: int, plans, sub, causal: bool,
+                    scale: float, has_seg: bool, has_alibi: bool = False,
+                    window=None):
+    """dk/dv accumulation over the q-block grid dim (innermost): per
+    strip of k columns, over the q rows at or below them that the band
+    reaches, dv = sum_i p_i^T @ do_i, dk = scale * sum_i ds_i^T @ q_i,
+    accumulated in VMEM scratch."""
+    ex = _Extras(rest, has_seg, has_alibi, k_side_first=True)
+    dk_ref, dv_ref, *carry = ex.rest    # no accumulators where nq == 1
+    ki = _grid_pos(1, nk)
+    i = _grid_pos(2, nq)
+    sq, sk = sub
 
-    @pl.when(i == 0)
-    def _init():
-        dk_acc_ref[...] = jnp.zeros_like(dk_acc_ref)
-        dv_acc_ref[...] = jnp.zeros_like(dv_acc_ref)
+    if carry:
+        dk_acc_ref, dv_acc_ref = carry
 
-    # causal: q blocks entirely above the diagonal see only masked
-    # scores; sliding window additionally prunes q blocks entirely
-    # below/right of the band
-    compute = (i * block_q + block_q - 1 >= ki * block_k) if causal else True
-    if window is not None:
-        compute = compute & (
-            i * block_q <= ki * block_k + block_k - 1 + (window - 1))
+        @pl.when(i == 0)
+        def _init():
+            dk_acc_ref[...] = jnp.zeros_like(dk_acc_ref)
+            dv_acc_ref[...] = jnp.zeros_like(dv_acc_ref)
 
-    @pl.when(compute)
-    def _step():
-        k = k_ref[0]                                  # [BK, D], input dtype
-        v = v_ref[0]                                  # [BK, D], input dtype
-        q = q_ref[0]
-        do = do_ref[0]
-        lse = lse_ref[0].astype(jnp.float32)
-        delta = delta_ref[0].astype(jnp.float32)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * scale  # [BQ, BK] fp32
-        if causal or has_alibi:
-            row = i * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            col = ki * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            if has_alibi:
-                s = s + slope_ref[0, 0, 0] * (col - row).astype(jnp.float32)
-            s = _band_mask(s, row, col, causal, window)
-        if has_seg:
-            s = _seg_mask(sq_ref, sk_ref, s)
-        p = jnp.exp(s - lse)                       # [BQ, BK] fp32
-        dv_acc_ref[...] = dv_acc_ref[...] + jax.lax.dot_general(
+    d = i * q_ref.shape[1] - ki * k_ref.shape[1]
+
+    def strip(b0, b1, lo, hi, masked):
+        cols = pl.ds(b0 * sk, (b1 - b0) * sk)
+        rows = pl.ds(lo * sq, (hi - lo) * sq)
+        q = q_ref[0, rows, :]                         # [R, D], input dtype
+        do = do_ref[0, rows, :]                       # [R, D]
+        s = _strip_scores(q, k_ref[0, cols, :], scale, ex, rows, cols,
+                          d + lo * sq, b0 * sk, masked, 0, sq, causal,
+                          window)
+        p = jnp.exp(s - lse_ref[0, rows, :].astype(jnp.float32))
+        dv = lax.dot_general(
             p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )  # [BK, D]
-        dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )  # [BQ, BK] fp32
-        ds = p * (dp - delta)
-        dk_acc_ref[...] = dk_acc_ref[...] + jax.lax.dot_general(
+            preferred_element_type=jnp.float32)       # [C, D]
+        dp = lax.dot_general(
+            do, v_ref[0, cols, :], (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)       # [R, C] fp32
+        ds = p * (dp - delta_ref[0, rows, :].astype(jnp.float32))
+        dk = lax.dot_general(
             ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )  # [BK, D]
-
-    @pl.when(i == nq - 1)
-    def _finish():
+            preferred_element_type=jnp.float32)       # [C, D]
         # s was scaled after the q·k dot, so dL/dk = scale * sum ds^T @ q
-        dk_ref[0] = (dk_acc_ref[...] * scale).astype(dk_ref.dtype)
-        dv_ref[0] = dv_acc_ref[...].astype(dv_ref.dtype)
+        if carry:
+            dk_acc_ref[cols, :] = dk_acc_ref[cols, :] + dk
+            dv_acc_ref[cols, :] = dv_acc_ref[cols, :] + dv
+        else:
+            dk_ref[0, cols, :] = (dk * scale).astype(dk_ref.dtype)
+            dv_ref[0, cols, :] = dv.astype(dv_ref.dtype)
+
+    _for_each_strip(plans, d, strip)
+
+    if carry:
+        @pl.when(i == nq - 1)
+        def _finish():
+            dk_ref[0] = (dk_acc_ref[...] * scale).astype(dk_ref.dtype)
+            dv_ref[0] = dv_acc_ref[...].astype(dv_ref.dtype)
+
+
+@functools.lru_cache(maxsize=64)
+def _dq_call(B, T, H, D, dtype, bq, bk, sub, causal, scale, interpret,
+             has_seg, window, has_alibi):
+    """The dq ``pallas_call`` of one static configuration (cached like
+    ``_forward_call``).  Operands: q, k, v, do, lse, delta[, seg, seg]
+    [, slopes]."""
+    nq, nk = T // bq, T // bk
+    plans = _q_major_plans(nq, nk, bq, bk, sub, causal, window,
+                           has_seg or has_alibi)
+
+    def k_block(i, j):
+        if causal:
+            j = jnp.minimum(j, _causal_last_k(i, bq, bk, nk))
+            if window is not None:
+                j = jnp.maximum(j, _window_first_k(i, bq, bk, window))
+        return j
+
+    def q_idx(b, i, j):
+        return (b, i, 0)
+
+    def kv_idx(b, i, j):
+        return (b, k_block(i, j), 0)
+
+    in_specs = [
+        pl.BlockSpec((1, bq, D), q_idx),      # q block
+        pl.BlockSpec((1, bk, D), kv_idx),     # k block
+        pl.BlockSpec((1, bk, D), kv_idx),     # v block
+        pl.BlockSpec((1, bq, D), q_idx),      # do block
+        pl.BlockSpec((1, bq, 1), q_idx),      # lse block
+        pl.BlockSpec((1, bq, 1), q_idx),      # delta
+    ]
+    if has_seg:
+        in_specs += [
+            pl.BlockSpec((1, bq, 1), lambda b, i, j: (b // H, i, 0)),
+            pl.BlockSpec((1, bk, 1),
+                         lambda b, i, j: (b // H, k_block(i, j), 0)),
+        ]
+    if has_alibi:
+        in_specs += [pl.BlockSpec((1, 1, 1), lambda b, i, j: (b, 0, 0))]
+
+    return pl.pallas_call(
+        functools.partial(_bwd_dq_kernel, nq=nq, nk=nk, plans=plans,
+                          sub=sub, causal=causal, scale=scale,
+                          has_seg=has_seg, has_alibi=has_alibi,
+                          window=window),
+        name="flash_bwd_dq",
+        grid=(B * H, nq, nk),
+        in_specs=in_specs,
+        out_specs=pl.BlockSpec((1, bq, D), q_idx),
+        out_shape=jax.ShapeDtypeStruct((B * H, T, D), dtype),
+        scratch_shapes=[] if nk == 1 else [
+            pltpu.VMEM((bq, D), jnp.float32)],
+        compiler_params=_ARBITRARY_INNER,
+        interpret=interpret,
+    )
+
+
+@functools.lru_cache(maxsize=64)
+def _dkv_call(B, T, H, D, k_dtype, v_dtype, bq, bk, sub, causal, scale,
+              interpret, has_seg, window, has_alibi):
+    """The dk/dv ``pallas_call`` of one static configuration (cached like
+    ``_forward_call``).  Operands: k, v, q, do, lse, delta[, seg, seg]
+    [, slopes]."""
+    nq, nk = T // bq, T // bk
+    plans = _k_major_plans(nq, nk, bq, bk, sub, causal, window,
+                           has_seg or has_alibi)
+
+    def q_block(ki, i):
+        if causal:
+            # clamp from below: first useful q block
+            i = jnp.maximum(i, (ki * bk) // bq)
+            if window is not None:
+                # clamp from above: last q block inside the band
+                i = jnp.minimum(i, jnp.minimum(
+                    (ki * bk + bk - 1 + window - 1) // bq, nq - 1))
+        return i
+
+    def kv_idx(b, ki, i):
+        return (b, ki, 0)
+
+    def q_idx(b, ki, i):
+        return (b, q_block(ki, i), 0)
+
+    in_specs = [
+        pl.BlockSpec((1, bk, D), kv_idx),     # k block
+        pl.BlockSpec((1, bk, D), kv_idx),     # v block
+        pl.BlockSpec((1, bq, D), q_idx),      # q block
+        pl.BlockSpec((1, bq, D), q_idx),      # do block
+        pl.BlockSpec((1, bq, 1), q_idx),      # lse
+        pl.BlockSpec((1, bq, 1), q_idx),      # delta
+    ]
+    if has_seg:
+        in_specs += [
+            pl.BlockSpec((1, bk, 1), lambda b, ki, i: (b // H, ki, 0)),
+            pl.BlockSpec((1, bq, 1),
+                         lambda b, ki, i: (b // H, q_block(ki, i), 0)),
+        ]
+    if has_alibi:
+        in_specs += [pl.BlockSpec((1, 1, 1), lambda b, ki, i: (b, 0, 0))]
+
+    return pl.pallas_call(
+        functools.partial(_bwd_dkv_kernel, nk=nk, nq=nq, plans=plans,
+                          sub=sub, causal=causal, scale=scale,
+                          has_seg=has_seg, has_alibi=has_alibi,
+                          window=window),
+        name="flash_bwd_dkv",
+        grid=(B * H, nk, nq),
+        in_specs=in_specs,
+        out_specs=[pl.BlockSpec((1, bk, D), kv_idx),
+                   pl.BlockSpec((1, bk, D), kv_idx)],
+        out_shape=[
+            jax.ShapeDtypeStruct((B * H, T, D), k_dtype),
+            jax.ShapeDtypeStruct((B * H, T, D), v_dtype),
+        ],
+        scratch_shapes=[] if nq == 1 else [
+            pltpu.VMEM((bk, D), jnp.float32),
+            pltpu.VMEM((bk, D), jnp.float32),
+        ],
+        compiler_params=_ARBITRARY_INNER,
+        interpret=interpret,
+    )
 
 
 def _flash_backward(q, k, v, o, lse, do, dlse, causal, scale, block_q,
@@ -527,8 +913,8 @@ def _flash_backward(q, k, v, o, lse, do, dlse, causal, scale, block_q,
     lse-returning variant ring attention differentiates through.
 
     ``dq_blocks``/``dkv_blocks`` override (block_q, block_k) per kernel —
-    the two kernels' VMEM pressure differs (3 live [BQ, BK] fp32 temps
-    each, but different stationary operands), so they tune independently.
+    the two kernels' VMEM pressure differs (3 live fp32 temps each, but
+    different stationary operands), so they tune independently.
 
     GQA backward materializes per-q-head k/v (one [B, T, H, D] transient
     each — the forward stays repeat-free) and group-sums dk/dv back to
@@ -546,6 +932,9 @@ def _flash_backward(q, k, v, o, lse, do, dlse, causal, scale, block_q,
     bq2, bk2 = dkv_blocks if dkv_blocks is not None else (block_q, block_k)
     bq1, bk1 = _fit_block(bq1, T), _fit_block(bk1, T)
     bq2, bk2 = _fit_block(bq2, T), _fit_block(bk2, T)
+    sub1, sub2 = _sub_tile(bq1, bk1), _sub_tile(bq2, bk2)
+    _record_tiles("bwd_dq", T, bq1, bk1, sub1, causal, window)
+    _record_tiles("bwd_dkv", T, bq2, bk2, sub2, causal, window)
 
     # fold batch & heads: [B, T, H, D] -> [BH, T, D]
     def fold(x):
@@ -559,123 +948,22 @@ def _flash_backward(q, k, v, o, lse, do, dlse, causal, scale, block_q,
         delta = delta - dlse
     lse3 = lse[..., None]                            # [BH, T, 1]
 
-    nk1, nq1 = T // bk1, T // bq1
-    nk2, nq2 = T // bk2, T // bq2
-    arb = pltpu.CompilerParams(
-        dimension_semantics=("parallel", "parallel", "arbitrary"))
-
-    if causal:
-        def kv_idx(b, i, j):
-            jj = jnp.minimum(j, _causal_last_k(i, bq1, bk1, nk1))
-            if window is not None:
-                jj = jnp.maximum(jj, _window_first_k(i, bq1, bk1, window))
-            return (b, jj, 0)
-
-        def q_idx(b, ki, i):  # clamp from below: first useful q block
-            ii = jnp.maximum(i, (ki * bk2) // bq2)
-            if window is not None:
-                # clamp from above: last q block inside the band
-                ii = jnp.minimum(
-                    ii, jnp.minimum(
-                        (ki * bk2 + bk2 - 1 + window - 1) // bq2, nq2 - 1))
-            return (b, ii, 0)
-    else:
-        def kv_idx(b, i, j):
-            return (b, j, 0)
-
-        def q_idx(b, ki, i):
-            return (b, i, 0)
-
     has_seg = segment_ids is not None
     has_alibi = alibi_slopes is not None
+    extras = []
     if has_seg:
         seg = segment_ids.astype(jnp.int32)[..., None]   # [B, T, 1]
+        extras += [seg, seg]
     if has_alibi:
-        slopes_f = jnp.tile(alibi_slopes.astype(jnp.float32),
-                            B)[:, None, None]            # [B*H, 1, 1]
+        extras += [jnp.tile(alibi_slopes.astype(jnp.float32),
+                            B)[:, None, None]]           # [B*H, 1, 1]
 
-    dq_specs = [
-        pl.BlockSpec((1, bq1, D), lambda b, i, j: (b, i, 0)),  # q block
-        pl.BlockSpec((1, bk1, D), kv_idx),                     # k block
-        pl.BlockSpec((1, bk1, D), kv_idx),                     # v block
-        pl.BlockSpec((1, bq1, D), lambda b, i, j: (b, i, 0)),  # do block
-        pl.BlockSpec((1, bq1, 1), lambda b, i, j: (b, i, 0)),  # lse block
-        pl.BlockSpec((1, bq1, 1), lambda b, i, j: (b, i, 0)),  # delta
-    ]
-    dq_ops = [qf, kf, vf, dof, lse3, delta]
-    if has_seg:
-        def skv_idx(b, i, j):
-            bi, ji, _ = kv_idx(b, i, j)
-            return (b // H, ji, 0)
-
-        dq_specs += [
-            pl.BlockSpec((1, bq1, 1), lambda b, i, j: (b // H, i, 0)),
-            pl.BlockSpec((1, bk1, 1), skv_idx),
-        ]
-        dq_ops += [seg, seg]
-    if has_alibi:
-        dq_specs += [pl.BlockSpec((1, 1, 1), lambda b, i, j: (b, 0, 0))]
-        dq_ops += [slopes_f]
-
-    dq = pl.pallas_call(
-        functools.partial(_bwd_dq_kernel, nk=nk1, causal=causal, scale=scale,
-                          has_seg=has_seg, has_alibi=has_alibi,
-                          window=window),
-        name="flash_bwd_dq",
-        grid=(B * H, nq1, nk1),
-        in_specs=dq_specs,
-        out_specs=pl.BlockSpec((1, bq1, D), lambda b, i, j: (b, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((B * H, T, D), q.dtype),
-        scratch_shapes=[pltpu.VMEM((bq1, D), jnp.float32)],
-        compiler_params=arb,
-        interpret=interpret,
-    )(*dq_ops)
-
-    dkv_specs = [
-        pl.BlockSpec((1, bk2, D), lambda b, ki, i: (b, ki, 0)),  # k block
-        pl.BlockSpec((1, bk2, D), lambda b, ki, i: (b, ki, 0)),  # v block
-        pl.BlockSpec((1, bq2, D), q_idx),                        # q block
-        pl.BlockSpec((1, bq2, D), q_idx),                        # do block
-        pl.BlockSpec((1, bq2, 1), q_idx),                        # lse
-        pl.BlockSpec((1, bq2, 1), q_idx),                        # delta
-    ]
-    dkv_ops = [kf, vf, qf, dof, lse3, delta]
-    if has_seg:
-        def sq_idx(b, ki, i):
-            bi, ii, _ = q_idx(b, ki, i)
-            return (b // H, ii, 0)
-
-        dkv_specs += [
-            pl.BlockSpec((1, bk2, 1), lambda b, ki, i: (b // H, ki, 0)),
-            pl.BlockSpec((1, bq2, 1), sq_idx),
-        ]
-        dkv_ops += [seg, seg]
-    if has_alibi:
-        dkv_specs += [pl.BlockSpec((1, 1, 1), lambda b, ki, i: (b, 0, 0))]
-        dkv_ops += [slopes_f]
-
-    dk, dv = pl.pallas_call(
-        functools.partial(_bwd_dkv_kernel, nq=nq2, causal=causal, scale=scale,
-                          has_seg=has_seg, has_alibi=has_alibi,
-                          window=window),
-        name="flash_bwd_dkv",
-        grid=(B * H, nk2, nq2),
-        in_specs=dkv_specs,
-        out_specs=[
-            pl.BlockSpec((1, bk2, D), lambda b, ki, i: (b, ki, 0)),
-            pl.BlockSpec((1, bk2, D), lambda b, ki, i: (b, ki, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((B * H, T, D), k.dtype),
-            jax.ShapeDtypeStruct((B * H, T, D), v.dtype),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((bk2, D), jnp.float32),
-            pltpu.VMEM((bk2, D), jnp.float32),
-        ],
-        compiler_params=arb,
-        interpret=interpret,
-    )(*dkv_ops)
+    dq = _dq_call(B, T, H, D, q.dtype, bq1, bk1, sub1, causal, scale,
+                  interpret, has_seg, window, has_alibi)(
+        qf, kf, vf, dof, lse3, delta, *extras)
+    dk, dv = _dkv_call(B, T, H, D, k.dtype, v.dtype, bq2, bk2, sub2, causal,
+                       scale, interpret, has_seg, window, has_alibi)(
+        kf, vf, qf, dof, lse3, delta, *extras)
 
     def unfold(x, dtype):
         return x.reshape(B, H, T, D).transpose(0, 2, 1, 3).astype(dtype)

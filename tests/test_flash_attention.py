@@ -63,22 +63,30 @@ def test_flash_bf16():
     )
 
 
-def _dense_ref(q, k, v, causal, seg=None):
-    """Dense reference with GQA expansion + segment masking."""
-    B, T, H, D = q.shape
-    Hkv = k.shape[2]
-    if Hkv != H:
-        k = jnp.repeat(k, H // Hkv, axis=2)
-        v = jnp.repeat(v, H // Hkv, axis=2)
-    s = jnp.einsum("bthd,bshd->bhts", q.astype(jnp.float32),
-                   k.astype(jnp.float32)) * (D ** -0.5)
+def _reference(q, k, v, causal, seg=None, window=None, slopes=None):
+    """Plain attention with every option the kernels take: GQA expansion,
+    causal / sliding-window band, ALiBi bias, segment ids."""
+    if k.shape[2] != q.shape[2]:
+        k = jnp.repeat(k, q.shape[2] // k.shape[2], axis=2)
+        v = jnp.repeat(v, q.shape[2] // v.shape[2], axis=2)
+    T, D = q.shape[1], q.shape[3]
+    s = jnp.einsum("bthd,bshd->bhts", q, k) * (D ** -0.5)
+    row, col = jnp.arange(T)[:, None], jnp.arange(T)[None, :]
+    if slopes is not None:
+        s = s + slopes[None, :, None, None] * (col - row)[None, None]
+    valid = jnp.ones((1, 1, T, T), bool)
     if causal:
-        s = jnp.where(jnp.tril(jnp.ones((T, T), bool))[None, None], s, -1e30)
+        valid = valid & (row >= col)
+        if window is not None:
+            valid = valid & (row - col < window)
     if seg is not None:
-        ok = seg[:, None, :, None] == seg[:, None, None, :]
-        s = jnp.where(ok, s, -1e30)
-    p = jax.nn.softmax(s, axis=-1)
-    return jnp.einsum("bhts,bshd->bthd", p, v.astype(jnp.float32))
+        valid = valid & (seg[:, None, :, None] == seg[:, None, None, :])
+    p = jax.nn.softmax(jnp.where(valid, s, -1e30), axis=-1)
+    return jnp.einsum("bhts,bshd->bthd", p, v)
+
+
+def _dense_ref(q, k, v, causal, seg=None):
+    return _reference(q, k, v, causal, seg)
 
 
 @pytest.mark.parametrize("causal", [False, True])
@@ -163,22 +171,7 @@ def test_bert_classifier_rides_flash_with_padding_mask():
 
 
 def _dense_ref_band(q, k, v, causal, window=None, slopes=None):
-    """Dense reference with sliding-window band + ALiBi bias."""
-    B, T, H, D = q.shape
-    s = jnp.einsum("bthd,bshd->bhts", q.astype(jnp.float32),
-                   k.astype(jnp.float32)) * (D ** -0.5)
-    row = jnp.arange(T)[:, None]
-    col = jnp.arange(T)[None, :]
-    if slopes is not None:
-        s = s + slopes[None, :, None, None] * (col - row)[None, None]
-    valid = jnp.ones((T, T), bool)
-    if causal:
-        valid = row >= col
-        if window is not None:
-            valid = valid & (row - col < window)
-    s = jnp.where(valid[None, None], s, -1e30)
-    p = jax.nn.softmax(s, axis=-1)
-    return jnp.einsum("bhts,bshd->bthd", p, v.astype(jnp.float32))
+    return _reference(q, k, v, causal, None, window, slopes)
 
 
 @pytest.mark.parametrize("window", [1, 16, 40, 64])
@@ -300,3 +293,266 @@ def test_attention_window_with_key_mask():
     mask = jnp.ones((2, 64), jnp.int32)
     with pytest.raises(ValueError):
         A(cfg).init(jax.random.PRNGKey(1), x, key_mask=mask)
+
+
+# --------------------------------------------------------------------------
+# Sub-tile pruning inside a grid block (PR 26): the visit rule, the kernels
+# that loop by it, and the gauges that say what a build visits.
+# --------------------------------------------------------------------------
+
+import sys  # noqa: E402
+
+from byteps_tpu.observability.metrics import get_registry  # noqa: E402
+
+# (``byteps_tpu.ops`` shadows the submodule with the function of its name)
+fa = sys.modules["byteps_tpu.ops.flash_attention"]
+
+
+# windows: none, narrower than a 32-wide sub-tile, crossing sub-tiles (and,
+# at block 64, grid blocks); ALiBi and windows need causal
+_SUB_TILE_CASES = [
+    (causal, window, extra)
+    for causal in (False, True)
+    for window in (None, 24, 50)
+    for extra in ("plain", "segment_ids", "alibi", "gqa_hkv1", "gqa_hkv2")
+    if causal or (window is None and extra != "alibi")]
+
+
+@pytest.mark.parametrize("block", [64, 128])
+@pytest.mark.parametrize("causal,window,extra", _SUB_TILE_CASES)
+def test_flash_sub_tiles_match_reference(monkeypatch, causal, window, extra,
+                                         block):
+    """Forward AND dq, dk, dv against the plain reference with 32-wide
+    sub-tiles: block 128 = one grid block of 4 x 4 sub-tiles (the plan is
+    folded at trace time, no scratch carry), block 64 = a 2 x 2 grid of
+    2 x 2 (the plan chosen from the program ids, carry in scratch)."""
+    monkeypatch.setattr(fa, "_SUB_TILE", 32)
+    B, T, H, D = 1, 128, 4, 32
+    hkv = {"gqa_hkv1": 1, "gqa_hkv2": 2}.get(extra, H)
+    ks = jax.random.split(jax.random.PRNGKey(26), 3)
+    q = jax.random.normal(ks[0], (B, T, H, D))
+    k = jax.random.normal(ks[1], (B, T, hkv, D))
+    v = jax.random.normal(ks[2], (B, T, hkv, D))
+    seg = slopes = None
+    if extra == "segment_ids":   # boundaries inside sub-tiles
+        seg = jnp.asarray(np.searchsorted([20, 75, 100], np.arange(T),
+                                          side="right"))[None]
+    if extra == "alibi":
+        slopes = jnp.asarray([2.0 ** -i for i in range(1, H + 1)],
+                             jnp.float32)
+
+    def flash(a, b, c):
+        return flash_attention(a, b, c, causal, None, block, block, True,
+                               seg, window, slopes)
+
+    def ref(a, b, c):
+        return _reference(a, b, c, causal, seg, window, slopes)
+
+    np.testing.assert_allclose(np.asarray(flash(q, k, v)),
+                               np.asarray(ref(q, k, v)),
+                               rtol=2e-4, atol=2e-5)
+    gf = jax.grad(lambda *a: jnp.sum(flash(*a) ** 2), (0, 1, 2))(q, k, v)
+    gr = jax.grad(lambda *a: jnp.sum(ref(*a) ** 2), (0, 1, 2))(q, k, v)
+    for a, b in zip(gf, gr):
+        assert a.shape == b.shape
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=2e-3, atol=2e-4)
+
+
+def test_flash_tuned_sub_tile_matches_reference():
+    """The same at the tuned sub-tile size: one block of 2 x 2 of them."""
+    T = 2 * fa._SUB_TILE
+    ks = jax.random.split(jax.random.PRNGKey(27), 3)
+    q, k, v = (jax.random.normal(kk, (1, T, 1, 64)) for kk in ks)
+    assert fa._sub_tile(T, T) == (T // 2, T // 2)
+
+    def flash(a, b, c):
+        return flash_attention(a, b, c, True, None, T, T, True)
+
+    np.testing.assert_allclose(np.asarray(flash(q, k, v)),
+                               np.asarray(_reference(q, k, v, True)),
+                               rtol=2e-4, atol=2e-5)
+    gf = jax.grad(lambda *a: jnp.sum(flash(*a) ** 2), (0, 1, 2))(q, k, v)
+    gr = jax.grad(lambda *a: jnp.sum(_reference(*a, True) ** 2),
+                  (0, 1, 2))(q, k, v)
+    for a, b in zip(gf, gr):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=2e-3, atol=2e-4)
+
+
+def test_tile_visits_counts():
+    """The cells' shape (T = 1024 = one block) and T = 4096 in 1024
+    blocks."""
+    assert fa.tile_visits(1024, 1024, 1024, 256, True) == ({(0, 0): 10}, 16)
+    assert fa.tile_visits(1024, 1024, 1024, 128, True) == ({(0, 0): 36}, 64)
+    assert fa.tile_visits(1024, 1024, 1024, 512, True) == ({(0, 0): 3}, 4)
+    assert fa.tile_visits(1024, 1024, 1024, 256, False) == ({(0, 0): 16}, 16)
+    # window 300 reaches back over one whole 256-wide tile into the next:
+    # the band only, up to three tiles a row
+    visited, total = fa.tile_visits(1024, 1024, 1024, 256, True, 300)
+    assert (visited, total) == ({(0, 0): 1 + 2 + 3 + 3}, 16)
+    visited, total = fa.tile_visits(4096, 1024, 1024, 256, True)
+    assert total == 16
+    for qi in range(4):
+        for kj in range(4):
+            want = 10 if qi == kj else 16 if kj < qi else 0
+            assert visited[(qi, kj)] == want, (qi, kj)
+    assert sum(visited.values()) == 16 * 17 // 2
+
+
+@pytest.mark.parametrize("causal,window", [
+    (False, None), (True, None), (True, 1), (True, 24), (True, 64),
+    (True, 65), (True, 100), (True, 300)])
+@pytest.mark.parametrize("T,bq,bk,sub", [
+    (256, 256, 256, (64, 64)), (256, 128, 128, (64, 64)),
+    (256, 64, 128, (32, 64)), (256, 128, 64, (64, 32)),
+    (192, 192, 192, (192, 192))])
+def test_visit_rule_is_exact(T, bq, bk, sub, causal, window):
+    """Both range functions and the mask test against brute force over
+    the band itself: a tile is visited iff it holds a valid position, and
+    takes the mask path iff it also holds an invalid one."""
+    sq, sk = sub
+    row, col = np.arange(T)[:, None], np.arange(T)[None, :]
+    valid = np.ones((T, T), bool)
+    if causal:
+        valid = row >= col
+        if window is not None:
+            valid = valid & (row - col < window)
+    visited, total = fa.tile_visits(T, bq, bk, sub, causal, window)
+    assert total == (bq // sq) * (bk // sk)
+    from_q_side = {key: 0 for key in visited}
+    for (qi, kj), n in visited.items():
+        tiles = set()
+        for a in range(bq // sq):
+            r0 = qi * bq + a * sq
+            lo, hi = fa._k_tile_range(r0, kj * bk, bk // sk, sq, sk,
+                                      causal, window)
+            tiles |= {(a, b) for b in range(lo, hi)}
+        want = set()
+        for a in range(bq // sq):
+            for b in range(bk // sk):
+                r0, c0 = qi * bq + a * sq, kj * bk + b * sk
+                tile = valid[r0:r0 + sq, c0:c0 + sk]
+                if tile.any():
+                    want.add((a, b))
+                    assert bool(fa._needs_mask(r0, c0, sq, sk, causal,
+                                               window)) == (not tile.all())
+        assert tiles == want and n == len(want)
+        for b in range(bk // sk):
+            lo, hi = fa._q_tile_range(kj * bk + b * sk, qi * bq, bq // sq,
+                                      sq, sk, causal, window)
+            assert {(a, b) for a in range(lo, hi)} == {
+                t for t in want if t[1] == b}
+            from_q_side[(qi, kj)] += max(hi - lo, 0)
+    assert from_q_side == visited
+
+
+def _plan_tiles(plans, d, k_major):
+    """{(a, b): masked} of the plan serving offset ``d`` (none: {})."""
+    tiles = {}
+    for d_lo, d_hi, strips in plans:
+        if d_lo <= d <= d_hi:
+            for t0, t1, lo, hi, masked in strips:
+                for t in range(t0, t1):
+                    for u, flag in zip(range(lo, hi), masked):
+                        tiles[(u, t) if k_major else (t, u)] = flag
+    return tiles
+
+
+@pytest.mark.parametrize("causal,window", [
+    (False, None), (True, None), (True, 24), (True, 100), (True, 300)])
+@pytest.mark.parametrize("T,bq,bk,sub", [
+    (256, 256, 256, (64, 64)), (512, 128, 128, (64, 64)),
+    (256, 64, 128, (32, 64)), (256, 128, 64, (64, 32))])
+def test_step_plans_cover_the_visit_rule(T, bq, bk, sub, causal, window):
+    """What the kernels execute — strips, specialised per grid offset —
+    is the visit rule: the same tiles, masked where the edge crosses."""
+    sq, sk = sub
+    nq, nk = T // bq, T // bk
+    for k_major, build in ((False, fa._q_major_plans),
+                           (True, fa._k_major_plans)):
+        plans = build(nq, nk, bq, bk, sub, causal, window, False)
+        runs = [(lo, hi) for lo, hi, _ in plans]
+        assert runs == sorted(runs) and all(
+            a[1] < b[0] for a, b in zip(runs, runs[1:]))
+        for qi in range(nq):
+            for kj in range(nk):
+                want = {}
+                for a in range(bq // sq):
+                    r0 = qi * bq + a * sq
+                    lo, hi = fa._k_tile_range(r0, kj * bk, bk // sk, sq, sk,
+                                              causal, window)
+                    for b in range(lo, hi):
+                        want[(a, b)] = fa._needs_mask(
+                            r0, kj * bk + b * sk, sq, sk, causal, window)
+                assert _plan_tiles(plans, qi * bq - kj * bk, k_major) == want
+
+
+def test_step_plans_at_the_tuned_shapes():
+    """T = 1024 causal is four ragged strips, masked on the diagonal
+    only; non-causal — and a block below the diagonal at T = 4096 — is
+    the whole block as one strip, no mask."""
+    args = (1024, 1024, (256, 256))
+    assert fa._q_major_plans(1, 1, *args, True, None, False) == [(0, 0, (
+        (0, 1, 0, 1, (True,)), (1, 2, 0, 2, (False, True)),
+        (2, 3, 0, 3, (False, False, True)),
+        (3, 4, 0, 4, (False, False, False, True))))]
+    assert fa._k_major_plans(1, 1, *args, True, None, False) == [(0, 0, (
+        (0, 1, 0, 4, (True, False, False, False)),
+        (1, 2, 1, 4, (True, False, False)), (2, 3, 2, 4, (True, False)),
+        (3, 4, 3, 4, (True,))))]
+    whole = ((0, 4, 0, 4, (False,) * 4),)
+    assert fa._q_major_plans(1, 1, *args, False, None, False) == [
+        (0, 0, whole)]
+    diag, interior = fa._q_major_plans(4, 4, *args, True, None, False)
+    assert diag[:2] == (0, 0) and len(diag[2]) == 4
+    assert interior == (1024, 3072, whole)
+    # segment ids / ALiBi: every tile masked, so equal ranges still merge
+    assert fa._q_major_plans(1, 1, *args, False, None, True) == [
+        (0, 0, ((0, 4, 0, 4, (True,) * 4),))]
+
+
+def test_sub_tile_leaves_an_undivided_side_whole():
+    s = fa._SUB_TILE
+    assert fa._sub_tile(4 * s, 2 * s) == (s, s)
+    assert fa._sub_tile(s + 8, 2 * s) == (s + 8, s)
+    assert fa._sub_tile(64, 64) == (64, 64)
+
+
+@pytest.mark.parametrize("causal", [True, False])
+def test_flash_tiles_gauges(causal):
+    """Each pallas_call build records what it visits, per kernel; at the
+    cells' shape that is the triangle of the tuned s, and everything for
+    a non-causal call.  Tracing alone records — nothing runs here."""
+    reg = get_registry()
+    reg.remove_prefix("flash.tiles_")
+    x = jax.ShapeDtypeStruct((1, 1024, 2, 64), jnp.bfloat16)
+    jax.eval_shape(jax.grad(lambda q, k, v: jnp.sum(flash_attention(
+        q, k, v, causal, interpret=True).astype(jnp.float32)), (0, 1, 2)),
+        x, x, x)
+    n = 1024 // fa._SUB_TILE
+    assert (n, n * (n + 1) // 2) == (4, 10)     # s = 256: 10 of 16
+    for kernel in ("fwd", "bwd_dq", "bwd_dkv"):
+        visited = reg.get("flash.tiles_visited", kernel=kernel).value
+        total = reg.get("flash.tiles_total", kernel=kernel).value
+        assert total == n * n
+        assert visited == (n * (n + 1) // 2 if causal else total)
+
+
+def test_layers_share_one_pallas_call_build():
+    """Three layers of one configuration trace and lower each kernel once:
+    the builders are cached, and the callable they return is a jit."""
+    x = jax.ShapeDtypeStruct((1, 128, 2, 32), jnp.float32)
+    builders = (fa._forward_call, fa._dq_call, fa._dkv_call)
+    before = [b.cache_info() for b in builders]
+
+    def loss(q, k, v):
+        for _ in range(3):
+            q = flash_attention(q, k, v, True, None, 64, 32, True)
+        return jnp.sum(q)
+
+    jax.eval_shape(jax.grad(loss, (0, 1, 2)), x, x, x)
+    for b, was in zip(builders, before):
+        now = b.cache_info()
+        assert now.misses - was.misses == 1
+        assert now.hits - was.hits == 2

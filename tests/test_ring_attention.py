@@ -125,6 +125,22 @@ def test_ring_flash_grad_matches_local():
 def test_flash_with_lse_grads():
     """flash_attention_with_lse is differentiable in BOTH outputs: compare
     against the dense (o, logsumexp) computation."""
+    _check_with_lse_grads(False, 16)
+
+
+@pytest.mark.parametrize("causal", [False, True])
+def test_flash_with_lse_grads_across_sub_tiles(monkeypatch, causal):
+    """The same with a non-zero ``dlse`` through a block of 4 x 4
+    sub-tiles — ring attention's diagonal block is the causal case, its
+    off-diagonal blocks the non-causal one."""
+    import sys
+
+    fa = sys.modules["byteps_tpu.ops.flash_attention"]
+    monkeypatch.setattr(fa, "_SUB_TILE", 8)
+    _check_with_lse_grads(causal, T)
+
+
+def _check_with_lse_grads(causal, block):
     from byteps_tpu.ops.flash_attention import flash_attention_with_lse
 
     q, k, v = _qkv(5)
@@ -132,12 +148,16 @@ def test_flash_with_lse_grads():
 
     def dense(q, k, v):
         s = jnp.einsum("bqhd,bkhd->bqhk", q * scale, k)
+        if causal:
+            s = jnp.where(jnp.tril(jnp.ones((T, T), bool))[None, :, None, :],
+                          s, -1e30)
         o = jnp.einsum("bqhk,bkhd->bqhd", jax.nn.softmax(s, axis=-1), v)
         lse = jax.scipy.special.logsumexp(s, axis=-1)  # [B, Tq, H]
         return jnp.sum(o ** 2) + jnp.sum(jnp.sin(lse))
 
     def flash(q, k, v):
-        o, lse = flash_attention_with_lse(q, k, v, False, None, 16, 16)
+        o, lse = flash_attention_with_lse(q, k, v, causal, None, block,
+                                          block)
         return jnp.sum(o ** 2) + jnp.sum(jnp.sin(lse))
 
     np.testing.assert_allclose(float(flash(q, k, v)), float(dense(q, k, v)),
