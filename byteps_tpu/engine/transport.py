@@ -812,8 +812,15 @@ class LocalEndpoints:
             # description past close() (and AF_UNIX shutdown() does
             # not wake it) — kick it through the closed-guard so the
             # rendezvous actually stops answering
+            # ... and do not return while it still does: the woken
+            # thread drops the listener a scheduling quantum later, and
+            # a supervised restart that probes the path in that window
+            # (a loaded host) reads the corpse as a live collision
             if self._spath is not None:
-                _kick_listener(self._spath)
+                for _ in range(20):
+                    _kick_listener(self._spath)
+                    if not _endpoint_alive(self._spath, timeout=0.05):
+                        break
         if unlink:
             for p in self._paths:
                 try:

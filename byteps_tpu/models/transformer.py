@@ -86,6 +86,47 @@ class TransformerConfig:
     # head_dim != hidden_size / num_heads); None derives it
     head_dim: Optional[int] = None
     mlp: str = "gelu"  # gelu | swiglu
+    # latent attention (DeepSeek-V2 report, section 2.1): queries through
+    # a ``q_lora_rank`` latent, keys and values through a
+    # ``kv_lora_rank`` latent, each head's key the latent's
+    # ``qk_nope_head_dim`` channels beside ONE ``qk_rope_head_dim``-wide
+    # rotary key that all heads share; values ``v_head_dim`` wide.
+    # ``rope_interleave`` rotates the adjacent channel pairs (2i, 2i+1)
+    # instead of the half-split pairs.  Training path only (no cache).
+    attn_kind: str = "mha"  # mha | mla
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    rope_interleave: bool = False
+    # fine-grained experts (parallel/moe.py): with ``moe_experts`` > 0
+    # the first ``dense_layers`` blocks keep the dense MLP and every
+    # later one routes each token to ``moe_top_k`` of ``moe_experts``
+    # SwiGLU experts of width ``moe_d_ff`` (sigmoid scores, weights
+    # normalised and multiplied by ``moe_scale``) beside ``moe_shared``
+    # shared experts.  ``moe_held = (first, count)`` is the contiguous
+    # slice of experts THIS rank holds (default: all): the router keeps
+    # all its outputs, the layer computes its own experts' part.  With
+    # ``moe_ep_axis`` (inside shard_map over that axis) the rank's
+    # offset is its axis index times ``count`` and the parts are
+    # exchanged and summed.
+    moe_experts: int = 0
+    moe_top_k: int = 0
+    moe_d_ff: int = 0
+    moe_shared: int = 0
+    moe_scale: float = 1.0
+    moe_held: Optional[tuple] = None
+    moe_ep_axis: Optional[str] = None
+    dense_layers: int = 0
+    # multi-token prediction (DeepSeek-V3 report, section 2.2): one module
+    # off the final hidden state that predicts the token after next;
+    # ``lm_loss_fn`` adds ``mtp_loss_weight`` times its cross-entropy
+    mtp_layers: int = 0
+    mtp_loss_weight: float = 0.3
+    # recompute each block in the backward pass instead of keeping its
+    # activations (``nn.remat``)
+    remat: bool = False
     # mesh axis names; attention shard_map uses (dp_axis, sp_axis, tp_axis)
     dp_axis: str = "dp"
     sp_axis: str = "sp"
@@ -124,6 +165,20 @@ class TransformerConfig:
             raise ValueError(f"unknown norm {self.norm!r}")
         return nn.RMSNorm(epsilon=self.norm_eps, dtype=self.dtype,
                           name=name)
+
+    def block_cls(self):
+        """``Block``, or with ``remat`` a block recomputed in the backward
+        pass — all of it but what is small to keep and dear to make
+        again: the flash forward kernel's output and log-sum-exp (the
+        kernel would otherwise run twice) and the expert layer's choice
+        and layout (a top-k and a sort)."""
+        if not self.remat:
+            return Block
+        from ..ops.flash_attention import FLASH_OUT
+        from ..parallel.moe import PLAN
+
+        return nn.remat(Block, policy=jax.checkpoint_policies.
+                        save_only_these_names(FLASH_OUT, PLAN))
 
     @property
     def has_sp(self) -> bool:
@@ -300,8 +355,11 @@ def _scaled_inv_freq(inv_freq, scaling):
     raise ValueError(f"unsupported rope_scaling type {rt!r}")
 
 
-def apply_rope(x, positions, theta: float = 10000.0, scaling=None):
-    """Rotary position embedding, HF half-split convention:
+def apply_rope(x, positions, theta: float = 10000.0, scaling=None,
+               interleave: bool = False):
+    """Rotary position embedding, HF half-split convention (or, with
+    ``interleave``, the adjacent pairs (x[2i], x[2i+1]) at the same
+    angles):
     ``x [B, T, H, D]`` rotated by per-position angles
     ``pos / theta^(2i/D)``; ``positions`` is ``[T]`` absolute offsets
     (prefill: ``arange(T)``; decode step: ``pos + arange(tq)``) or
@@ -327,6 +385,12 @@ def apply_rope(x, positions, theta: float = 10000.0, scaling=None):
     cos = jnp.cos(ang)[:, :, None, :]      # [1|B, T, 1, D/2]
     sin = jnp.sin(ang)[:, :, None, :]
     xf = x.astype(jnp.float32)
+    if interleave:
+        pairs = xf.reshape(xf.shape[:-1] + (half, 2))
+        x1, x2 = pairs[..., 0], pairs[..., 1]
+        return jnp.stack(
+            [x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1
+        ).reshape(xf.shape).astype(x.dtype)
     x1, x2 = xf[..., :half], xf[..., half:]
     return jnp.concatenate(
         [x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1
@@ -755,6 +819,54 @@ class Attention(nn.Module):
         return o_proj(out)
 
 
+class LatentAttention(nn.Module):
+    """Multi-head latent attention, the training path (no cache):
+
+        c_q = norm(x W_qa);   [q_nope | q_rope] = c_q W_qb      per head
+        [c_kv | k_rope] = x W_kva;   c_kv = norm(c_kv)
+        [k_nope | v] = c_kv W_kvb                               per head
+        q = [q_nope | rope(q_rope)],  k = [k_nope | rope(k_rope)]
+
+    with the one ``k_rope`` shared by every head.  q and k heads are
+    ``qk_nope_head_dim + qk_rope_head_dim`` wide, v heads ``v_head_dim``
+    (the flash kernels carry both widths); scores scale by the q width.
+    """
+
+    cfg: TransformerConfig
+
+    @nn.compact
+    def __call__(self, x, key_mask=None, cache=None, pos=None):
+        cfg = self.cfg
+        if cache is not None or key_mask is not None:
+            raise NotImplementedError(
+                "latent attention is built for training: no cache, no "
+                "key_mask")
+        H = cfg.num_heads
+        dn, dr, dv = (cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
+                      cfg.v_head_dim)
+        r = cfg.kv_lora_rank
+        dense = partial(QuantDense, dtype=cfg.dtype,
+                        kernel_init=nn.initializers.xavier_uniform())
+        c_q = cfg.make_norm("q_norm")(
+            dense(features=cfg.q_lora_rank, name="q_a")(x))
+        q = dense(features=(H, dn + dr), name="q_b")(c_q)
+        kv_a = dense(features=r + dr, name="kv_a")(x)
+        c_kv = cfg.make_norm("kv_norm")(kv_a[..., :r])
+        kv = dense(features=(H, dn + dv), name="kv_b")(c_kv)
+        rope = partial(apply_rope, positions=jnp.arange(x.shape[1]),
+                       theta=cfg.rope_theta, scaling=cfg.rope_scaling,
+                       interleave=cfg.rope_interleave)
+        k_rope = rope(kv_a[..., None, r:])               # [B, T, 1, dr]
+        q = jnp.concatenate([q[..., :dn], rope(q[..., dn:])], axis=-1)
+        k = jnp.concatenate(
+            [kv[..., :dn],
+             jnp.broadcast_to(k_rope, k_rope.shape[:2] + (H, dr))], axis=-1)
+        out = cfg.attention_fn()(q, k, kv[..., dn:])
+        return QuantDense(
+            features=cfg.d_model, in_axes=2, dtype=cfg.dtype, name="o",
+            kernel_init=nn.initializers.xavier_uniform())(out)
+
+
 class MLP(nn.Module):
     cfg: TransformerConfig
 
@@ -786,26 +898,101 @@ class MLP(nn.Module):
         )(h)
 
 
-class Block(nn.Module):
+class _Leaves(nn.Module):
+    """Named parameter leaves with no computation of their own:
+    ``((name, shape, initializer), ...)`` -> ``{name: array}``."""
+
+    leaves: tuple
+
+    @nn.compact
+    def __call__(self):
+        return {name: self.param(name, init, shape)
+                for name, shape, init in self.leaves}
+
+
+class ExpertLayer(nn.Module):
+    """The routed feed-forward of a block: the held experts' part
+    (``parallel/moe.py:expert_layer``) plus the shared expert.  Sows the
+    layer's two counts (``assignments_held``, ``rows_computed``) into the
+    ``moe_stats`` collection (when the caller makes it mutable)."""
+
     cfg: TransformerConfig
 
     @nn.compact
+    def __call__(self, x):
+        from ..parallel.moe import expert_layer
+
+        cfg = self.cfg
+        d, f, E = cfg.d_model, cfg.moe_d_ff, cfg.moe_experts
+        held = cfg.moe_held or (0, E)
+        init = nn.initializers.normal(stddev=0.02)
+        router = _Leaves((("kernel", (d, E), init),
+                          ("bias", (E,), nn.initializers.zeros)),
+                         name="router")()
+        w = _Leaves((("gate", (held[1], d, f), init),
+                     ("up", (held[1], d, f), init),
+                     ("down", (held[1], f, d), init)), name="experts")()
+        flat = x.reshape(-1, d)
+        y, counts = expert_layer(
+            flat, router["kernel"], router["bias"], w["gate"], w["up"],
+            w["down"], top_k=cfg.moe_top_k, scale=cfg.moe_scale,
+            held=held, axis_name=cfg.moe_ep_axis)
+        for name, n in zip(("assignments_held", "rows_computed"), counts):
+            self.sow("moe_stats", name, n, reduce_fn=lambda a, b: a + b,
+                     init_fn=lambda: jnp.zeros((), jnp.int32))
+        y = y.reshape(x.shape)
+        if cfg.moe_shared:
+            shared = dataclasses.replace(
+                cfg, mlp="swiglu", d_ff=f * cfg.moe_shared)
+            y = y + MLP(shared, name="shared")(x)
+        return y
+
+
+class Block(nn.Module):
+    cfg: TransformerConfig
+    experts: bool = False     # the feed-forward is an expert layer
+
+    @nn.compact
     def __call__(self, x, key_mask=None, cache=None, pos=None):
+        attention = (LatentAttention if self.cfg.attn_kind == "mla"
+                     else Attention)
         y = self.cfg.make_norm("ln1")(x)
         if cache is not None:
             if key_mask is not None:
                 raise ValueError(
                     "KV-cache decode does not support key_mask (pad K/V "
                     "would enter the cache as real context)")
-            attn_out, new_cache = Attention(self.cfg, name="attn")(
+            attn_out, new_cache = attention(self.cfg, name="attn")(
                 y, cache=cache, pos=pos)
             x = x + attn_out
         else:
             new_cache = None
-            x = x + Attention(self.cfg, name="attn")(y, key_mask=key_mask)
+            x = x + attention(self.cfg, name="attn")(y, key_mask=key_mask)
         y = self.cfg.make_norm("ln2")(x)
-        x = x + MLP(self.cfg, name="mlp")(y)
+        if self.experts:
+            x = x + ExpertLayer(self.cfg, name="moe")(y)
+        else:
+            x = x + MLP(self.cfg, name="mlp")(y)
         return (x, new_cache) if cache is not None else x
+
+
+class MTPModule(nn.Module):
+    """One multi-token-prediction depth: the final hidden state and the
+    NEXT token's embedding, each normalised, projected together to
+    ``d_model``, through one more block and a norm; the caller applies
+    the shared head."""
+
+    cfg: TransformerConfig
+
+    @nn.compact
+    def __call__(self, h, next_emb):
+        cfg = self.cfg
+        x = jnp.concatenate([cfg.make_norm("norm_h")(h),
+                             cfg.make_norm("norm_e")(next_emb)], axis=-1)
+        x = QuantDense(cfg.d_model, dtype=cfg.dtype, name="proj")(x)
+        x = cfg.block_cls()(cfg, experts=cfg.moe_experts > 0,
+                            name="block")(x)
+        return cfg.make_norm("norm")(x)
 
 
 class Transformer(nn.Module):
@@ -835,10 +1022,18 @@ class Transformer(nn.Module):
             )
         elif cfg.pos_emb not in ("rope", "none"):
             raise ValueError(f"unknown pos_emb {cfg.pos_emb!r}")
+        block = cfg.block_cls()
         self.blocks = [
-            Block(cfg, name=f"block_{i}") for i in range(cfg.num_layers)
+            block(cfg, experts=cfg.moe_experts > 0 and i >= cfg.dense_layers,
+                  name=f"block_{i}")
+            for i in range(cfg.num_layers)
         ]
         self.ln_f = cfg.make_norm("ln_f")
+        if cfg.mtp_layers > 1:
+            raise ValueError("one multi-token-prediction depth is built; "
+                             f"mtp_layers={cfg.mtp_layers}")
+        if cfg.mtp_layers:
+            self.mtp = MTPModule(cfg, name="mtp")
         if not cfg.tie_embeddings:
             # bf16 operands + fp32 accumulate: sampling still sees fp32
             # logits (MXU accumulates fp32 regardless) but the vocab-wide
@@ -875,7 +1070,17 @@ class Transformer(nn.Module):
                 preferred_element_type=jnp.float32)
         return self.lm_head(h).astype(jnp.float32)
 
+    def hidden_mtp(self, tokens):
+        """``(h, h_mtp)``: ``hidden`` and, from it and the next token's
+        embedding, the multi-token-prediction module's final state
+        (position t predicts token t + 2; the last position's "next
+        token" wraps round and its targets are the caller's to ignore)."""
+        h = self.hidden(tokens)
+        return h, self.mtp(h, jnp.roll(self.embed(tokens), -1, axis=1))
+
     def __call__(self, tokens):
+        if self.cfg.mtp_layers and self.is_initializing():
+            return self.logits(self.hidden_mtp(tokens)[0])
         return self.logits(self.hidden(tokens))
 
     def decode(self, tokens, caches, pos, last_only=False, last_idx=None):

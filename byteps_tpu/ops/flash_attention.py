@@ -77,7 +77,11 @@ long-context story.
 
 Layout convention matches the rest of the stack: ``[B, T, H, D]``.
 ``D`` should be a multiple of the 128-lane width for full MXU utilization
-(64 works; the compiler pads).
+(64 works; the compiler pads).  v (and o, do, dv) carry a head width of
+their own, ``Dv``: latent attention's keys are 192 wide (128 + the shared
+64 rotary channels) and its values 128, and padding v to 192 would waste
+a third of the PV, dV and dP products.  The builders are keyed on both
+widths; where they are equal nothing differs from a one-width kernel.
 """
 
 from __future__ import annotations
@@ -88,6 +92,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -131,6 +136,7 @@ DEFAULT_BWD_DKV_BLOCKS = (1024, 1024)  # (block_q, block_k) of _bwd_dkv
 # still round-trip their state through VMEM scratch: forward 0.561.
 _SUB_TILE = 256
 _NEG_INF = -1e30
+FLASH_OUT = "flash_out"     # checkpoint name of the forward's o and lse
 _ARBITRARY_INNER = pltpu.CompilerParams(
     dimension_semantics=("parallel", "parallel", "arbitrary"))
 
@@ -506,13 +512,14 @@ def _check_band_args(causal, window, alibi_slopes, H):
 
 
 @functools.lru_cache(maxsize=64)
-def _forward_call(B, T, H, Hkv, D, dtype, bq, bk, sub, causal, scale,
+def _forward_call(B, T, H, Hkv, D, Dv, dtype, bq, bk, sub, causal, scale,
                   interpret, has_seg, window, has_alibi):
     """The forward ``pallas_call`` of one static configuration.  Cached:
     every layer of a model calls the SAME object, so JAX traces the kernel
     and lowers it to Mosaic once a program, not once a layer (the
     callable is a ``jit``; a fresh one per layer missed its cache 24
-    times in a 24-layer step)."""
+    times in a 24-layer step).  ``D`` is the width of a q / k head,
+    ``Dv`` of a v / o head (latent attention: 192 and 128)."""
     group = H // Hkv
     nq, nk = T // bq, T // bk
     plans = _q_major_plans(nq, nk, bq, bk, sub, causal, window,
@@ -546,7 +553,7 @@ def _forward_call(B, T, H, Hkv, D, dtype, bq, bk, sub, causal, scale,
     in_specs = [
         pl.BlockSpec((1, bq, D), lambda b, i, j: (b, i, 0)),
         pl.BlockSpec((1, bk, D), kv_idx),
-        pl.BlockSpec((1, bk, D), kv_idx),
+        pl.BlockSpec((1, bk, Dv), kv_idx),
     ]
     if has_seg:
         in_specs += [
@@ -565,17 +572,17 @@ def _forward_call(B, T, H, Hkv, D, dtype, bq, bk, sub, causal, scale,
         grid=(B * H, nq, nk),
         in_specs=in_specs,
         out_specs=[
-            pl.BlockSpec((1, bq, D), lambda b, i, j: (b, i, 0)),
+            pl.BlockSpec((1, bq, Dv), lambda b, i, j: (b, i, 0)),
             # lse kept 3-D: TPU requires the last two block dims divisible
             # by (8, 128) or equal to the full array dims — (bq, 1) is
             pl.BlockSpec((1, bq, 1), lambda b, i, j: (b, i, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((B * H, T, D), dtype),
+            jax.ShapeDtypeStruct((B * H, T, Dv), dtype),
             jax.ShapeDtypeStruct((B * H, T, 1), jnp.float32),
         ],
         scratch_shapes=[] if nk == 1 else [
-            pltpu.VMEM((bq, D), jnp.float32),   # acc
+            pltpu.VMEM((bq, Dv), jnp.float32),  # acc
             pltpu.VMEM((bq, 1), jnp.float32),   # running max
             pltpu.VMEM((bq, 1), jnp.float32),   # running sum
         ],
@@ -588,6 +595,7 @@ def _flash_forward(q, k, v, causal, scale, block_q, block_k, interpret,
                    segment_ids=None, window=None, alibi_slopes=None):
     interpret = resolve_interpret(interpret, "flash_attention forward")
     B, T, H, D = q.shape
+    Dv = v.shape[-1]
     H, Hkv, _ = _gqa_group(q, k)
     _check_band_args(causal, window, alibi_slopes, H)
     bq = _fit_block(block_q, T)
@@ -597,7 +605,7 @@ def _flash_forward(q, k, v, causal, scale, block_q, block_k, interpret,
     # fold heads into the batch grid dim; [B, T, H, D] -> [B*H, T, D]
     operands = [q.transpose(0, 2, 1, 3).reshape(B * H, T, D),
                 k.transpose(0, 2, 1, 3).reshape(B * Hkv, T, D),
-                v.transpose(0, 2, 1, 3).reshape(B * Hkv, T, D)]
+                v.transpose(0, 2, 1, 3).reshape(B * Hkv, T, Dv)]
     if segment_ids is not None:
         seg = segment_ids.astype(jnp.int32)[..., None]   # [B, T, 1]
         operands += [seg, seg]
@@ -605,9 +613,9 @@ def _flash_forward(q, k, v, causal, scale, block_q, block_k, interpret,
         operands += [jnp.tile(alibi_slopes.astype(jnp.float32),
                               B)[:, None, None]]         # [B*H, 1, 1]
     o, lse = _forward_call(
-        B, T, H, Hkv, D, q.dtype, bq, bk, sub, causal, scale, interpret,
+        B, T, H, Hkv, D, Dv, q.dtype, bq, bk, sub, causal, scale, interpret,
         segment_ids is not None, window, alibi_slopes is not None)(*operands)
-    return o.reshape(B, H, T, D).transpose(0, 2, 1, 3), lse[..., 0]
+    return o.reshape(B, H, T, Dv).transpose(0, 2, 1, 3), lse[..., 0]
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 9))
@@ -625,7 +633,8 @@ def flash_attention(
     alibi_slopes: Optional[jax.Array] = None,
 ) -> jax.Array:
     """Exact attention, O(T) memory forward.  q: ``[B, T, H, D]``;
-    k/v: ``[B, T, Hkv, D]`` with ``H % Hkv == 0`` (GQA/MQA: each group of
+    k: ``[B, T, Hkv, D]``, v: ``[B, T, Hkv, Dv]`` (``Dv`` may differ from
+    ``D``; the output is ``[B, T, H, Dv]``) with ``H % Hkv == 0`` (GQA/MQA: each group of
     ``H/Hkv`` query heads shares one kv head, read via the BlockSpec index
     map — no materialized repeat in the forward).
 
@@ -666,6 +675,10 @@ def _fwd_rule(q, k, v, causal, scale, block_q, block_k, interpret,
     bq, bk = _fwd_blocks(block_q, block_k)
     o, lse = _flash_forward(q, k, v, causal, scale, bq, bk,
                             interpret, segment_ids, window, alibi_slopes)
+    # named for a caller's recomputation policy: a block under
+    # ``jax.checkpoint`` that saves these two does not run the forward
+    # kernel again in the backward pass (models/transformer.py)
+    o, lse = checkpoint_name(o, FLASH_OUT), checkpoint_name(lse, FLASH_OUT)
     return o, (q, k, v, o, lse, segment_ids, alibi_slopes)
 
 
@@ -781,7 +794,7 @@ def _bwd_dkv_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, delta_ref, *rest,
 
 
 @functools.lru_cache(maxsize=64)
-def _dq_call(B, T, H, D, dtype, bq, bk, sub, causal, scale, interpret,
+def _dq_call(B, T, H, D, Dv, dtype, bq, bk, sub, causal, scale, interpret,
              has_seg, window, has_alibi):
     """The dq ``pallas_call`` of one static configuration (cached like
     ``_forward_call``).  Operands: q, k, v, do, lse, delta[, seg, seg]
@@ -806,8 +819,8 @@ def _dq_call(B, T, H, D, dtype, bq, bk, sub, causal, scale, interpret,
     in_specs = [
         pl.BlockSpec((1, bq, D), q_idx),      # q block
         pl.BlockSpec((1, bk, D), kv_idx),     # k block
-        pl.BlockSpec((1, bk, D), kv_idx),     # v block
-        pl.BlockSpec((1, bq, D), q_idx),      # do block
+        pl.BlockSpec((1, bk, Dv), kv_idx),    # v block
+        pl.BlockSpec((1, bq, Dv), q_idx),     # do block
         pl.BlockSpec((1, bq, 1), q_idx),      # lse block
         pl.BlockSpec((1, bq, 1), q_idx),      # delta
     ]
@@ -838,7 +851,7 @@ def _dq_call(B, T, H, D, dtype, bq, bk, sub, causal, scale, interpret,
 
 
 @functools.lru_cache(maxsize=64)
-def _dkv_call(B, T, H, D, k_dtype, v_dtype, bq, bk, sub, causal, scale,
+def _dkv_call(B, T, H, D, Dv, k_dtype, v_dtype, bq, bk, sub, causal, scale,
               interpret, has_seg, window, has_alibi):
     """The dk/dv ``pallas_call`` of one static configuration (cached like
     ``_forward_call``).  Operands: k, v, q, do, lse, delta[, seg, seg]
@@ -865,9 +878,9 @@ def _dkv_call(B, T, H, D, k_dtype, v_dtype, bq, bk, sub, causal, scale,
 
     in_specs = [
         pl.BlockSpec((1, bk, D), kv_idx),     # k block
-        pl.BlockSpec((1, bk, D), kv_idx),     # v block
+        pl.BlockSpec((1, bk, Dv), kv_idx),    # v block
         pl.BlockSpec((1, bq, D), q_idx),      # q block
-        pl.BlockSpec((1, bq, D), q_idx),      # do block
+        pl.BlockSpec((1, bq, Dv), q_idx),     # do block
         pl.BlockSpec((1, bq, 1), q_idx),      # lse
         pl.BlockSpec((1, bq, 1), q_idx),      # delta
     ]
@@ -889,14 +902,14 @@ def _dkv_call(B, T, H, D, k_dtype, v_dtype, bq, bk, sub, causal, scale,
         grid=(B * H, nk, nq),
         in_specs=in_specs,
         out_specs=[pl.BlockSpec((1, bk, D), kv_idx),
-                   pl.BlockSpec((1, bk, D), kv_idx)],
+                   pl.BlockSpec((1, bk, Dv), kv_idx)],
         out_shape=[
             jax.ShapeDtypeStruct((B * H, T, D), k_dtype),
-            jax.ShapeDtypeStruct((B * H, T, D), v_dtype),
+            jax.ShapeDtypeStruct((B * H, T, Dv), v_dtype),
         ],
         scratch_shapes=[] if nq == 1 else [
             pltpu.VMEM((bk, D), jnp.float32),
-            pltpu.VMEM((bk, D), jnp.float32),
+            pltpu.VMEM((bk, Dv), jnp.float32),
         ],
         compiler_params=_ARBITRARY_INNER,
         interpret=interpret,
@@ -922,6 +935,7 @@ def _flash_backward(q, k, v, o, lse, do, dlse, causal, scale, block_q,
     which a shared kv row would break."""
     interpret = resolve_interpret(interpret, "flash_attention backward")
     B, T, H, D = q.shape
+    Dv = v.shape[-1]
     H, Hkv, group = _gqa_group(q, k)
     _check_band_args(causal, window, alibi_slopes, H)
     if group > 1:
@@ -958,22 +972,24 @@ def _flash_backward(q, k, v, o, lse, do, dlse, causal, scale, block_q,
         extras += [jnp.tile(alibi_slopes.astype(jnp.float32),
                             B)[:, None, None]]           # [B*H, 1, 1]
 
-    dq = _dq_call(B, T, H, D, q.dtype, bq1, bk1, sub1, causal, scale,
+    dq = _dq_call(B, T, H, D, Dv, q.dtype, bq1, bk1, sub1, causal, scale,
                   interpret, has_seg, window, has_alibi)(
         qf, kf, vf, dof, lse3, delta, *extras)
-    dk, dv = _dkv_call(B, T, H, D, k.dtype, v.dtype, bq2, bk2, sub2, causal,
-                       scale, interpret, has_seg, window, has_alibi)(
+    dk, dv = _dkv_call(B, T, H, D, Dv, k.dtype, v.dtype, bq2, bk2, sub2,
+                       causal, scale, interpret, has_seg, window,
+                       has_alibi)(
         kf, vf, qf, dof, lse3, delta, *extras)
 
     def unfold(x, dtype):
-        return x.reshape(B, H, T, D).transpose(0, 2, 1, 3).astype(dtype)
+        return (x.reshape(B, H, T, x.shape[-1]).transpose(0, 2, 1, 3)
+                .astype(dtype))
 
     dq_out = unfold(dq, q.dtype)
     dk_out = unfold(dk, k.dtype)
     dv_out = unfold(dv, v.dtype)
     if group > 1:  # fold per-q-head kv grads back onto the shared kv heads
         dk_out = dk_out.reshape(B, T, Hkv, group, D).sum(3).astype(k.dtype)
-        dv_out = dv_out.reshape(B, T, Hkv, group, D).sum(3).astype(v.dtype)
+        dv_out = dv_out.reshape(B, T, Hkv, group, Dv).sum(3).astype(v.dtype)
     return dq_out, dk_out, dv_out
 
 

@@ -14,7 +14,7 @@ from .collectives import (
 )
 from .ring_attention import local_attention, ring_attention, ulysses_attention
 from .pipeline import pipeline_apply, pipeline_loss
-from .moe import load_balancing_loss, moe_ffn, top1_routing
+from .moe import expert_layer, route
 
 __all__ = [
     "AXIS_ORDER", "build_mesh", "parse_mesh_shape", "reduce_axes",
@@ -23,5 +23,5 @@ __all__ = [
     "broadcast_shard", "broadcast_stacked", "replicate", "shard_map",
     "ring_attention", "ulysses_attention", "local_attention",
     "pipeline_apply", "pipeline_loss",
-    "moe_ffn", "top1_routing", "load_balancing_loss",
+    "expert_layer", "route",
 ]

@@ -1,113 +1,278 @@
-"""Expert parallelism (ep) — Switch-style top-1 routed MoE FFN with
-``all_to_all`` dispatch over a mesh axis.
+"""The expert layer's core: route, dispatch, grouped products, combine.
 
-Absent from the reference (SURVEY.md §2.4); supplied as the TPU-idiomatic
-"ep" axis: experts are sharded over ``ep``, each rank routes its local
-tokens, buckets them per destination rank with static capacity (XLA needs
-static shapes — overflow tokens are *dropped*, the standard Switch
-Transformer behavior, and their outputs fall back to zero so the residual
-stream carries them), exchanges buckets with one ``all_to_all``, runs its
-local experts' FFN batched on the MXU, and returns results with a second
-``all_to_all``.
+A fine-grained expert layer (DeepSeek-V3 report, section 2.1.2) as one
+rank of an expert-parallel group runs it.  The rank is told which
+contiguous slice of the experts it holds (``held = (first, count)``),
+routes every token over ALL the experts, and computes the part of the
+result that its own experts give:
 
-Everything here is called inside ``shard_map``; weights for the local
-experts arrive pre-sharded (leading expert dim = local experts).
+  router    ``s = sigmoid(x W_g)`` in float32; the top ``k`` of ``s + b``
+            (``b`` is the balancing bias: it takes part in the choice and
+            in nothing else, and receives no gradient); weights are the
+            uncorrected ``s`` of the chosen, normalised to sum to one and
+            multiplied by ``scale``.
+  dispatch  the assignments whose expert is held, sorted by expert, laid
+            out group after group in ONE static row buffer — every group
+            from a row-tile boundary, so a tile belongs to one expert —
+            and the tokens gathered into it.  The assignments of experts
+            held elsewhere fall in a tail group that is never laid out.
+  experts   three grouped matrix products (gate, up, down) over the real
+            tiles, ``ops/grouped_matmul.py``.
+  combine   each token sums its held assignments' rows by their weights.
+
+**No capacity factor and no dropped assignment.**  The buffer holds the
+worst routing — ``tokens x min(k, count)`` rows plus a tile of slack a
+group — and the products' cost follows the tiles that are real.  The
+dispatch and the combine are gathers in both directions (an assignment
+knows its row and a row its assignment), each with the other as its
+backward pass; nothing scatters.
+
+With ``axis_name`` the layer is expert parallel across that mesh axis:
+a rank routes ITS OWN tokens once, sends each to the ranks that hold
+one of its chosen experts (``all_to_all``; the choices and weights, a
+few numbers a token, go to every rank), computes its experts' part for
+the tokens it was sent, and sends the parts back to the tokens' owners,
+which add them up (``all_to_all``).  The send buffers are static and
+sized for the worst routing — every token to every rank — so nothing
+is dropped here either; a slot whose token did not choose the rank is
+zero and lays out no row.  On one rank there is no exchange and nothing
+stands in for it.
+
+Counters (``observability.metrics`` registry, beside ``flash.tiles_*``):
+gauges ``moe.rows_buffer`` and ``moe.experts_held`` are set when a layer
+is traced; counters ``moe.assignments_held`` and ``moe.rows_computed``
+grow by a train step's metrics of those names, step by step
+(``training/step.py``): the first is what the router chose on held
+experts, the second the rows of the buffer that hold a real
+assignment — equal unless something was dropped.
 """
 
 from __future__ import annotations
 
-from typing import Optional, Tuple
+import functools
+from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
+
+from ..observability.metrics import get_registry
+from ..ops.grouped_matmul import grouped_matmul
+
+ROW_TILE = 256      # rows of a tile of the buffer: one expert each
+PLAN = "moe_plan"   # checkpoint name of the top-k choice and its layout
 
 
-def top1_routing(
-    logits: jax.Array, capacity: int
-) -> Tuple[jax.Array, jax.Array]:
-    """Switch top-1 router.
+class Plan(NamedTuple):
+    """Where every held assignment's row lies, both ways round."""
 
-    logits: ``[T, E]``.  Returns ``dispatch [T, E, C]`` (0/1) and
-    ``combine [T, E, C]`` (gate-prob weighted) tensors with per-expert
-    capacity ``C``; tokens beyond capacity are dropped.
-    """
-    T, E = logits.shape
-    probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
-    expert = jnp.argmax(probs, axis=-1)  # [T]
-    gate = jnp.take_along_axis(probs, expert[:, None], axis=-1)[:, 0]  # [T]
-    onehot = jax.nn.one_hot(expert, E, dtype=jnp.int32)  # [T, E]
-    # position of each token within its expert's queue (arrival order)
-    pos = jnp.cumsum(onehot, axis=0) * onehot - 1  # [T, E]; -1 where not routed
-    keep = (pos >= 0) & (pos < capacity)
-    pos_oh = jax.nn.one_hot(jnp.clip(pos, 0, capacity - 1), capacity,
-                            dtype=jnp.float32)  # [T, E, C]
-    dispatch = pos_oh * keep[..., None].astype(jnp.float32)
-    combine = dispatch * gate[:, None, None]
-    return dispatch, combine
+    dest: jax.Array        # [T, k] row of the assignment (0 if not held)
+    held: jax.Array        # [T, k] bool: its expert is held here
+    src: jax.Array         # [R] assignment (t * k + j) of the row
+    valid: jax.Array       # [R] bool: the row is a real assignment
+    tile_group: jax.Array  # [R // tile] expert (local) of each row tile
+    n_active: jax.Array    # [] tiles that hold real rows
+    count: jax.Array       # [count] assignments per held expert
 
 
-def moe_ffn(
-    x: jax.Array,
-    gate_w: jax.Array,
-    w_up: jax.Array,
-    w_down: jax.Array,
-    axis_name: Optional[str] = "ep",
-    capacity_factor: float = 1.25,
-) -> jax.Array:
-    """Expert-parallel routed FFN.  Call inside shard_map.
-
-    x: ``[T, D]`` local tokens.  gate_w: ``[D, E_total]`` (replicated).
-    w_up: ``[E_local, D, F]``, w_down: ``[E_local, F, D]`` — this rank's
-    expert weights.  Returns ``[T, D]``.
-
-    With ``axis_name=None`` (or axis size 1) this is single-rank routed MoE:
-    all experts local, no all_to_all.
-    """
-    T, D = x.shape
-    n = lax.psum(1, axis_name) if axis_name is not None else 1
-    E_local = w_up.shape[0]
-    E = E_local * n
-    capacity = max(1, int(T * capacity_factor / E))
-
-    logits = x @ gate_w.astype(x.dtype)  # [T, E]
-    dispatch, combine = top1_routing(logits, capacity)  # [T, E, C]
-
-    xf = x.astype(jnp.float32)
-    # bucket tokens per expert: [E, C, D]
-    expert_in = jnp.einsum("tec,td->ecd", dispatch, xf)
-    if n > 1:
-        # tiled all_to_all: block j of the split axis (rank j's experts) goes
-        # to rank j; received blocks concatenate along concat_axis.
-        # [E, C, D] -> [E_local, n*C, D], token-source-major along axis 1
-        expert_in = lax.all_to_all(
-            expert_in, axis_name, split_axis=0, concat_axis=1, tiled=True
-        )
-
-    h = jnp.einsum("ecd,edf->ecf", expert_in.astype(x.dtype),
-                   w_up, preferred_element_type=jnp.float32)
-    h = jax.nn.gelu(h)
-    out = jnp.einsum("ecf,efd->ecd", h.astype(x.dtype), w_down,
-                     preferred_element_type=jnp.float32)  # [E_local, nC, D]
-
-    if n > 1:
-        # inverse tiled exchange: [E_local, n*C, D] -> [E, C, D] (block i of
-        # axis 1 returns to source rank i; received blocks stack expert-major)
-        out = lax.all_to_all(out, axis_name, split_axis=1, concat_axis=0,
-                             tiled=True)
-    else:
-        out = out.reshape(E, capacity, D)
-
-    y = jnp.einsum("tec,ecd->td", combine, out)  # gate-weighted return
-    return y.astype(x.dtype)
+def route(x, kernel, bias, top_k: int, scale: float):
+    """``(idx [T, k] int32, weights [T, k] float32)`` for tokens
+    ``x [T, d]``: sigmoid scores in float32 (a float32 product at full
+    precision: a bf16 pass flips choices), selection on ``score +
+    bias``, weights from the uncorrected scores."""
+    scores = jax.nn.sigmoid(jnp.dot(
+        x.astype(jnp.float32), kernel.astype(jnp.float32),
+        precision=lax.Precision.HIGHEST))
+    _, idx = lax.top_k(scores + lax.stop_gradient(
+        bias.astype(jnp.float32)), top_k)
+    idx = checkpoint_name(idx, PLAN)
+    picked = jnp.take_along_axis(scores, idx, axis=-1)
+    weights = picked / (jnp.sum(picked, axis=-1, keepdims=True) + 1e-20)
+    return idx.astype(jnp.int32), weights * scale
 
 
-def load_balancing_loss(logits: jax.Array) -> jax.Array:
-    """Switch aux loss: E * sum_e (fraction routed to e * mean prob of e)."""
-    probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
-    E = logits.shape[-1]
-    frac = jnp.mean(
-        jax.nn.one_hot(jnp.argmax(probs, -1), E, dtype=jnp.float32), axis=0
-    )
-    mean_prob = jnp.mean(probs, axis=0)
-    return E * jnp.sum(frac * mean_prob)
+def buffer_rows(tokens: int, top_k: int, count: int, tile: int) -> int:
+    """Rows of the static buffer: every assignment a token can have on
+    ``count`` experts, plus a tile of slack a group."""
+    worst = tokens * min(top_k, count)
+    return -(-worst // tile) * tile + count * tile
+
+
+def plan(idx, first, count: int, tile: int = ROW_TILE) -> Plan:
+    """Lay the assignments ``idx [T, k]`` whose expert lies in
+    ``[first, first + count)`` out in the row buffer (``first`` may be
+    traced: a rank's own offset)."""
+    T, k = idx.shape
+    A, R = T * k, buffer_rows(T, k, count, tile)
+    local = idx.reshape(-1) - first
+    held = (local >= 0) & (local < count)
+    key = jnp.where(held, local, count)                  # tail: not held
+    group = jnp.minimum(key, count - 1)
+    onehot = (key[:, None] == jnp.arange(count)[None, :]).astype(jnp.int32)
+    running = jnp.cumsum(onehot, axis=0)
+    n = running[-1]                                      # [count]
+    pos = jnp.take_along_axis(running, group[:, None], axis=1)[:, 0] - 1
+    tiles = jnp.maximum(1, -(-n // tile))                # >= 1: see dw
+    tile_end = jnp.cumsum(tiles)
+    row0 = (tile_end - tiles) * tile                     # group's first row
+    dest = jnp.where(held, row0[group] + pos, 0)
+    tile_group = jnp.minimum(jnp.searchsorted(
+        tile_end, jnp.arange(R // tile), side="right"), count - 1)
+    # row -> assignment: the stable sort's order within a group is the
+    # running count's
+    order = jnp.argsort(key, stable=True)
+    sorted0 = jnp.cumsum(n) - n
+    g = jnp.repeat(tile_group, tile, total_repeat_length=R)
+    off = jnp.arange(R) - row0[g]
+    valid = off < n[g]
+    src = jnp.where(valid, order[jnp.clip(sorted0[g] + off, 0, A - 1)], 0)
+    # named for a caller's recomputation policy: a block under
+    # ``jax.checkpoint`` that saves the plan (a few integers a row) does
+    # not choose and sort again in the backward pass
+    return Plan(*(checkpoint_name(a, PLAN) for a in (
+        dest.reshape(T, k).astype(jnp.int32), held.reshape(T, k),
+        src.astype(jnp.int32), valid, tile_group.astype(jnp.int32),
+        tile_end[-1].astype(jnp.int32), n)))
+
+
+# ------------------------------------------------ dispatch and combine
+
+
+@jax.custom_vjp
+def dispatch(x, p: Plan):
+    """``rows [R, d]``: each real row its token, the others zero."""
+    k = p.dest.shape[1]
+    return jnp.where(p.valid[:, None], x[p.src // k], 0).astype(x.dtype)
+
+
+def _dispatch_fwd(x, p):
+    return dispatch(x, p), p
+
+
+def _dispatch_bwd(p, drows):
+    picked = jnp.where(p.held[..., None], drows[p.dest], 0)
+    return jnp.sum(picked.astype(jnp.float32), axis=1).astype(
+        drows.dtype), None
+
+
+dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
+
+
+@jax.custom_vjp
+def combine(rows, weights, p: Plan):
+    """``y [T, d]``: each token's held rows summed by their weights."""
+    picked = jnp.where(p.held[..., None], rows[p.dest], 0)
+    return jnp.einsum("tkd,tk->td", picked.astype(jnp.float32),
+                      weights).astype(rows.dtype)
+
+
+def _combine_fwd(rows, weights, p):
+    return combine(rows, weights, p), (rows, weights, p)
+
+
+def _combine_bwd(res, dy):
+    rows, weights, p = res
+    k = p.dest.shape[1]
+    g = dy[p.src // k].astype(jnp.float32)               # [R, d]
+    w_row = jnp.where(p.valid, weights.reshape(-1)[p.src], 0.0)
+    drows = (w_row[:, None] * g).astype(rows.dtype)
+    dw_row = jnp.sum(jnp.where(p.valid[:, None],
+                               rows.astype(jnp.float32), 0.0) * g, axis=-1)
+    dweights = jnp.where(p.held, dw_row[p.dest], 0.0)
+    return drows, dweights.astype(weights.dtype), None
+
+
+combine.defvjp(_combine_fwd, _combine_bwd)
+
+
+# ------------------------------------------------------------ the layer
+
+
+def experts_ffn(rows, gate, up, down, p: Plan, interpret=None):
+    """The held experts' SwiGLU over the row buffer: ``gate, up
+    [count, d, f]``, ``down [count, f, d]``."""
+    mm = functools.partial(grouped_matmul, tile_group=p.tile_group,
+                           n_active=p.n_active, interpret=interpret)
+    h = jax.nn.silu(mm(rows, gate.astype(rows.dtype))) * mm(
+        rows, up.astype(rows.dtype))
+    return mm(h, down.astype(rows.dtype))
+
+
+def served(idx, first, count: int, p: Plan):
+    """``(chosen, rows)``: the assignments of ``idx [T, k]`` the router
+    put on the held experts, counted from ``idx`` alone, and the rows of
+    the buffer that hold a real assignment — what the dispatch gathered
+    and the products computed.  Equal unless an assignment was dropped
+    on the way into the buffer.  (Two reductions: following every
+    assignment to its row and back cost 5 ms a step at 5 x 65 536
+    assignments on the v5e, 1 % of the step.)"""
+    flat = idx.reshape(-1)
+    chosen = (flat >= first) & (flat < first + count)
+    return (jnp.sum(chosen, dtype=jnp.int32),
+            jnp.sum(p.valid, dtype=jnp.int32))
+
+
+def _held_part(x, idx, weights, first, gate, up, down, tile, interpret):
+    """The held experts' part for tokens ``x [T, d]`` already routed
+    (``idx, weights [T, k]``), and its two counts."""
+    count = gate.shape[0]
+    with jax.named_scope("dispatch"):
+        p = plan(idx, first, count, tile)
+        rows = dispatch(x, p)
+    reg = get_registry()
+    reg.gauge("moe.rows_buffer").set(rows.shape[0])
+    reg.gauge("moe.experts_held").set(count)
+    with jax.named_scope("experts"):
+        out = experts_ffn(rows, gate, up, down, p, interpret)
+    with jax.named_scope("combine"):
+        y = combine(out, weights, p)
+    return y, served(idx, first, count, p)
+
+
+def expert_layer(x, router_kernel, router_bias, gate, up, down, *,
+                 top_k: int, scale: float,
+                 held: Optional[Tuple[int, int]] = None,
+                 axis_name: Optional[str] = None, tile: int = ROW_TILE,
+                 interpret=None):
+    """``(y [T, d], (chosen, served))``: the routed part of the layer
+    for tokens ``x [T, d]`` — the sum over each token's chosen experts
+    that THIS rank holds (``gate.shape[0]`` of them, from ``held[0]``;
+    all of them by default) — and the two counts of ``served``.  The
+    shared expert is the caller's (every rank computes it alike: it
+    counts once).
+
+    With ``axis_name`` (inside ``shard_map``): ``x`` is this rank's
+    tokens, the rank holds the experts from ``axis_index * count``,
+    ``y`` is complete — every rank's part, summed — and the counts are
+    the group's."""
+    count = gate.shape[0]
+    first = 0 if held is None else held[0]
+    if held is not None and held[1] != count:
+        raise ValueError(f"held {held} names {held[1]} experts, the "
+                         f"weights hold {count}")
+    with jax.named_scope("router"):
+        idx, weights = route(x, router_kernel, router_bias, top_k, scale)
+    if axis_name is None:
+        return _held_part(x, idx, weights, first, gate, up, down, tile,
+                          interpret)
+    ranks = lax.psum(1, axis_name)
+    first = lax.axis_index(axis_name) * count
+    T, d = x.shape
+    with jax.named_scope("exchange"):
+        # tokens to the ranks that hold a chosen expert: slot r of the
+        # send buffer is for rank r
+        wanted = jnp.any((idx // count)[None] == jnp.arange(ranks)[
+            :, None, None], axis=-1)                       # [ranks, T]
+        got = lax.all_to_all(jnp.where(wanted[..., None], x[None], 0),
+                             axis_name, 0, 0)              # from rank s
+        idx_all = lax.all_gather(idx, axis_name)
+        w_all = lax.all_gather(weights, axis_name)
+    part, counts = _held_part(
+        got.reshape(ranks * T, d), idx_all.reshape(ranks * T, top_k),
+        w_all.reshape(ranks * T, top_k), first, gate, up, down, tile,
+        interpret)
+    with jax.named_scope("exchange"):
+        back = lax.all_to_all(part.reshape(ranks, T, d), axis_name, 0, 0)
+        y = jnp.sum(back.astype(jnp.float32), axis=0).astype(x.dtype)
+    return y, tuple(lax.psum(c, axis_name) for c in counts)

@@ -15,6 +15,7 @@ with these same pieces (see models/transformer.py and __graft_entry__.py).
 
 from __future__ import annotations
 
+import collections
 from typing import Any, Callable, NamedTuple, Optional, Sequence
 
 import jax
@@ -23,6 +24,7 @@ import optax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..common.config import get_config
+from ..observability.metrics import get_registry
 from ..common.tracing import (SCOPE_HEAD, SCOPE_MODEL, SCOPE_OPTIMIZER,
                               SCOPE_STEP_METRICS)
 from ..ops.compression import Compression
@@ -115,7 +117,12 @@ def make_data_parallel_step(
     on the *local* batch shard.  The returned step function has signature
     ``step(state: TrainState, batch) -> (TrainState, metrics)`` where
     ``batch`` is a pytree whose leaves have the global batch on dim 0
-    (sharded over ``axes``), and metrics = {"loss": mean loss}.
+    (sharded over ``axes``), and metrics = {"loss": mean loss}.  A
+    ``loss_fn`` may return a third element, a dict of counts of its own
+    (``lm_loss_fn``: ``moe_assignments_held``, ``moe_rows_computed``);
+    they join the metrics summed over the workers, and every call of the
+    step adds them to the registry's counters of their names
+    (``flush_step_counts``).
 
     Semantics match the reference benchmark
     (example/pytorch/benchmark_byteps.py): gradients are *averaged* across
@@ -168,9 +175,11 @@ def make_data_parallel_step(
             # forward here; the backward carries the same scope inside
             # ``transpose(jvp(...))``
             with jax.named_scope(SCOPE_MODEL):
-                return loss_fn(p, state.model_state, batch)
+                loss, *aux = loss_fn(p, state.model_state, batch)
+            return loss, aux
 
-        (loss, new_mstate), grads = jax.value_and_grad(lf, has_aux=True)(state.params)
+        (loss, (new_mstate, *counts)), grads = jax.value_and_grad(
+            lf, has_aux=True)(state.params)
         # push_pull and the inner update name themselves (``tx``)
         updates, new_opt = tx.update(grads, state.opt_state, state.params)
         with jax.named_scope(SCOPE_OPTIMIZER):
@@ -185,9 +194,13 @@ def make_data_parallel_step(
                 if jnp.issubdtype(x.dtype, jnp.floating) else x,
                 new_mstate,
             )
+            metrics = {"loss": loss}
+            for extra in counts:
+                metrics.update({k: jax.lax.psum(v, axes)
+                                for k, v in extra.items()})
         return (
             TrainState(new_params, new_opt, new_mstate, state.step + 1),
-            {"loss": loss},
+            metrics,
         )
 
     state_spec = P()  # params/opt state replicated across data axes
@@ -202,6 +215,60 @@ def make_data_parallel_step(
     return TrainStep(jitted, tx, mesh)
 
 
+# counts of steps already dispatched whose values the device has yet to
+# produce, oldest first
+_PENDING: collections.deque = collections.deque()
+
+
+def _count_step(counts) -> None:
+    """Each count of one step grows the registry counter of its name
+    (``moe_assignments_held`` -> ``moe.assignments_held``: the layer's
+    prefix becomes the registry's dotted one), ``train.steps_counted``
+    by one."""
+    reg = get_registry()
+    reg.counter("train.steps_counted", instants=False).inc()
+    for name, n in counts.items():
+        reg.counter(name.replace("_", ".", 1), instants=False).inc(int(n))
+
+
+def flush_step_counts(wait: bool = True) -> None:
+    """Add the counts of the steps dispatched so far to the registry —
+    all of them (waits for the device), or with ``wait=False`` those
+    whose values are there already."""
+    while _PENDING and (wait or all(
+            v.is_ready() for v in _PENDING[0].values())):
+        _count_step(_PENDING.popleft())
+
+
+def _note_counts(metrics) -> None:
+    """Host side of a step whose loss function counts, after its
+    dispatch: the counts wait in ``_PENDING`` until the device has them
+    (no host callback in the program, no wait on the step just sent)."""
+    if len(metrics) > 1:
+        _PENDING.append({k: v for k, v in metrics.items() if k != "loss"})
+        flush_step_counts(wait=False)
+
+
+class _Counting:
+    """A lowered or compiled train step whose calls feed the registry's
+    counters as ``TrainStep.__call__`` does; everything else
+    (``as_text``, ``memory_analysis``, ...) is the wrapped object's."""
+
+    def __init__(self, inner):
+        self._inner = inner
+
+    def compile(self, *args, **kwargs):
+        return _Counting(self._inner.compile(*args, **kwargs))
+
+    def __call__(self, state, batch):
+        out = self._inner(state, batch)
+        _note_counts(out[1])
+        return out
+
+    def __getattr__(self, name):
+        return getattr(self._inner, name)
+
+
 class TrainStep:
     """Callable train step bundling the jitted SPMD program with the
     *wrapped* optimizer (DistributedOptimizer chain) whose state layout the
@@ -213,14 +280,16 @@ class TrainStep:
         self.mesh = mesh
 
     def __call__(self, state, batch):
-        return self._fn(state, batch)
+        out = self._fn(state, batch)
+        _note_counts(out[1])
+        return out
 
     def init_state(self, params, model_state=None) -> TrainState:
         state = create_train_state(params, self.tx, model_state=model_state)
         return replicate_state(state, self.mesh)
 
     def lower(self, state, batch):
-        return self._fn.lower(state, batch)
+        return _Counting(self._fn.lower(state, batch))
 
 
 def make_zero_step(loss_fn, zero, model_state=None, reduce_grads=None):
@@ -311,7 +380,15 @@ def lm_loss_fn(model, fused_head: bool = False,
     ignore-index semantics (out-of-range target → loss 0, no grad).
     Requires a model exposing ``hidden`` plus either an ``lm_head``
     Dense or tied embeddings (models/transformer.Transformer, either
-    way; for tied models the head weight is the embedding transpose).  ``block_n``/``block_v`` pass
+    way; for tied models the head weight is the embedding transpose).
+    Where the model has a multi-token-prediction module
+    (``cfg.mtp_layers``) the loss gains ``cfg.mtp_loss_weight`` times the
+    module's cross-entropy on the token after next, through the same
+    head; where it has expert layers the closure returns a third
+    element, ``{"moe_assignments_held": n, "moe_rows_computed": r}``
+    (``parallel/moe.py:served``, summed over its expert layers), which
+    the step reports beside ``loss``.
+    ``block_n``/``block_v`` pass
     through to the kernel for vocab/batch sizes its auto-fit cannot
     divide (e.g. GPT-2's 50257).
 
@@ -334,12 +411,41 @@ def lm_loss_fn(model, fused_head: bool = False,
             emb = emb.unbox()
         return emb.T.astype(h.dtype)
 
+    def _hiddens(params, m, tokens):
+        """``((h, h_mtp | None), n)``: the pre-head states — with a
+        multi-token-prediction module its state too — and the expert
+        layers' counts summed over the layers, ``{"moe_<count>": n}``
+        (None without experts)."""
+        cfg = getattr(m, "cfg", None)
+        method = m.hidden_mtp if getattr(cfg, "mtp_layers", 0) else m.hidden
+        if getattr(cfg, "moe_experts", 0):
+            hs, stats = m.apply({"params": params}, tokens, method=method,
+                                mutable=["moe_stats"])
+            n = {}
+            for path, leaf in jax.tree_util.tree_leaves_with_path(stats):
+                name = f"moe_{path[-1].key}"
+                n[name] = n.get(name, 0) + leaf
+        else:
+            hs, n = m.apply({"params": params}, tokens, method=method), None
+        return (hs if isinstance(hs, tuple) else (hs, None)), n
+
+    def _mtp_targets(targets):
+        # position t of the module predicts token t + 2
+        return jnp.roll(targets, -1, axis=1).at[:, -1].set(-100)
+
+    def _with_mtp(head_ce, m, h, h_mtp, targets):
+        loss = head_ce(h, targets)
+        if h_mtp is not None:
+            loss = loss + m.cfg.mtp_loss_weight * head_ce(
+                h_mtp, _mtp_targets(targets))
+        return loss
+
     def _fused_ce(params, m, tokens, targets):
         from ..ops.fused_cross_entropy import fused_linear_cross_entropy
 
-        h = m.apply({"params": params}, tokens, method=m.hidden)
-        with jax.named_scope(SCOPE_HEAD):
-            w = _head_weight(params, h)
+        (h, h_mtp), n = _hiddens(params, m, tokens)
+
+        def head_ce(h, targets):
             B, T, d = h.shape
             V = w.shape[-1]
             flat_t = targets.reshape(-1)
@@ -353,21 +459,37 @@ def lm_loss_fn(model, fused_head: bool = False,
             return per_row.sum() / jnp.maximum(valid, 1).astype(
                 per_row.dtype)
 
-    def _plain_ce(params, m, tokens, targets):
-        # the head's matmul is inside the model's own call here (its
-        # Flax scope names it); the scope covers the CE over its logits
-        logits = m.apply({"params": params}, tokens)
         with jax.named_scope(SCOPE_HEAD):
-            t = targets[:, :-1]
-            valid = (t >= 0) & (t < logits.shape[-1])
-            # optax's integer-label CE has no ignore-index: out-of-range
-            # labels produce garbage — clamp them and zero their loss
-            per_tok = optax.softmax_cross_entropy_with_integer_labels(
-                logits[:, :-1], jnp.where(valid, t, 0)
-            )
-            per_tok = jnp.where(valid, per_tok, 0.0)
-            return per_tok.sum() / jnp.maximum(valid.sum(), 1).astype(
-                per_tok.dtype)
+            w = _head_weight(params, h)
+            return _with_mtp(head_ce, m, h, h_mtp, targets), n
+
+    def _plain_ce(params, m, tokens, targets):
+        def ce_of(logits, targets):
+            with jax.named_scope(SCOPE_HEAD):
+                t = targets[:, :-1]
+                valid = (t >= 0) & (t < logits.shape[-1])
+                # optax's integer-label CE has no ignore-index:
+                # out-of-range labels produce garbage — clamp them and
+                # zero their loss
+                per_tok = optax.softmax_cross_entropy_with_integer_labels(
+                    logits[:, :-1], jnp.where(valid, t, 0)
+                )
+                per_tok = jnp.where(valid, per_tok, 0.0)
+                return per_tok.sum() / jnp.maximum(valid.sum(), 1).astype(
+                    per_tok.dtype)
+
+        cfg = getattr(m, "cfg", None)
+        if not (getattr(cfg, "mtp_layers", 0)
+                or getattr(cfg, "moe_experts", 0)):
+            # the head's matmul is inside the model's own call here (its
+            # Flax scope names it); the scope covers the CE over its
+            # logits
+            return ce_of(m.apply({"params": params}, tokens), targets), None
+        (h, h_mtp), n = _hiddens(params, m, tokens)
+        return _with_mtp(
+            lambda h, t: ce_of(m.apply({"params": params}, h,
+                                       method=m.logits), t),
+            m, h, h_mtp, targets), n
 
     ce = _fused_ce if fused_head else _plain_ce
 
@@ -380,7 +502,7 @@ def lm_loss_fn(model, fused_head: bool = False,
         else:
             targets = jnp.roll(tokens, -1, axis=1)
         targets = targets.at[:, -1].set(-100)  # ignore the wrap position
-        loss = ce(params, model, tokens, targets)
+        loss, held = ce(params, model, tokens, targets)
         if early_exit is not None:
             from ..inference import truncated_draft
 
@@ -392,7 +514,9 @@ def lm_loss_fn(model, fused_head: bool = False,
             dmodel, dvars = truncated_draft(
                 model.cfg, {"params": params}, e_layers)
             loss = loss + e_weight * ce(
-                dvars["params"], dmodel, tokens, targets)
+                dvars["params"], dmodel, tokens, targets)[0]
+        if held is not None:
+            return loss, model_state, held
         return loss, model_state
 
     return loss_fn

@@ -1,8 +1,8 @@
-"""Pipeline (pp) and expert (ep) parallelism tests on the CPU mesh.
+"""Pipeline (pp) parallelism tests on the CPU mesh (the expert layer's
+are in test_expert_layer.py).
 
-Contracts: a 4-stage GPipe pipeline must equal sequential application of
-the 4 stages (forward AND gradients); expert-parallel MoE over 4 ranks must
-equal the single-rank routed MoE on the same tokens/experts.
+Contract: a 4-stage GPipe pipeline must equal sequential application of
+the 4 stages (forward AND gradients).
 """
 
 import jax
@@ -12,7 +12,6 @@ import pytest
 from jax.sharding import Mesh, PartitionSpec as P
 
 from byteps_tpu.parallel.collectives import shard_map
-from byteps_tpu.parallel.moe import load_balancing_loss, moe_ffn, top1_routing
 from byteps_tpu.parallel.pipeline import pipeline_apply, pipeline_loss
 
 
@@ -122,77 +121,3 @@ def test_pipeline_remat_matches():
                            in_specs=(P("pp"), P()), out_specs=P("pp")))
     np.testing.assert_allclose(np.asarray(f1(params, micros)),
                                np.asarray(f2(params, micros)), atol=1e-5)
-
-
-# --------------------------------------------------------------------- moe
-
-T, DM, F, E = 32, 8, 16, 8  # tokens, d_model, d_ff, experts
-N_RANKS = 4
-
-
-def _moe_weights(key):
-    k1, k2, k3 = jax.random.split(key, 3)
-    return (
-        jax.random.normal(k1, (DM, E)) * 0.5,          # gate
-        jax.random.normal(k2, (E, DM, F)) * 0.2,       # up
-        jax.random.normal(k3, (E, F, DM)) * 0.2,       # down
-    )
-
-
-def test_top1_routing_capacity():
-    logits = jnp.array([[10.0, 0.0]] * 5)  # all 5 tokens -> expert 0
-    dispatch, combine = top1_routing(logits, capacity=3)
-    # only 3 fit
-    assert float(dispatch[:, 0].sum()) == 3.0
-    assert float(dispatch[3:, 0].sum()) == 0.0  # overflow dropped in order
-    # combine weighted by gate prob
-    assert np.all(np.asarray(combine) <= np.asarray(dispatch))
-
-
-def test_moe_ep_matches_single_rank():
-    """4-way expert-parallel == all-experts-local, same capacity."""
-    gate, up, down = _moe_weights(jax.random.PRNGKey(0))
-    x = jax.random.normal(jax.random.PRNGKey(1), (T, DM))
-
-    # single-rank reference: capacity must match the ep run, where each
-    # rank routes T tokens into E experts with factor cf
-    cf = 2.0
-    ref = moe_ffn(x, gate, up, down, axis_name=None, capacity_factor=cf)
-
-    mesh = Mesh(np.array(jax.devices()[:N_RANKS]), ("ep",))
-    E_local = E // N_RANKS
-
-    def run(x_all, gate, up, down):
-        # every rank gets the SAME tokens (replicated) and its expert slice
-        return moe_ffn(x_all, gate, up[0], down[0],
-                       axis_name="ep", capacity_factor=cf)
-
-    fn = jax.jit(shard_map(
-        run, mesh,
-        in_specs=(P(), P(), P("ep"), P("ep")),
-        out_specs=P(),  # identical tokens => identical outputs
-    ))
-    out = fn(x, gate, up.reshape(N_RANKS, E_local, DM, F),
-             down.reshape(N_RANKS, E_local, F, DM))
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                               atol=1e-4, rtol=1e-4)
-
-
-def test_moe_overflow_tokens_get_zero():
-    gate, up, down = _moe_weights(jax.random.PRNGKey(2))
-    # tiny capacity: force drops
-    x = jax.random.normal(jax.random.PRNGKey(3), (T, DM))
-    out = moe_ffn(x, gate, up, down, axis_name=None, capacity_factor=0.1)
-    # some rows must be exactly zero (dropped), others not
-    norms = np.linalg.norm(np.asarray(out), axis=-1)
-    assert (norms == 0).any() and (norms > 0).any()
-
-
-def test_load_balancing_loss_uniform_is_one():
-    # perfectly uniform router -> loss == 1.0 (E * E * (1/E) * (1/E))
-    logits = jnp.zeros((64, E))
-    lb = load_balancing_loss(logits)
-    # argmax breaks ties to expert 0, so frac is degenerate; use random
-    logits = jax.random.normal(jax.random.PRNGKey(0), (4096, E)) * 0.01
-    lb = load_balancing_loss(logits)
-    assert 0.9 < float(lb) < 1.3
