@@ -247,3 +247,97 @@ def scatter_buckets(bucket_arrays: Sequence[jax.Array], plan: BucketPlan):
             vec = chunks[0][1] if len(chunks) == 1 else jnp.concatenate([c for _, c in chunks])
             flat.append(vec.reshape(leaf.shape).astype(leaf.dtype))
     return jax.tree_util.tree_unflatten(plan.treedef, flat)
+
+
+# ---------------------------------------------------------------------------
+# Buckets of dim-0 shares: what lets a reduce-scatter leave every worker the
+# contiguous 1/shards of each leaf along dim 0, and an all-gather of such
+# shares rebuild the leaves (the sharded update, training/step.py).  A
+# share's bytes are not contiguous in a flat bucket of whole leaves, so
+# the flat plan above cannot say it; this one never cuts a leaf:
+#
+# * a leaf whose share fills a bucket is a bucket by itself, whatever its
+#   size — the collective runs on the leaf as it lies (``psum_scatter`` /
+#   ``all_gather`` over dim 0), nothing is packed and nothing unpacked;
+# * consecutive smaller leaves of one dtype share a bucket whose payload is
+#   shard-major: flat ``[shards * size]``, its r-th ``size`` elements
+#   ("row" r) worker r's shares end to end.
+# ---------------------------------------------------------------------------
+
+
+def dim0_share(leaf, shards: int) -> jax.ShapeDtypeStruct:
+    """The shape of one worker's contiguous 1/shards of ``leaf`` along
+    dim 0 (the caller has checked that it divides)."""
+    return jax.ShapeDtypeStruct(
+        (leaf.shape[0] // shards,) + tuple(leaf.shape[1:]), leaf.dtype)
+
+
+def plan_share_buckets(leaves: Sequence[Any], shards: int,
+                       partition_bytes: int = 4_096_000,
+                       first_id: int = 0) -> BucketPlan:
+    """The bucket plan of a list of leaves whose dim 0 divides by
+    ``shards``, in share coordinates (``plan.leaves``, every slice and
+    every ``size`` describe ONE worker's share): ``plan_buckets``' order
+    (reverse), dtype rule and priorities at ``partition_bytes / shards``
+    a worker, except that no leaf is cut: a leaf that fills a bucket
+    stands alone (and closes nothing: the small leaves on either side of
+    it still meet in one bucket), and a bucket of small leaves closes
+    before the leaf that would overflow it.
+    ``first_id`` numbers the buckets after another plan's (the leaves
+    whose dim 0 does not divide), so no two collectives of a step share
+    a ``bps.push_pull/*/b<iii>`` scope."""
+    specs, treedef = leaf_specs_of_tree(
+        [dim0_share(x, shards) for x in leaves])
+    bound = max(1, partition_bytes // shards)
+    buckets: List[Bucket] = []
+    shared: Bucket | None = None      # the bucket small leaves still join
+
+    def open_bucket(leaf):
+        buckets.append(Bucket(bucket_id=first_id + len(buckets),
+                              dtype=leaf.dtype, size=0, priority=0,
+                              slices=[]))
+        return buckets[-1]
+
+    for leaf in reversed(specs):
+        if leaf.nbytes >= bound:
+            into = open_bucket(leaf)            # alone, and closed
+        else:
+            if (shared is None or shared.dtype != leaf.dtype
+                    or shared.nbytes + leaf.nbytes > bound):
+                shared = open_bucket(leaf)
+            into = shared
+        into.slices.append(BucketSlice(leaf.index, 0, into.size, leaf.size))
+        into.size += leaf.size
+        into.priority = -leaf.index             # the smallest index so far
+    return BucketPlan(leaves=specs, buckets=buckets, treedef=treedef)
+
+
+def pack_share_bucket(leaves: Sequence[jax.Array], bucket: Bucket,
+                      rows: int) -> jax.Array:
+    """The flat payload of ``bucket`` from its leaves (indexed as the
+    plan's): ``rows`` = shards packs whole leaves shard-major, ``rows``
+    = 1 one worker's shares into its row.  A leaf alone in its bucket is
+    its own payload, as it lies.  Traceable."""
+    if len(bucket.slices) == 1:
+        return leaves[bucket.slices[0].leaf_index]
+    with jax.named_scope(bucket_scope("pack", bucket.bucket_id)):
+        return jnp.concatenate(
+            [leaves[s.leaf_index].reshape(rows, s.length)
+             for s in bucket.slices], axis=1).reshape(-1)
+
+
+def unpack_share_bucket(payload: jax.Array, bucket: Bucket,
+                        plan: BucketPlan, rows: int) -> List[jax.Array]:
+    """Inverse of ``pack_share_bucket``: the bucket's leaves, in the
+    order of its slices, dim 0 ``rows`` x the share's."""
+    if len(bucket.slices) == 1:
+        return [payload]
+    with jax.named_scope(SCOPE_UNPACK):
+        table = payload.reshape(rows, bucket.size)
+        out = []
+        for s in bucket.slices:
+            shape = plan.leaves[s.leaf_index].shape
+            out.append(
+                table[:, s.bucket_start:s.bucket_start + s.length].reshape(
+                    (rows * shape[0],) + shape[1:]))
+        return out

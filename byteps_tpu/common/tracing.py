@@ -395,6 +395,7 @@ SCOPE_HEAD = "bps.head"                  # LM head: weight cast + CE kernels
 SCOPE_PUSH_PULL = "bps.push_pull"        # parent of the three bucket stages
 SCOPE_UNPACK = SCOPE_PUSH_PULL + "/unpack"   # buckets sliced back into leaves
 SCOPE_OPTIMIZER = "bps.optimizer"        # inner update + parameter write
+#                                          (on a share: its slice of the params)
 SCOPE_STEP_METRICS = "bps.step_metrics"  # loss / model-state psums
 
 BUCKET_STAGES = ("pack", "reduce")
@@ -402,7 +403,8 @@ BUCKET_STAGES = ("pack", "reduce")
 
 def bucket_scope(stage: str, i: int) -> str:
     """``bps.push_pull/<stage>/b<iii>`` for bucket ``i`` of the plan:
-    ``pack`` (concatenating the bucket's leaf slices) or ``reduce`` (its
+    ``pack`` (concatenating the bucket's leaf slices — gradients, and on
+    the sharded update the new parameter shares) or ``reduce`` (its
     reduce-scatter / cross-axis psum / all-gather, wire casts included)."""
     if stage not in BUCKET_STAGES:
         raise ValueError(f"bucket stage {stage!r} not in {BUCKET_STAGES}")

@@ -209,6 +209,69 @@ def push_pull_tree(
     return partition_mod.scatter_buckets(reduced, plan)
 
 
+def reduce_scatter_tree(
+    leaves: Sequence[jax.Array],
+    plan: BucketPlan,
+    scatter_axis: str = "dp",
+    sum_axes: Sequence[str] = (),
+    average: bool = True,
+) -> List[jax.Array]:
+    """The first half of ``push_pull_tree`` for leaves whose dim 0
+    divides by the scatter axis: one ``psum_scatter`` per bucket of
+    ``plan`` (``partition.plan_share_buckets``), in priority order, so
+    what this worker is left with IS its contiguous 1/shards of every
+    leaf's reduced value along dim 0 — returned as the list of those
+    shares.  ``all_gather_tree`` is the other half; what runs between
+    them (the optimizer, on a share) runs on 1/shards of the bytes.
+    Call inside shard_map."""
+    shards = _axis_size(scatter_axis)
+    denom = _axis_size(tuple(sum_axes) + (scatter_axis,)) if average else 1
+    payloads = [partition_mod.pack_share_bucket(leaves, b, shards)
+                for b in plan.buckets]
+    shares: List[Optional[jax.Array]] = [None] * len(leaves)
+    rows: List[Optional[jax.Array]] = [None] * len(payloads)
+    for i in plan.schedule_order():
+        with jax.named_scope(
+                bucket_scope("reduce", plan.buckets[i].bucket_id)):
+            y = lax.psum_scatter(payloads[i], scatter_axis,
+                                 scatter_dimension=0, tiled=True)
+            if sum_axes:
+                y = lax.psum(y, tuple(sum_axes))
+            rows[i] = y / denom if average else y
+    for b, row in zip(plan.buckets, rows):
+        for s, x in zip(b.slices,
+                        partition_mod.unpack_share_bucket(row, b, plan, 1)):
+            shares[s.leaf_index] = x
+    return shares
+
+
+def all_gather_tree(
+    shares: Sequence[jax.Array],
+    plan: BucketPlan,
+    scatter_axis: str = "dp",
+) -> List[jax.Array]:
+    """The whole leaves from every worker's dim-0 shares: one
+    ``all_gather`` per bucket of the same ``plan``, in priority order
+    and under the bucket's ``reduce`` scope.  The gather of a dim-0 share
+    along dim 0 IS the leaf, so a leaf alone in its bucket is neither
+    packed nor unpacked.  Call inside shard_map."""
+    shards = _axis_size(scatter_axis)
+    rows = [partition_mod.pack_share_bucket(shares, b, 1)
+            for b in plan.buckets]
+    whole: List[Optional[jax.Array]] = [None] * len(shares)
+    payloads: List[Optional[jax.Array]] = [None] * len(rows)
+    for i in plan.schedule_order():
+        with jax.named_scope(
+                bucket_scope("reduce", plan.buckets[i].bucket_id)):
+            payloads[i] = lax.all_gather(rows[i], scatter_axis, axis=0,
+                                         tiled=True)
+    for b, payload in zip(plan.buckets, payloads):
+        for s, x in zip(b.slices, partition_mod.unpack_share_bucket(
+                payload, b, plan, shards)):
+            whole[s.leaf_index] = x
+    return whole
+
+
 # ---------------------------------------------------------------------------
 # Eager (outside-jit) entry points: one controller, workers == mesh devices.
 # ---------------------------------------------------------------------------

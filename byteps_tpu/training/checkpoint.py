@@ -18,6 +18,7 @@ from typing import Any, Optional
 
 import jax
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec
 
 from ..common import logging as bps_log
 
@@ -25,22 +26,70 @@ from ..common import logging as bps_log
 def _checkpointer():
     import orbax.checkpoint as ocp
 
-    return ocp.PyTreeCheckpointer()
+    if jax.process_count() == 1:
+        return ocp.PyTreeCheckpointer()
+    # The root writes and every process reads ON ITS OWN (below): orbax
+    # must not wait at a barrier for processes that never call it.
+    me = jax.process_index()
+    alone = ocp.options.MultiprocessingOptions(
+        primary_host=me, active_processes={me},
+        barrier_sync_key_prefix=f"bps_p{me}")
+    return ocp.Checkpointer(
+        ocp.PyTreeCheckpointHandler(multiprocessing_options=alone),
+        multiprocessing_options=alone)
+
+
+def whole_on_every_process(state: Any) -> Any:
+    """``state`` with every ``jax.Array`` leaf that lies sharded over a
+    mesh (the data-parallel step keeps a share's moments ``P(dp)`` on
+    dim 0, training/step.py) gathered to a fully replicated array of the
+    same shape; every other leaf as it came.  The gather is a collective
+    over the leaf's mesh: in a multi-process job EVERY process must call
+    this, in the same order — a process can read a sharded leaf whole
+    only after it."""
+    leaves, treedef = jax.tree_util.tree_flatten(state)
+    by_mesh = {}
+    for k, x in enumerate(leaves):
+        if (isinstance(x, jax.Array) and not x.is_fully_replicated
+                and isinstance(x.sharding, NamedSharding)):
+            by_mesh.setdefault(x.sharding.mesh, []).append(k)
+    for mesh, ks in by_mesh.items():
+        gathered = jax.jit(
+            lambda xs: xs,
+            out_shardings=NamedSharding(mesh, PartitionSpec()),
+        )([leaves[k] for k in ks])
+        for k, x in zip(ks, gathered):
+            leaves[k] = x
+    return treedef.unflatten(leaves)
+
+
+def _to_host(x):
+    if not hasattr(x, "dtype"):
+        return x
+    if (isinstance(x, jax.Array) and not x.is_fully_replicated
+            and not x.is_fully_addressable):
+        raise ValueError(
+            f"a {x.shape} leaf sharded as {x.sharding} spans processes and "
+            "cannot be read whole on one; shard it over a named mesh "
+            "(save_checkpoint gathers those) or gather it before saving")
+    return np.asarray(x)
 
 
 def save_checkpoint(path: str, state: Any, force: bool = True) -> str:
     """Save a pytree (TrainState or any params tree) to ``path``.
 
     Multi-host: only process 0 writes (the reference's root-centric model);
-    call on every process — non-roots no-op.
+    call on every process — a leaf sharded across the mesh is gathered
+    first, by all of them (``whole_on_every_process``), then non-roots
+    no-op.  The checkpoint holds every leaf whole, whatever the world
+    that wrote it sharded.
     """
     path = os.path.abspath(path)
+    state = whole_on_every_process(state)
     if jax.process_index() != 0:
         return path
     # orbax wants fully-addressable host arrays
-    host_state = jax.tree_util.tree_map(
-        lambda x: np.asarray(x) if hasattr(x, "dtype") else x, state
-    )
+    host_state = jax.tree_util.tree_map(_to_host, state)
     _checkpointer().save(path, host_state, force=force)
     bps_log.info("checkpoint saved to %s", path)
     return path

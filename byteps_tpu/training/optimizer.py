@@ -17,6 +17,7 @@ only the k-th triggers communication — the same wire traffic reduction.
 
 from __future__ import annotations
 
+import math
 from typing import Any, NamedTuple, Optional, Sequence, Union
 
 import jax
@@ -86,6 +87,94 @@ def resolve_local_axis(axes: Sequence[str],
     return local_axis, tuple(a for a in axes if a != local_axis)
 
 
+def resolve_wire_dtype(cast):
+    """The dtype a cast spec puts on the wire: the Compressor class's,
+    else the environment's (``BYTEPS_WIRE_DTYPE``), else None — the
+    payload crosses in its own precision."""
+    wire = getattr(cast, "wire_dtype", None)
+    return get_config().wire_jnp_dtype if wire is None else wire
+
+
+# What an ``update`` may do to a non-scalar operand and still mean the
+# same on a worker's share of every leaf as on the whole leaf: act on
+# each element alone.  A reduction, a product, a sort or a gather over a
+# leaf (a global norm, a trust ratio, factored moments) is in no such
+# list; nor is anything that draws random bits.
+_ELEMENTWISE = frozenset({
+    "abs", "add", "and", "atan2", "cbrt", "ceil", "clamp",
+    "convert_element_type", "copy", "cos", "div", "eq", "erf", "erf_inv",
+    "exp", "exp2", "expm1", "floor", "ge", "gt", "integer_pow", "is_finite",
+    "le", "log", "log1p", "logistic", "lt", "max", "min", "mul", "ne", "neg",
+    "nextafter", "not", "or", "pow", "rem", "round", "rsqrt", "select_n",
+    "sign", "sin", "sqrt", "square", "sub", "tan", "tanh", "xor"})
+_CALLS = frozenset({"jit", "pjit", "closed_call", "core_call",
+                    "custom_jvp_call", "custom_vjp_call", "remat",
+                    "checkpoint"})
+
+
+def _not_elementwise(jaxpr) -> Optional[str]:
+    """The first primitive of ``jaxpr`` that does more to a non-scalar
+    operand than act on each element alone, or None."""
+    def small(v):
+        return math.prod(getattr(v.aval, "shape", ())) == 1
+
+    for eqn in jaxpr.eqns:
+        name = eqn.primitive.name
+        if all(small(v) for v in eqn.invars):
+            # scalars from scalars (a step count, a schedule), or a
+            # scalar broadcast to a leaf's shape: every worker computes
+            # the same
+            if (all(small(v) for v in eqn.outvars)
+                    or name == "broadcast_in_dim"):
+                continue
+            return name
+        if name in _ELEMENTWISE:
+            continue
+        if name not in _CALLS:
+            return name
+        for sub in eqn.params.values():
+            sub = getattr(sub, "jaxpr", sub)
+            if hasattr(sub, "eqns"):
+                found = _not_elementwise(sub)
+                if found is not None:
+                    return found
+    return None
+
+
+def trace_share_update(tx: optax.GradientTransformation, grads, state,
+                       params):
+    """``tx.update`` traced ONCE on a worker's dim-0 share of every leaf
+    (the arguments: abstract, on the shares' shapes): ``(update, None)``
+    — ``update(grads, state, params)`` binds that traced program, so
+    the program that was checked is the program that runs and the
+    caller's optimizer is traced once, as on the replicated path — or
+    ``(None, why)`` when it only holds on whole leaves.  Updating a
+    share with its share of the state is the same mathematics as
+    updating the leaf only if every non-scalar operand is treated
+    element by element: the jaxpr is walked against ``_ELEMENTWISE``
+    (``clip_by_global_norm``, LARS / LAMB trust ratios and Adafactor
+    reduce over a leaf, and a share's norm is not the leaf's).  An
+    update that cannot be traced on the shares at all (a captured
+    per-parameter constant of the whole shape, a mesh axis it names
+    itself) is refused likewise."""
+    try:
+        jaxpr, out = jax.make_jaxpr(tx.update, return_shape=True)(
+            grads, state, params)
+    except (TypeError, ValueError, NameError) as e:
+        return None, f"not traceable on shares: {e!r}"
+    found = _not_elementwise(jaxpr.jaxpr)
+    if found is not None:
+        return None, f"`{found}` over a leaf"
+    run = jax.extend.core.jaxpr_as_fun(jaxpr)
+    out_tree = jax.tree_util.tree_structure(out)
+
+    def update(grads, state, params):
+        return out_tree.unflatten(
+            run(*jax.tree_util.tree_leaves((grads, state, params))))
+
+    return update, None
+
+
 def sgd_momentum_update(m, g, lr: float, momentum: float):
     """One heavy-ball SGD step on host numpy: ``m' = momentum*m + g``,
     ``delta = -lr*m'`` (the parameter increment).  Returns ``(m', delta)``.
@@ -150,12 +239,9 @@ def push_pull_gradients(
                 "byteps_tpu.compression.error_feedback_compress before "
                 "push_pull_gradients")
         compression = cast
-    cfg = get_config()
-    pb = partition_bytes or cfg.effective_partition_bytes
+    pb = partition_bytes or get_config().effective_partition_bytes
     # compression class wins; else env BYTEPS_WIRE_DTYPE ("bf16"/"fp16")
-    wire = getattr(compression, "wire_dtype", None)
-    if wire is None:
-        wire = cfg.wire_jnp_dtype
+    wire = resolve_wire_dtype(compression)
 
     def init_fn(params):
         del params
@@ -187,6 +273,34 @@ def push_pull_gradients(
         return reduced, state
 
     return optax.GradientTransformation(init_fn, update_fn)
+
+
+def distributed_links(optimizer, compression, axis_name, average,
+                      partition_bytes, plan, local_axis) -> list:
+    """The links ``DistributedOptimizer`` chains, in order: an
+    error-feedback compressor where the scheme is biased, the bucketed
+    push_pull, the caller's optimizer under its scope.  The chain's state
+    is the tuple of theirs; ``make_data_parallel_step`` walks the same
+    links by hand where it updates a share (training/step.py)."""
+    cast, ef_tx = resolve_compression(compression)
+    # validate eagerly: a bad local_axis must fail at build time, not
+    # from inside the traced update
+    if axis_name is not None:
+        axes = ((axis_name,) if isinstance(axis_name, str)
+                else tuple(axis_name))
+        resolve_local_axis(axes, local_axis)
+    links = [] if ef_tx is None else [ef_tx]
+    links.append(
+        push_pull_gradients(
+            axis_name=axis_name,
+            average=average,
+            compression=cast,
+            partition_bytes=partition_bytes,
+            plan=plan,
+            local_axis=local_axis,
+        ))
+    links.append(scoped_update(optimizer))
+    return links
 
 
 def DistributedOptimizer(
@@ -223,24 +337,8 @@ def DistributedOptimizer(
         updates, opt_state = opt.update(grads, opt_state, params)
     """
     del named_parameters
-    cast, ef_tx = resolve_compression(compression)
-    # validate eagerly: a bad local_axis must fail at build time, not
-    # from inside the traced update
-    if axis_name is not None:
-        axes = ((axis_name,) if isinstance(axis_name, str)
-                else tuple(axis_name))
-        resolve_local_axis(axes, local_axis)
-    links = [] if ef_tx is None else [ef_tx]
-    links.append(
-        push_pull_gradients(
-            axis_name=axis_name,
-            average=average,
-            compression=cast,
-            partition_bytes=partition_bytes,
-            plan=plan,
-            local_axis=local_axis,
-        ))
-    links.append(scoped_update(optimizer))
+    links = distributed_links(optimizer, compression, axis_name, average,
+                              partition_bytes, plan, local_axis)
     tx = optax.chain(*links)
     if backward_passes_per_step > 1:
         tx = optax.MultiSteps(tx, every_k_schedule=backward_passes_per_step)

@@ -16,20 +16,26 @@ with these same pieces (see models/transformer.py and __graft_entry__.py).
 from __future__ import annotations
 
 import collections
-from typing import Any, Callable, NamedTuple, Optional, Sequence
+import dataclasses
+from typing import Any, Callable, List, NamedTuple, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 import optax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from ..common import logging as bps_log
+from ..common import partition
 from ..common.config import get_config
 from ..observability.metrics import get_registry
 from ..common.tracing import (SCOPE_HEAD, SCOPE_MODEL, SCOPE_OPTIMIZER,
                               SCOPE_STEP_METRICS)
 from ..ops.compression import Compression
-from .optimizer import DistributedOptimizer, scoped_update
-from ..parallel.collectives import shard_map
+from .optimizer import (distributed_links, resolve_compression,
+                        resolve_local_axis, resolve_wire_dtype, scoped_update,
+                        trace_share_update)
+from ..parallel.collectives import (all_gather_tree, push_pull_tree,
+                                    reduce_scatter_tree, shard_map)
 
 
 class TrainState(NamedTuple):
@@ -134,6 +140,39 @@ def make_data_parallel_step(
     reduce-scatter stage of the hierarchical reduction (docs/wire.md
     "Hierarchical reduction"); default: the innermost axis.
 
+    **Where the update runs (world > 1).**  The gradients are
+    reduce-scattered so that every worker is left with its contiguous
+    1/shards of every leaf's averaged gradient along dim 0 (shards = the
+    size of the local scatter axis); the optimizer updates that share
+    with its share of the moments, and the new parameter shares are
+    all-gathered: the same bytes on the wire as reducing and gathering
+    the gradient, 1/shards of the update's memory traffic, the moments
+    held once across the axis.  Both collectives follow one plan
+    (``partition.plan_share_buckets``): a leaf whose share fills a
+    bucket (``partition_bytes / shards``) goes by itself, as it lies —
+    the scatter of a leaf along dim 0 leaves its dim-0 share, the gather
+    of the shares IS the leaf, nothing is packed or unpacked —, the
+    small leaves share buckets, in ``schedule_order``.  The
+    optimizer sees the parameters' own treedef and ``ndim`` (path- and
+    ``ndim``-based masks keep their meaning); ``state.opt_state`` keeps
+    the ``DistributedOptimizer`` chain's treedef and the parameters'
+    global shapes, a share's moments placed ``P(scatter_axis)`` on dim
+    0; parameters stay replicated.  Which path runs is read from the
+    input, not from a switch: a leaf whose dim 0 does not divide is
+    reduced and updated whole on every worker, and the whole optimizer
+    stays on the replicated path (gradients all-gathered, state ``P()``)
+    when its ``update`` is not elementwise in every non-scalar operand
+    (``optimizer.trace_share_update``: a share's norm is not the
+    leaf's), when a wire cast is set (parameters must not cross the wire
+    below master precision) or when ``backward_passes_per_step > 1``;
+    the gauges ``optimizer.sharded_bytes`` / ``optimizer.replicated_bytes``
+    (label ``reason``) say which, a log line why.  A state that is not
+    where the program wants it — fresh from ``create_train_state``,
+    restored and broadcast from a checkpoint — is put there by the
+    step's first call (a local slice; ``lower`` likewise lowers against
+    the wanted shardings whatever it is handed), so callers keep handing
+    it replicated state; ``checkpoint.save_checkpoint`` gathers it whole.
+
     .. note:: At ``world == 1`` (with ``backward_passes_per_step == 1``)
        the DistributedOptimizer wrapper is dropped — matching the
        reference's ``size()==1`` short-circuit — but any ``compression``
@@ -142,12 +181,15 @@ def make_data_parallel_step(
        biased registry schemes), so single- and multi-process runs see
        the same gradient numerics.  The ``opt_state`` pytree nesting
        still differs from the multi-worker chain, so **checkpoints do
-       not transfer between world sizes**.
+       not transfer between world 1 and larger worlds**.  Between two
+       worlds > 1 they do: a checkpoint holds every leaf whole, whatever
+       the world that wrote it sharded.
     """
     axes = tuple(axes)
     world = 1
     for ax in axes:
         world *= mesh.shape[ax]
+    layouts = None
     if world == 1 and backward_passes_per_step == 1:
         # Single-worker fast path (the reference likewise short-circuits
         # when size()==1): the push_pull wrapper is already a traced no-op
@@ -160,17 +202,21 @@ def make_data_parallel_step(
         if comp_tx is not None:
             tx = optax.chain(comp_tx, tx)
     else:
-        tx = DistributedOptimizer(
-            optimizer,
-            compression=compression,
-            axis_name=axes,
-            average=True,
-            partition_bytes=partition_bytes or get_config().partition_bytes,
-            backward_passes_per_step=backward_passes_per_step,
-            local_axis=local_axis,
-        )
+        pb = partition_bytes or get_config().partition_bytes
+        links = distributed_links(optimizer, compression, axes, True, pb,
+                                  None, local_axis)
+        tx = optax.chain(*links)
+        if backward_passes_per_step > 1:
+            tx = optax.MultiSteps(
+                tx, every_k_schedule=backward_passes_per_step)
+        scatter, sums = resolve_local_axis(axes, local_axis)
+        wire = resolve_wire_dtype(resolve_compression(compression)[0])
+        layouts = _UpdateLayouts(
+            tx, links, mesh, scatter, sums, pb,
+            "multi_step" if backward_passes_per_step > 1
+            else "wire_cast" if wire is not None else None)
 
-    def local_step(state: TrainState, batch):
+    def local_step(lay, state: TrainState, batch):
         def lf(p):
             # forward here; the backward carries the same scope inside
             # ``transpose(jvp(...))``
@@ -180,10 +226,15 @@ def make_data_parallel_step(
 
         (loss, (new_mstate, *counts)), grads = jax.value_and_grad(
             lf, has_aux=True)(state.params)
-        # push_pull and the inner update name themselves (``tx``)
-        updates, new_opt = tx.update(grads, state.opt_state, state.params)
-        with jax.named_scope(SCOPE_OPTIMIZER):
-            new_params = optax.apply_updates(state.params, updates)
+        if lay is not None and lay.sharded:
+            new_params, new_opt = layouts.sharded_update(
+                lay, grads, state.opt_state, state.params)
+        else:
+            # push_pull and the inner update name themselves (``tx``)
+            updates, new_opt = tx.update(grads, state.opt_state,
+                                         state.params)
+            with jax.named_scope(SCOPE_OPTIMIZER):
+                new_params = optax.apply_updates(state.params, updates)
         with jax.named_scope(SCOPE_STEP_METRICS):
             n = jax.lax.psum(1, axes)
             loss = jax.lax.psum(loss, axes) / n
@@ -203,16 +254,214 @@ def make_data_parallel_step(
             metrics,
         )
 
-    state_spec = P()  # params/opt state replicated across data axes
-    batch_spec = P(axes)
-    mapped = shard_map(
-        local_step,
-        mesh,
-        in_specs=(state_spec, batch_spec),
-        out_specs=(state_spec, state_spec),
-    )
-    jitted = jax.jit(mapped, donate_argnums=(0,) if donate else ())
-    return TrainStep(jitted, tx, mesh)
+    def step_fn(state: TrainState, batch):
+        # the state's specs depend on its shapes (which leaves' dim 0
+        # divides, what the optimizer's state holds), so the shard_map is
+        # made where they are known: under the trace
+        lay = layouts.of(state) if layouts is not None else None
+        state_spec = TrainState(  # params / model state / step replicated
+            P(), lay.opt_specs if lay is not None else P(), P(), P())
+        return shard_map(
+            lambda st, b: local_step(lay, st, b),
+            mesh,
+            in_specs=(state_spec, P(axes)),
+            out_specs=(state_spec, P()),
+        )(state, batch)
+
+    jitted = jax.jit(step_fn, donate_argnums=(0,) if donate else ())
+    return TrainStep(jitted, tx, mesh, layouts, donate)
+
+
+_REASONS = ("elementwise", "wire_cast", "multi_step", "dim0")
+
+
+@dataclasses.dataclass(frozen=True)
+class _UpdateLayout:
+    """Where one parameter tree's update runs: the leaves (by index in
+    the flattened tree) whose dim-0 share each worker updates and the
+    rest, their bucket plans, and where every leaf of the optimizer's
+    state lies (a share's moments ``P(scatter)`` on dim 0)."""
+
+    sharded: Tuple[int, ...]
+    rest: Tuple[int, ...]
+    plan: Optional[partition.BucketPlan]        # of the sharded leaves
+    rest_plan: Optional[partition.BucketPlan]   # flat, of the rest
+    opt_specs: Any                       # pytree of P, as opt_state
+    opt_shardings: Optional[List[Any]]   # per opt_state leaf; None = P()
+    update: Any = None        # the caller's ``update``, traced on shares
+
+
+class _UpdateLayouts:
+    """The sharded update of ``make_data_parallel_step`` at world > 1:
+    which path a parameter tree takes (read from its shapes and from the
+    optimizer's own program, once per tree), where the state lies for
+    it, and the update itself."""
+
+    def __init__(self, tx, links, mesh, scatter, sums, partition_bytes,
+                 gate: Optional[str]):
+        self.tx, self.links, self.mesh = tx, links, mesh
+        self.scatter, self.sums = scatter, tuple(sums)
+        self.shards = mesh.shape[scatter]
+        self.partition_bytes = partition_bytes
+        self.gate = gate          # what keeps the whole optimizer replicated
+        self._on_shares = NamedSharding(mesh, P(scatter))
+        self._to_shares = jax.jit(lambda x: x,
+                                  out_shardings=self._on_shares)
+        self._cache = {}
+
+    def of(self, state) -> _UpdateLayout:
+        leaves, treedef = jax.tree_util.tree_flatten(state.params)
+        key = (treedef, tuple((x.shape, x.dtype) for x in leaves))
+        if key not in self._cache:
+            self._cache[key] = self._build(state)
+        return self._cache[key]
+
+    def _build(self, state) -> _UpdateLayout:
+        leaves, treedef = jax.tree_util.tree_flatten(state.params)
+        leaves = [jax.ShapeDtypeStruct(x.shape, x.dtype) for x in leaves]
+        shards = self.shards
+        sharded = () if self.gate else tuple(
+            i for i, x in enumerate(leaves)
+            if x.ndim and x.shape[0] and x.shape[0] % shards == 0)
+        reason, inner_specs, update = self.gate or "dim0", None, None
+        if sharded:
+            # the caller's optimizer on shares against its state on whole
+            # leaves: what changed shape is a share's (moments), the rest
+            # (counts) every worker holds
+            mixed = list(leaves)
+            for i in sharded:
+                mixed[i] = partition.dim0_share(leaves[i], shards)
+            mixed = treedef.unflatten(mixed)
+            inner = jax.tree_util.tree_map(
+                lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype),
+                state.opt_state[-1])
+            try:
+                on_shares = jax.eval_shape(self.links[-1].init, mixed)
+                update, refusal = trace_share_update(
+                    self.links[-1], mixed, on_shares, mixed)
+            except (TypeError, ValueError) as e:
+                refusal = f"`init` not traceable on shares: {e!r}"
+            if refusal is None:
+                inner_specs = jax.tree_util.tree_map(
+                    lambda w, s: P() if w.shape == s.shape
+                    else P(self.scatter), inner, on_shares)
+            else:
+                bps_log.info("data-parallel update: the optimizer stays "
+                             "on whole leaves (%s)", refusal)
+                sharded, reason = (), "elementwise"
+        nbytes = [x.size * x.dtype.itemsize for x in leaves]
+        on_shares = sum(nbytes[i] for i in sharded)
+        reg = get_registry()
+        reg.gauge("optimizer.sharded_bytes").set(on_shares)
+        for r in _REASONS:
+            reg.gauge("optimizer.replicated_bytes", reason=r).set(
+                sum(nbytes) - on_shares if r == reason else 0)
+        bps_log.info(
+            "data-parallel update: %d parameter bytes on dim-0 shares over "
+            "%r (x%d), %d replicated (%s)", on_shares, self.scatter, shards,
+            sum(nbytes) - on_shares, reason)
+        if not sharded:
+            return _UpdateLayout((), (), None, None, P(), None)
+        front = state.opt_state[:-1]
+        opt_shardings = [None] * len(jax.tree_util.tree_leaves(front)) + [
+            None if spec == P() else self._on_shares
+            for spec in jax.tree_util.tree_leaves(inner_specs)]
+        rest = tuple(i for i in range(len(leaves)) if i not in set(sharded))
+        rest_plan = partition.plan_buckets(
+            [leaves[i] for i in rest], self.partition_bytes) if rest else None
+        # (numbered after the rest's, whose flat pack names its buckets
+        # by position)
+        plan = partition.plan_share_buckets(
+            [leaves[i] for i in sharded], shards, self.partition_bytes,
+            first_id=rest_plan.num_buckets if rest else 0)
+        return _UpdateLayout(sharded, rest, plan, rest_plan,
+                             tuple(P() for _ in front) + (inner_specs,),
+                             opt_shardings, update)
+
+    def sharded_update(self, lay: _UpdateLayout, grads, opt_state, params):
+        """``tx.update`` + ``apply_updates`` with the reduction split
+        around the optimizer: what precedes the push_pull link runs as
+        the chain runs it, each bucket is reduce-scattered, the caller's
+        optimizer updates this worker's dim-0 share of every sharded leaf
+        (and the other leaves whole, from a reduction of today's kind),
+        and the new shares are all-gathered into whole parameters.
+        Runs under the step's shard_map; returns ``(params, opt_state)``."""
+        front = self.links[:-2]
+        states = list(opt_state)
+        for k, link in enumerate(front):
+            grads, states[k] = link.update(grads, states[k], params)
+        g, treedef = jax.tree_util.tree_flatten(grads)
+        p = treedef.flatten_up_to(params)
+        shares = reduce_scatter_tree(
+            [g[i] for i in lay.sharded], lay.plan, self.scatter, self.sums)
+        if lay.rest:
+            reduced = push_pull_tree(
+                [g[i] for i in lay.rest], plan=lay.rest_plan,
+                scatter_axis=self.scatter, sum_axes=self.sums)
+            for i, x in zip(lay.rest, reduced):
+                g[i] = x
+        with jax.named_scope(SCOPE_OPTIMIZER):
+            r = jax.lax.axis_index(self.scatter)
+            for i, x in zip(lay.sharded, shares):
+                g[i] = x
+                p[i] = jax.lax.dynamic_slice_in_dim(
+                    p[i], r * x.shape[0], x.shape[0], axis=0,
+                    allow_negative_indices=False)
+            p_mixed = treedef.unflatten(p)
+            # (the program ``_build`` traced and found elementwise)
+            updates, states[-1] = lay.update(
+                treedef.unflatten(g), states[-1], p_mixed)
+            new = treedef.flatten_up_to(
+                optax.apply_updates(p_mixed, updates))
+        gathered = all_gather_tree(
+            [new[i] for i in lay.sharded], lay.plan, self.scatter)
+        for i, x in zip(lay.sharded, gathered):
+            new[i] = x
+        return treedef.unflatten(new), tuple(states)
+
+    def place(self, state, donate: bool):
+        """``state`` with every leaf of the optimizer's state that the
+        program wants on dim-0 shares put there: a local slice of a
+        replicated leaf, its whole copy given up leaf by leaf where the
+        step would donate it anyway.  A leaf already there is left alone
+        — all of them, from the step's second call on — and the check
+        touches no device."""
+        lay = self.of(state)
+        if lay.opt_shardings is None:
+            return state
+        leaves, treedef = jax.tree_util.tree_flatten(state.opt_state)
+        moved = False
+        for k, want in enumerate(lay.opt_shardings):
+            x = leaves[k]
+            have = getattr(x, "sharding", None)
+            if want is None or have == want or (
+                    have is not None
+                    and have.is_equivalent_to(want, x.ndim)):
+                continue
+            # a program, so that a replicated leaf is sliced where it lies
+            # (``device_put`` would take it through the host)
+            leaves[k] = self._to_shares(x)
+            if donate and isinstance(x, jax.Array):
+                # (the device frees it once the slice has read it)
+                x.delete()
+            moved = True
+        if not moved:
+            return state
+        return state._replace(opt_state=treedef.unflatten(leaves))
+
+    def abstract(self, state):
+        """``state`` as the program is lowered against it, whatever it
+        was handed (concrete, or abstract and replicated as
+        ``benchmark/aot_check.py`` builds it): a share's moments on
+        their shares, every other leaf as it came."""
+        lay = self.of(state)
+        if lay.opt_shardings is None:
+            return state
+        leaves, treedef = jax.tree_util.tree_flatten(state.opt_state)
+        return state._replace(opt_state=treedef.unflatten([
+            x if want is None else jax.ShapeDtypeStruct(
+                x.shape, x.dtype, sharding=want)
+            for x, want in zip(leaves, lay.opt_shardings)]))
 
 
 # counts of steps already dispatched whose values the device has yet to
@@ -254,14 +503,15 @@ class _Counting:
     counters as ``TrainStep.__call__`` does; everything else
     (``as_text``, ``memory_analysis``, ...) is the wrapped object's."""
 
-    def __init__(self, inner):
+    def __init__(self, inner, place):
         self._inner = inner
+        self._place = place
 
     def compile(self, *args, **kwargs):
-        return _Counting(self._inner.compile(*args, **kwargs))
+        return _Counting(self._inner.compile(*args, **kwargs), self._place)
 
     def __call__(self, state, batch):
-        out = self._inner(state, batch)
+        out = self._inner(self._place(state), batch)
         _note_counts(out[1])
         return out
 
@@ -274,22 +524,35 @@ class TrainStep:
     *wrapped* optimizer (DistributedOptimizer chain) whose state layout the
     program expects — use ``init_state`` to build a matching TrainState."""
 
-    def __init__(self, fn, tx: optax.GradientTransformation, mesh: Mesh):
+    def __init__(self, fn, tx: optax.GradientTransformation, mesh: Mesh,
+                 layouts: Optional[_UpdateLayouts] = None,
+                 donate: bool = True):
         self._fn = fn
         self.tx = tx
         self.mesh = mesh
+        self._layouts = layouts
+        self._donate = donate
+
+    def _place(self, state):
+        """The state where the program wants it (``_UpdateLayouts.place``;
+        nothing to do at world 1)."""
+        if self._layouts is None:
+            return state
+        return self._layouts.place(state, self._donate)
 
     def __call__(self, state, batch):
-        out = self._fn(state, batch)
+        out = self._fn(self._place(state), batch)
         _note_counts(out[1])
         return out
 
     def init_state(self, params, model_state=None) -> TrainState:
         state = create_train_state(params, self.tx, model_state=model_state)
-        return replicate_state(state, self.mesh)
+        return self._place(replicate_state(state, self.mesh))
 
     def lower(self, state, batch):
-        return _Counting(self._fn.lower(state, batch))
+        if self._layouts is not None:
+            state = self._layouts.abstract(state)
+        return _Counting(self._fn.lower(state, batch), self._place)
 
 
 def make_zero_step(loss_fn, zero, model_state=None, reduce_grads=None):

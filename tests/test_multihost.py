@@ -147,12 +147,71 @@ _TF_WORKER = textwrap.dedent(
 )
 
 
+_SHARDED_SAVE_WORKER = textwrap.dedent(
+    """
+    import numpy as np
+    import jax
+
+    jax.config.update("jax_platforms", "cpu")
+
+    import jax.numpy as jnp
+    import optax
+
+    import byteps_tpu as bps
+    from byteps_tpu.training import make_data_parallel_step, shard_batch
+    from byteps_tpu.training.checkpoint import (
+        restore_checkpoint, save_checkpoint, whole_on_every_process)
+
+    bps.init()
+    assert jax.process_count() == 2, jax.process_count()
+    mesh = bps.mesh()
+
+    def loss_fn(p, model_state, b):
+        h = jnp.tanh(b["x"] @ p["w1"])
+        return jnp.mean((h @ p["w2"] - b["y"]) ** 2), model_state
+
+    rng = np.random.RandomState(0)
+    params = {"w1": (rng.randn(8, 32) * 0.3).astype(np.float32),
+              "w2": (rng.randn(32, 4) * 0.3).astype(np.float32)}
+    batch = shard_batch({"x": rng.randn(8, 8).astype(np.float32),
+                         "y": rng.randn(8, 4).astype(np.float32)}, mesh)
+    step = make_data_parallel_step(loss_fn, optax.adamw(1e-2), mesh,
+                                   partition_bytes=256)
+    state, _ = step(step.init_state(params), batch)
+
+    # the step left the moments on dim-0 shares, one a process: no
+    # process holds them whole
+    mu = state.opt_state[-1][0].mu["w1"]
+    assert mu.shape == (8, 32)
+    assert not mu.is_fully_addressable and not mu.is_fully_replicated
+
+    # every process calls; the gather inside is a collective, the write
+    # is the root's
+    path = save_checkpoint("@CKPT@/mid", tuple(state))
+    whole = whole_on_every_process(state)
+    if jax.process_index() == 0:
+        restored = restore_checkpoint(path, broadcast=False)
+        for a, b in zip(jax.tree_util.tree_leaves(restored),
+                        jax.tree_util.tree_leaves(tuple(whole))):
+            assert np.asarray(a).shape == b.shape
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+    # the save took nothing from the state: the run goes on
+    state, m = step(state, batch)
+    assert np.isfinite(float(m["loss"])) and int(state.step) == 2
+
+    print(f"SAVE_WORKER_{jax.process_index()}_OK")
+    bps.shutdown()
+    """
+)
+
+
 from byteps_tpu.engine.transport import free_port as _free_port
 
 
 def _run_two_workers(tmp_path, source, ok_marker):
     script = tmp_path / "worker.py"
-    script.write_text(source)
+    script.write_text(source.replace("@CKPT@", str(tmp_path)))
     port = _free_port()
     procs = []
     for wid in range(2):
@@ -203,6 +262,15 @@ def _run_two_workers(tmp_path, source, ok_marker):
 @pytest.mark.slow
 def test_two_process_push_pull(tmp_path):
     _run_two_workers(tmp_path, _WORKER, "WORKER_{wid}_OK")
+
+
+@pytest.mark.slow
+def test_two_process_save_of_dim0_sharded_moments(tmp_path):
+    """The data-parallel step keeps a share's moments ``P(dp)``; across
+    two real processes no one of them can read such a leaf whole, so
+    ``save_checkpoint`` (called by both, written by the root) gathers
+    first — and the checkpoint holds every leaf whole."""
+    _run_two_workers(tmp_path, _SHARDED_SAVE_WORKER, "SAVE_WORKER_{wid}_OK")
 
 
 @pytest.mark.slow

@@ -117,26 +117,74 @@ def bucket_ids(names, stage):
 @pytest.mark.parametrize("partition_bytes", [4096, 16384, 1 << 20])
 def test_each_planned_bucket_has_its_pack_and_reduce_scope(partition_bytes):
     lowered, params = lowered_step(4, partition_bytes=partition_bytes)
-    plan = partition.plan_buckets(params, partition_bytes)
+    # (every leaf of the tiny model divides by 4: one plan, of shares)
+    plan = partition.plan_share_buckets(
+        jax.tree_util.tree_leaves(params), 4, partition_bytes)
     all_names = op_names(lowered)
-    want = list(range(plan.num_buckets))
-    assert bucket_ids(all_names, "pack") == want
+    want = [b.bucket_id for b in plan.buckets]
+    # a leaf alone in its bucket is its own payload: nothing to pack
+    shared = [b.bucket_id for b in plan.buckets if len(b.slices) > 1]
+    assert shared and (len(shared) < len(want) or partition_bytes > 16384)
+    assert bucket_ids(all_names, "pack") == shared
     assert bucket_ids(all_names, "reduce") == want
     # one scope per bucket and stage: nothing of bucket i is named j
-    for stage in tracing.BUCKET_STAGES:
-        for i in want:
+    for stage, ids in (("pack", shared), ("reduce", want)):
+        for i in ids:
             scope = tracing.bucket_scope(stage, i)
             assert under(all_names, scope + "/"), scope
-    # the collectives themselves carry their bucket's reduce scope, and
-    # the loss average the metrics scope
+    # the collectives themselves carry their bucket's reduce scope — the
+    # scatter of the gradients and the gather of the new parameters, one
+    # of each a bucket — and the loss average the metrics scope
     reduce_scope = re.compile(
-        re.escape(tracing.SCOPE_PUSH_PULL) + r"/reduce/b\d{3}/")
+        re.escape(tracing.SCOPE_PUSH_PULL) + r"/reduce/b(\d{3})/")
+    collectives = {"reduce_scatter": [], "all_gather": []}
     for n in all_names:
         op = n.rsplit("/", 1)[-1]
-        if op in ("reduce_scatter", "all_gather"):
+        if op in collectives:
             assert reduce_scope.search(n), n
+            collectives[op].append(int(reduce_scope.search(n).group(1)))
         if op == "psum":
             assert tracing.SCOPE_STEP_METRICS in n, n
+    assert sorted(collectives["reduce_scatter"]) == want
+    assert sorted(collectives["all_gather"]) == want
+
+
+STAGE_SCOPES = (
+    tracing.SCOPE_MODEL, tracing.SCOPE_HEAD,
+    tracing.SCOPE_PUSH_PULL + "/pack/", tracing.SCOPE_PUSH_PULL + "/reduce/",
+    tracing.SCOPE_UNPACK, tracing.SCOPE_OPTIMIZER,
+    tracing.SCOPE_STEP_METRICS)
+
+
+def test_every_op_of_the_step_lies_under_one_of_the_seven_scopes():
+    """``benchmark/harness/scopes.py`` knows seven stages and reads
+    anything else as ``unscoped``: the sharded update's own ops — the
+    parameter shares cut out for the optimizer, the small leaves' new
+    shares packed, the all-gathers, both unpacks — each carry one of
+    them."""
+    # the compiled program's ``op_name``s: the whole name stack of each
+    # instruction, as the device trace carries it
+    text = lowered_step(4)[0].compile().as_text()
+    every = set(re.findall(r'op_name="([^"]+)"', text))
+    inside = {n for n in every if "/shard_map/" in n}
+    assert len(inside) > 100
+    stray = {re.sub(r"\.\d+$", "", n) for n in inside
+             if not any(scope in n for scope in STAGE_SCOPES)}
+    # (the step counter's ``+ 1`` is the one op the step leaves unnamed;
+    # the compiler names the constants it broadcasts itself)
+    assert stray <= {"jit(step_fn)/shard_map/add",
+                     "jit(step_fn)/shard_map/broadcast"}, sorted(stray)[:10]
+    ops = lambda scope: {n.rsplit("/", 1)[-1]            # noqa: E731
+                         for n in under(every, scope)}
+    # small leaves are joined into payloads and payloads cut back into
+    # leaves
+    assert "concatenate" in ops(tracing.SCOPE_PUSH_PULL + "/pack/")
+    assert {"reduce_scatter", "all_gather", "div"} <= ops(
+        tracing.SCOPE_PUSH_PULL + "/reduce/")
+    assert "slice" in ops(tracing.SCOPE_UNPACK)
+    # the optimizer cuts its share out of the replicated parameters
+    assert {"axis_index", "dynamic_slice", "sqrt"} <= ops(
+        tracing.SCOPE_OPTIMIZER)
 
 
 def test_the_delayed_gradient_step_carries_the_bucket_scopes():
@@ -207,3 +255,42 @@ def test_scoped_update_is_the_same_transformation_under_a_name():
         grads, s1, params)
     scoped_ops = under(op_names(lowered), tracing.SCOPE_OPTIMIZER)
     assert any(n.endswith(("/mul", "/sqrt", "/div")) for n in scoped_ops)
+
+
+def test_a_leaf_that_does_not_divide_shares_no_bucket_id_with_the_shares():
+    """A leaf whose dim 0 does not divide by the world is reduced whole
+    under the flat plan, whose buckets are numbered first; the shares'
+    buckets follow, so every collective of the step has a ``reduce``
+    scope of its own."""
+    mesh = Mesh(np.array(jax.devices()[:4]), ("dp",))
+    params = {"w": jnp.ones((8, 64)), "b": jnp.ones((64,)),
+              "odd": jnp.ones((3,)), "odd2": jnp.ones((5, 7))}
+
+    def loss_fn(p, model_state, batch):
+        h = batch["x"] @ p["w"] + p["b"]
+        return jnp.mean(h ** 2) * p["odd"].sum() * p["odd2"].sum(), (
+            model_state)
+
+    step = make_data_parallel_step(loss_fn, optax.adamw(1e-3), mesh,
+                                   partition_bytes=64)
+    names = op_names(step.lower(
+        step.init_state(params),
+        shard_batch({"x": jnp.ones((8, 8))}, mesh)))
+    rest = partition.plan_buckets(
+        [params["odd"], params["odd2"]], 64).num_buckets
+    shares = partition.plan_share_buckets(
+        [params["b"], params["w"]], 4, 64, first_id=rest)
+    assert rest > 1 and shares.num_buckets > 1
+    assert bucket_ids(names, "reduce") == list(
+        range(rest + shares.num_buckets))
+    by_id = {}
+    for n in names:
+        m = re.search(r"/reduce/b(\d{3})/(reduce_scatter|all_gather)", n)
+        if m:
+            by_id.setdefault(int(m.group(1)), set()).add(m.group(2))
+    # the flat buckets scatter and gather the gradient; each share bucket
+    # scatters the gradient and gathers the new parameters
+    assert all(by_id[i] == {"reduce_scatter", "all_gather"}
+               for i in range(rest + shares.num_buckets))
+    assert [b.bucket_id for b in shares.buckets] == list(
+        range(rest, rest + shares.num_buckets))
