@@ -2,7 +2,7 @@
 
 One rule, applied by every entry point before its first compile
 (``api.init``, ``serving.frontend.build_engine_from_env``,
-``chip_smoke.py``, ``bench.py``): the cache directory is chosen from
+``chip_smoke.py``): the cache directory is chosen from
 OUTSIDE the program.  If ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads
 it itself and this module sets nothing.  Otherwise the cache goes to
 ``<checkout>/.jax_cache`` — a fixed path derived from this package's own

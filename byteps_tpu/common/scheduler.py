@@ -13,7 +13,12 @@ Counterpart of reference ``scheduled_queue.{h,cc}``:
 This Python implementation is the reference semantics for tests and the
 fallback when the native C++ engine (byteps_tpu/native) is unavailable; the
 eager engine uses whichever is loaded.  Under jit the same ordering rule is
-applied *statically* via ``BucketPlan.schedule_order()``.
+applied *statically* via ``BucketPlan.schedule_order()``: it fixes the order
+in which a step's collectives are issued, not whether they are hidden.  On
+the v5e the chain of packed buckets ran after the backward pass, all of it
+exposed (37.6 ms a step, PERF_LEDGER.jsonl PR 22); what hid communication
+was making a collective a leaf's own (12.7 ms exposed, ledger PR 28; see
+training/step.py).
 """
 
 from __future__ import annotations

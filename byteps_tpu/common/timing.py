@@ -56,7 +56,7 @@ def two_k_differenced_time(fn_s, fn_l, args, k_s: int, k_l: int,
 
     Returns seconds/iteration, or ``None`` when the median difference
     is non-positive (host noise exceeded the signal — the caller must
-    fall back AND say so; see bench.py's method strings).
+    fall back AND say so).
     """
     readback_barrier(fn_s(*args), fn_l(*args))  # warm / compile
     diffs = []

@@ -242,7 +242,7 @@ class WireCompressor:
         (training/zero.py) — which only ever pushes its OWNED span keys
         — holds ~1/world of the replicated client's residual state: the
         EF memory shards for free alongside the optimizer state.  This
-        hook is the accounting surface the bench/tests pin that on."""
+        hook is the accounting surface the tests pin that on."""
         with self._lock:
             return sum(int(r.nbytes) for n, r in self._residual.items()
                        if n.startswith(prefix))

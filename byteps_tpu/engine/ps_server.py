@@ -281,7 +281,7 @@ class _Handler(socketserver.BaseRequestHandler):
         _reg = get_registry()
         # registry-only (mirror=False): per-request trace detail is the
         # profiler's job; a counter event per request would tax the
-        # handler loop for a redundant series (bench_obs.py)
+        # handler loop for a redundant series
         m_reqs = _reg.counter("ps.requests", track="ps_server",
                               instants=False, mirror=False)
         m_errs = _reg.counter("ps.request_errors", track="ps_server",

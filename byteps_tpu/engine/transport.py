@@ -5,8 +5,8 @@ a pluggable ps-lite van (ZeroMQ / RDMA), while intra-machine traffic
 goes through a dedicated local layer (``BytePSSharedMemory`` POSIX shm,
 ``BytePSCommSocket`` AF_UNIX) that never touches the NIC (PAPER.md
 layer map).  Our wire engine was TCP-only, and on the colocated
-topology every test/bench/single-host-serve runs, per-frame TCP
-overhead is most of the round trip (BENCH_COMM.json loopback rows).
+topology every test and single-host serve runs, per-frame TCP overhead
+is a large part of the round trip.
 
 This module is the transport seam extracted from that socket plumbing.
 A *transport* is anything that duck-types the blocking stream-socket
@@ -234,7 +234,7 @@ _UDS_BUF = 4 * 1024 * 1024
 
 def free_port() -> int:
     """Grab an ephemeral loopback TCP port (bind-and-release).  The
-    one implementation behind every test/bench/chaos harness that
+    one implementation behind every test and chaos harness that
     spawns endpoints on fresh ports."""
     with socket.socket() as s:
         s.bind(("127.0.0.1", 0))

@@ -42,7 +42,7 @@ client interoperate.  The failure contract:
     desynchronize FIFO matching for every later reply.
 
 ``BYTEPS_WIRE_WINDOW=0`` disables the workers entirely and restores the
-serial blocking client — the A/B baseline ``bench_comm.py`` measures
+serial blocking client — the baseline the pipelined one is compared
 against.  See docs/wire.md.
 """
 
@@ -384,7 +384,7 @@ class ShardWorker:
         # except window occupancy: these fire several times per frame on
         # the I/O threads, per-frame trace detail already comes from the
         # client-queue/wire spans, and mirroring every bump measurably
-        # taxes the step (bench_obs.py) — scrapes still see live values
+        # taxes the step — scrapes still see live values
         # byte/frame/reply counters carry the transport label so a
         # scrape can attribute wire volume to tcp vs the local fast
         # paths per shard (docs/wire.md "Transports")

@@ -128,8 +128,7 @@ def classify_divergence(model: Transformer, variables, prompt,
 def _jitted_apply(model):
     """One jit wrapper per model: an inline ``jax.jit(model.apply)``
     would build a fresh wrapper (and recompile the full forward) on
-    every ``classify_divergence`` call — the bench invokes it up to 3x
-    per run."""
+    every ``classify_divergence`` call."""
     return jax.jit(model.apply)
 
 

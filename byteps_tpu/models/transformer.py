@@ -1361,10 +1361,10 @@ def init_cache(cfg: TransformerConfig, batch_size: int, max_len: int,
       mixed-dot path (the layout tensor-parallel decode shards over
       its head axis).
     * ``"auto"`` — flat on TPU for causal caches with a usable chunk
-      size: always for bf16, and for int8 under MHA only (the measured
-      win region — a GQA-shrunken s8 cache's byte saving no longer
-      pays for the kernel's in-VMEM dequant, so GQA int8 keeps the
-      grouped dense path; scripts/int8_flat_decode_ab.py).  Grouped
+      size: always for bf16, and for int8 under MHA only (where the
+      flat-s8 kernel won — a GQA-shrunken s8 cache's byte saving no
+      longer pays for the kernel's in-VMEM dequant, so GQA int8 keeps
+      the grouped dense path; ops/decode_attention.py).  Grouped
       otherwise (CPU tests keep the dense path — interpret-mode Pallas
       per decode step would crawl).
 

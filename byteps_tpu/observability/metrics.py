@@ -29,7 +29,7 @@ Exposition: :meth:`MetricsRegistry.snapshot` (plain dicts, used by
 
 One process-global registry (``get_registry()``) backs the per-process
 scrape endpoints; isolated ``MetricsRegistry()`` instances exist so
-tests and benches can count in a vacuum (the pattern the old per-class
+tests can count in a vacuum (the pattern the old per-class
 instances supported).
 """
 

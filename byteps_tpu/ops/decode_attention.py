@@ -299,13 +299,11 @@ def decode_attention_usable(q_shape, cache_len: int,
                             quant_cache: bool,
                             kv_heads: Optional[int] = None) -> bool:
     """Static gate for the auto-switch: tq=1, and for an s8 cache MHA
-    only (``kv_heads == H``).  The r5 on-chip sweep
-    (scripts/int8_flat_decode_ab.py) found the flat-s8 kernel wins
-    exactly where the cache is at its largest — MHA, KV*D=768: 0.654
-    ms/tok vs 0.714 bf16-flat and 2.570 s8-grouped at B=8/T=1024 —
-    while every GQA point loses (KV*D<=384: the GQA-shrunken cache's
-    byte saving no longer pays for the in-VMEM dequant and the
-    KV-deep scale dots; KV*D=128 measures 0.408 vs 0.312 dense).
+    only (``kv_heads == H``).  The kernel is chosen by shape because,
+    on an earlier runtime's chip, the flat-s8 kernel won exactly where
+    the cache is at its largest — MHA, KV*D=768 — and lost at every GQA
+    point (KV*D<=384: the GQA-shrunken cache's byte saving no longer
+    pays for the in-VMEM dequant and the KV-deep scale dots).
     GQA s8 caches keep the dense mixed-dot path; explicit
     ``init_cache(layout="flat")`` overrides.  Any cache length works —
     the kernel grid is ceil(S/block) with the tail masked — and wide

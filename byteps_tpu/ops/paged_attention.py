@@ -5,9 +5,8 @@ as a pool of fixed-size blocks and — until this kernel — materialized a
 dense ``[1, max_seq, ...]`` row per slot per decode tick via
 ``gather_paged_rows`` before attending it.  That gather re-copies the
 entire cache stream every tick, which is exactly the byte traffic the
-whole system exists to avoid (docs/rationale.md): the uniform-leg paged
-TPOT honestly ran ~1.15-1.3x dense (BENCH_SERVE.json
-``serve_paged_mixed``, PR 9).  This kernel is the vLLM PagedAttention
+whole system exists to avoid (docs/rationale.md).  This kernel is the
+vLLM PagedAttention
 move on the TPU decode kernel (ops/decode_attention.py): the **block
 table rides into the kernel** and the BlockSpec index map resolves grid
 step ``(b, j)`` to the *physical* block id, so each step DMAs one

@@ -189,9 +189,17 @@ def push_pull_tree(
     The pytree is packed into <=partition_bytes buckets (reference
     PartitionTensor semantics + TPU fusion, common/partition.py) and one
     collective is issued per bucket in priority order
-    (BucketPlan.schedule_order == scheduled_queue.cc ordering).  XLA's
-    latency-hiding scheduler overlaps the resulting async collective chain
-    with whatever compute neighbors the call.
+    (BucketPlan.schedule_order == scheduled_queue.cc ordering).
+
+    A bucket packed from several leaves stands between each leaf's
+    gradient and its collective: on the v5e XLA ran this whole chain
+    after the backward pass, 37.6 ms exposed a step = all of the
+    collective time (PERF_LEDGER.jsonl, PR 22, ``gpt2m_train_dp4``).  A
+    collective that is a leaf's own is hidden inside the backward pass
+    (12.7 ms exposed, ledger PR 28): that is ``reduce_scatter_tree`` /
+    ``all_gather_tree`` below, which the data-parallel step uses.  This
+    flat bucketed form remains for the replicated path and the eager API
+    and has no benchmark cell at world > 1.
     """
     if plan is None:
         plan = partition_mod.plan_buckets(grads, partition_bytes)

@@ -45,7 +45,7 @@ class ResilienceCounters:
     """Thread-safe monotonic counters, registry-backed.
 
     ``registry=None`` builds a private :class:`MetricsRegistry` —
-    isolated counting for tests/benches, the semantics standalone
+    isolated counting for tests, the semantics standalone
     instances always had.  ``get_counters()`` binds the process-global
     registry so the scrape endpoints see resilience activity."""
 

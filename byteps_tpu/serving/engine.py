@@ -540,8 +540,7 @@ class ServingEngine:
                 k *= 2
             # ngram floors at 2 (the documented contract): single-token
             # matches fire on any vocabulary reuse, and every false
-            # proposal costs a widened verify forward — exactly the
-            # overhead bound the non-repetitive bench leg gates
+            # proposal costs a widened verify forward
             self.spec = NgramProposer(k, max(2, spec_ngram))
         else:
             self.spec = None
@@ -2473,7 +2472,7 @@ class ServingEngine:
         buckets touched on the paged XLA fallback),
         ``prefill``/``chunk``/``verify`` at the number of distinct
         buckets touched, and the prefix copy/extract programs at 1 each
-        (asserted by tests and bench_serve.py)."""
+        (asserted by tests)."""
         return {"decode": self.decode_traces,
                 "decode_buckets": (len(self._paged_decode_fns)
                                    if self.paged else 1),

@@ -1096,7 +1096,7 @@ def _model_from_env(cfg_str: str):
     random-initialized weights unless ``BYTEPS_SERVE_CHECKPOINT`` points
     at a checkpoint produced by ``training.checkpoint``.  A serving
     process with random weights is still the real engine — that is what
-    the smoke/bench tooling runs against."""
+    the smoke tooling runs against."""
     import jax
     import jax.numpy as jnp
 

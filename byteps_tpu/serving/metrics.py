@@ -4,14 +4,14 @@ Same pattern as ``resilience/counters.py``: every observation lands in
 a :class:`~byteps_tpu.observability.metrics.MetricsRegistry` (the
 process-global one for ``get_serve_metrics()`` — what ``/metrics``,
 ``OP_STATS`` and the TCP STATS reply scrape live — or a private one per
-standalone ``ServeMetrics()`` so benches count in isolation).  When
+standalone ``ServeMetrics()`` so tests count in isolation).  When
 ``BYTEPS_TRACE_PATH`` is set each bump also lands on the shared
 chrome-trace timeline as a counter event (value track), so batch
 occupancy, queue depth, and token throughput render next to the
 engine's push/pull spans in Perfetto — unchanged from pre-registry
 traces.  Per-request latency samples (queue wait, TTFT, TPOT) feed
 bounded-reservoir registry histograms that back the ``summary()``
-percentiles the bench and the TCP STATS op report.
+percentiles the TCP STATS op reports.
 """
 
 from __future__ import annotations
@@ -53,8 +53,7 @@ PREEMPTIONS = "serve.preemptions"
 # blocks the XLA gather fallback materialized into dense rows this
 # tick (n_slots x high-water bucket, decode AND verify passes):
 # GATHERED_BLOCKS * pool.block_bytes is the per-tick cache-stream copy
-# the pos-capped gather shrinks and the fused kernel eliminates —
-# bench_serve.py --paged reports the reduction (serve_paged_kernel)
+# the pos-capped gather shrinks and the fused kernel eliminates
 GATHERED_BLOCKS = "serve.gathered_blocks"
 # speculative decoding (serving/engine.py spec_k > 0, serving/spec.py):
 # DECODE_TICKS counts ticks that ran a decode/verify forward (the

@@ -24,6 +24,9 @@ free to run the whole reduce concurrently with the forward+backward — the
 same overlap ByteScheduler gets from its barrier removal, with the same
 bounded staleness (each parameter update lags its gradient by exactly one
 iteration; ByteScheduler's lag is sub-iteration but nonzero per layer).
+Whether the chip's schedule uses that freedom has never been measured, and
+the synchronous step now hides all but 12.7 ms of its collectives
+(PERF_LEDGER.jsonl, PR 28; ROADMAP D8).
 ``tests/test_overlap.py`` verifies both the exact staleness semantics and,
 via jaxpr dependency analysis, that no collective depends on the batch.
 

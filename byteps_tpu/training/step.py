@@ -3,14 +3,26 @@
 The reference's hot path is loss.backward() firing per-gradient hooks that
 enqueue push_pull tasks drained by C++ threads (SURVEY.md §3.2).  The TPU
 rendering is one traced SPMD program per step: ``shard_map`` over the mesh,
-local backward, bucketed priority-ordered push_pull (collectives.py), optax
-update — XLA's latency-hiding scheduler overlaps the collective chain with
-the backward compute, which is precisely the role of the reference's
-10-thread pipeline (core_loops.cc).
+local backward, one collective per bucket in priority order
+(collectives.py), optax update.
+
+What hides the communication, as measured on the v5e (PERF_LEDGER.jsonl,
+``gpt2m_train_dp4``): a bucket packed from several leaves stands between
+each leaf's gradient and its collective, and XLA then ran the whole
+collective chain after the backward pass — 37.6 ms exposed a step, all of
+the collective time (ledger, PR 22).  A collective that is a leaf's own
+(``plan_share_buckets``: a leaf whose dim-0 share fills a bucket is
+scattered and gathered as it lies) follows that leaf's weight-gradient
+product and is hidden inside the backward pass — 12.7 ms exposed (ledger,
+PR 28).  So ``make_data_parallel_step`` reduce-scatters leaf by leaf, runs
+the optimizer on this worker's share and all-gathers the new parameters;
+the flat bucketed ``push_pull_tree`` remains for the replicated path and
+the eager API, and has no benchmark cell at world > 1.
 
 ``make_data_parallel_step`` is the Horovod-benchmark-equivalent step used by
-bench.py and the examples; model-parallel (tp/sp) steps compose GSPMD jit
-with these same pieces (see models/transformer.py and __graft_entry__.py).
+``benchmark/`` and the examples; model-parallel (tp/sp) steps compose GSPMD
+jit with these same pieces (see models/transformer.py and
+__graft_entry__.py).
 """
 
 from __future__ import annotations
@@ -629,8 +641,7 @@ def lm_loss_fn(model, fused_head: bool = False,
     speculative decoding.  Without it the early-exit readout is
     untrained and the draft is useless no matter how well the full
     model converges (measured: acceptance ~0.002 on a converged
-    vanilla-trained 12L model vs 0.70-0.88 with the term — see
-    bench.py's trained-speculative row).  Requires a
+    vanilla-trained 12L model vs 0.70-0.88 with the term).  Requires a
     ``models.transformer.Transformer`` (the truncation slices its
     ``block_i`` param subtree).
 

@@ -36,9 +36,9 @@ feed grads already summed across data-parallel workers (on-mesh via
 :func:`zero_spans` exactly, or a plain allreduce).
 
 Honest CPU-host caveats: this is the *eager* PS data path — host numpy
-math, one wire round trip batch per phase — built to measure and pin
-the byte/state accounting (bench_comm.py --zero), not to win
-wall-clock on a single host.  See docs/parallel.md.
+math, one wire round trip batch per phase — built to pin the
+byte/state accounting (tests/test_zero.py), not to win wall-clock on a
+single host.  See docs/parallel.md.
 """
 
 from __future__ import annotations
@@ -104,7 +104,7 @@ class ShardedOptimizerState:
          key, folded into the local replica; returns the params dict.
 
     ``step(grads)`` does both.  ``state_bytes()`` is the client
-    optimizer-state footprint the tests/bench pin (momentum only —
+    optimizer-state footprint the tests pin (momentum only —
     the params replica is identical in both legs by design).
     """
 
@@ -239,7 +239,7 @@ class ReplicatedOptimizerState:
     """The A/B baseline: FULL momentum client-side, FULL parameter-
     delta mutation per step, one ordinary wire key per tensor — the
     pre-ZeRO eager PS loop, behind the same split-phase API so the
-    bench/tests drive both legs with one harness.  Uses the same
+    tests drive both legs with one harness.  Uses the same
     ``sgd_momentum_update`` rule, so a ``world=1`` sharded group and
     this baseline are bitwise-identical by construction."""
 

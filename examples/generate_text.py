@@ -97,7 +97,7 @@ def main():
     # speculative decoding with the trained model's own first layer as
     # draft (inference.truncated_draft): on TRAINED weights the early
     # layers carry most of the next-token signal, so acceptance is high
-    # — the property the bench's random-init model cannot show
+    # — the property a random-init model cannot show
     from byteps_tpu.inference import speculative_generate, truncated_draft
 
     dmodel, dvars = truncated_draft(cfg, {"params": params}, 1)

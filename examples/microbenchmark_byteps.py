@@ -10,7 +10,7 @@ Note what this measures: the EAGER path is host-mediated (host tensor →
 device → collective → host), so host↔device transfer dominates — the
 same is true of the reference's eager op (its GPU D2H/H2D stages).  The
 training hot path (``make_data_parallel_step``) keeps tensors on-device
-and does not pay this; use ``bench.py`` for end-to-end step numbers.
+and does not pay this; use ``benchmark/run.py`` for end-to-end step numbers.
 """
 
 from __future__ import annotations

@@ -6,7 +6,7 @@ prescribes: run every test on a virtual 8-device CPU platform so collective
 numerics and sharding are exercised without TPU hardware.
 
 The environment variables are for the child processes tests spawn
-(launcher roles, chaos scripts, benches); this process is configured
+(launcher roles, chaos scripts); this process is configured
 through ``jax.config`` before any backend is initialized.
 """
 
@@ -53,48 +53,3 @@ def _reset_global_state():
 def devices():
     return jax.devices()
 
-
-# --------------------------------------------------------------------------
-# Tier-1 duration budget guard (docs/wire.md, ROADMAP "tier-1 budget"):
-# the fast suite lives inside a hard 870 s timeout with thin headroom, and
-# that headroom historically eroded one slow test at a time.  On budgeted
-# runs (the tier-1 invocation, `-m 'not slow'`) any non-slow test whose
-# CALL phase exceeds the budget FAILS with an actionable message — the
-# in-run equivalent of parsing the `--durations` report after the fact,
-# with blame attached to the exact offender.  Full/slow runs (no
-# `not slow` markexpr) are never budgeted.  Override (e.g. for a known
-# throttled host): BYTEPS_TEST_DURATION_BUDGET_S, 0 disables.
-# --------------------------------------------------------------------------
-
-_DURATION_BUDGET_S = float(
-    os.environ.get("BYTEPS_TEST_DURATION_BUDGET_S", "20"))
-
-
-def _duration_budget_active(config) -> bool:
-    return (_DURATION_BUDGET_S > 0
-            and "not slow" in (getattr(config.option, "markexpr", "") or ""))
-
-
-def duration_budget_verdict(duration_s: float, budget_s: float):
-    """None when within budget, else the failure message (split out so
-    the guard logic itself is unit-testable)."""
-    if duration_s <= budget_s:
-        return None
-    return (f"tier-1 duration budget exceeded: call took {duration_s:.1f}s "
-            f"> {budget_s:.0f}s. slow-mark this test (keeping a fast "
-            f"variant) or split it — the fast suite must fit the 870s "
-            f"tier-1 timeout (ROADMAP.md). Budget knob: "
-            f"BYTEPS_TEST_DURATION_BUDGET_S.")
-
-
-@pytest.hookimpl(hookwrapper=True)
-def pytest_runtest_makereport(item, call):
-    outcome = yield
-    report = outcome.get_result()
-    if (report.when == "call" and report.passed
-            and _duration_budget_active(item.config)
-            and item.get_closest_marker("slow") is None):
-        msg = duration_budget_verdict(call.duration, _DURATION_BUDGET_S)
-        if msg is not None:
-            report.outcome = "failed"
-            report.longrepr = f"{item.nodeid}: {msg}"

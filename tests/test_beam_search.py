@@ -48,7 +48,7 @@ def _reference_beam(model, variables, prompt, n, k):
     return np.stack(out_toks), np.array(out_scores)
 
 
-@pytest.mark.slow  # ~21s: brute-force all-path reference enumeration (tier-1 duration budget); beam1_is_greedy/eos/length_penalty keep fast coverage
+@pytest.mark.slow  # ~21s: brute-force all-path reference enumeration; beam1_is_greedy/eos/length_penalty keep fast coverage
 def test_beam_matches_reference():
     cfg, model, tokens, variables = _model()
     n, k = 4, 3

@@ -324,4 +324,7 @@ def test_multiworker_ef_compression_converges():
     state, m0 = step(state, batch)
     for _ in range(60):
         state, m = step(state, batch)
+        # wait each step: 60 queued 8-device programs can starve XLA:CPU's
+        # in-process collective rendezvous on a loaded host (SIGABRT)
+        jax.block_until_ready(m)
     assert float(m["loss"]) < float(m0["loss"]) / 4

@@ -80,8 +80,8 @@ def test_traced_pos_one_program():
 def test_usable_gate():
     assert decode_attention_usable((8, 1, 12, 64), 1280, False)
     assert not decode_attention_usable((8, 4, 12, 64), 1280, False)
-    # s8 auto: MHA only (the measured win region — GQA's shrunken cache
-    # no longer pays for the in-VMEM dequant, scripts/int8_flat_decode_ab)
+    # s8 auto: MHA only (where the flat-s8 kernel won — GQA's shrunken
+    # cache no longer pays for the in-VMEM dequant)
     assert decode_attention_usable((8, 1, 12, 64), 1280, True,
                                    kv_heads=12)
     assert not decode_attention_usable((8, 1, 12, 64), 1280, True,
@@ -118,7 +118,7 @@ def test_int8_matches_grouped_q8_path(H, KV, pos):
                                rtol=2e-3, atol=2e-3)
 
 
-@pytest.mark.slow  # ~10s: two full generates (tier-1 duration budget); int8_matches_grouped_q8_path/window/tail-chunk parity stays fast
+@pytest.mark.slow  # ~10s: two full generates; int8_matches_grouped_q8_path/window/tail-chunk parity stays fast
 def test_flat_int8_generate_matches_grouped_int8():
     """End to end: generate() on a flat int8 cache (layout='flat',
     kv_quant) produces the same tokens as the grouped int8 cache — the

@@ -61,7 +61,7 @@ def test_incremental_decode_matches_forward():
         np.asarray(got), np.asarray(full), rtol=2e-5, atol=2e-5)
 
 
-@pytest.mark.slow  # ~16s: token-by-token reference loop (tier-1 duration budget); incremental_decode/prefill/windowed parity stay fast
+@pytest.mark.slow  # ~16s: token-by-token reference loop; incremental_decode/prefill/windowed parity stay fast
 def test_greedy_generate_matches_reference_loop():
     """The scan-based generate equals a naive loop that re-runs the full
     forward on the growing sequence each step."""
@@ -317,7 +317,7 @@ def test_flat_cache_generate_matches_grouped(kv):
                                   np.asarray(out_f["tokens"]))
 
 
-@pytest.mark.slow  # ~11s: token-by-token stepwise reference loop (tier-1 duration budget); flat_cache_generate_matches_grouped keeps flat-layout parity fast
+@pytest.mark.slow  # ~11s: token-by-token stepwise reference loop; flat_cache_generate_matches_grouped keeps flat-layout parity fast
 def test_flat_cache_stepwise_matches_forward():
     """Per-token decode against the flat cache reproduces the full causal
     forward — including the tq>1-at-pos>0 dense fallback (speculative

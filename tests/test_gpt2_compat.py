@@ -33,7 +33,7 @@ def _hf_model(n_layer=2, n_head=2, n_embd=32, vocab=97, n_positions=64,
     return transformers.GPT2LMHeadModel(cfg).eval()
 
 
-@pytest.mark.slow  # ~12s: HF torch forward (tier-1 duration budget); inference_stack_on_gpt2 + gpt2_arch_trains stay fast, llama keeps a fast torch-logits parity
+@pytest.mark.slow  # ~12s: HF torch forward; inference_stack_on_gpt2 + gpt2_arch_trains stay fast, llama keeps a fast torch-logits parity
 def test_logits_match_torch():
     hf = _hf_model()
     model, variables = load_gpt2(hf)
@@ -44,7 +44,7 @@ def test_logits_match_torch():
     np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-4)
 
 
-@pytest.mark.slow  # ~11s: HF torch generation loop (tier-1 duration budget); gpt2_arch_trains_with_fused_loss + config mapping stay fast
+@pytest.mark.slow  # ~11s: HF torch generation loop; gpt2_arch_trains_with_fused_loss + config mapping stay fast
 def test_greedy_generation_matches_torch():
     hf = _hf_model(seed=3)
     model, variables = load_gpt2(hf)
@@ -63,7 +63,7 @@ def test_greedy_generation_matches_torch():
 def test_inference_stack_on_gpt2():
     """Beam search, speculative decoding, int8 quantization, and the KV
     cache all run on converted GPT-2 weights.  Slow: four inference
-    modes x compile on the GPT-2 arch (tier-1 duration budget);
+    modes x compile on the GPT-2 arch;
     test_greedy_generation_matches_torch keeps the fast conversion
     parity coverage."""
     hf = _hf_model(seed=5)

@@ -106,7 +106,7 @@ def test_gqa_decode_matches_full_forward(kv):
             atol=2e-5, rtol=2e-5)
 
 
-@pytest.mark.slow  # ~8s: naive reference decode loop (tier-1 duration budget); groups_of_one_is_mha + grouped_q8_cached stay fast
+@pytest.mark.slow  # ~8s: naive reference decode loop; groups_of_one_is_mha + grouped_q8_cached stay fast
 def test_gqa_generate_matches_naive_and_int8_cache():
     cfg = TransformerConfig(num_heads=4, num_kv_heads=1, **KW)
     m = Transformer(cfg)
@@ -164,8 +164,7 @@ def test_gqa_train_grads_flow():
 
     params = vs["params"]
     opt = tx.init(params)
-    # jitted once: six op-by-op eager backward passes ran ~15-17 s,
-    # inside reach of the 20 s tier-1 per-test budget under host load
+    # jitted once: six op-by-op eager backward passes ran ~15-17 s
     vg = jax.jit(jax.value_and_grad(loss))
     l0, grads = vg(params)
     gnorms = [float(jnp.linalg.norm(g))

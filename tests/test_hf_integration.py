@@ -15,7 +15,7 @@ transformers = pytest.importorskip("transformers")
 from byteps_tpu.training import make_data_parallel_step, shard_batch
 
 
-@pytest.mark.slow  # ~11s: flax-bert train compile (tier-1 duration budget); flax_bert_rides_flash_attention keeps fast HF-integration coverage
+@pytest.mark.slow  # ~11s: flax-bert train compile; flax_bert_rides_flash_attention keeps fast HF-integration coverage
 def test_flax_bert_trains_through_push_pull_step():
     from transformers import BertConfig, FlaxBertForSequenceClassification
 

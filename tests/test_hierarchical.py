@@ -528,25 +528,6 @@ def test_init_accepts_consistent_local_contract():
     bps.shutdown()
 
 
-# ------------------------------------------------- duration budget guard
-
-
-def test_duration_budget_guard_logic():
-    """The tier-1 duration-budget guard (conftest): within budget ->
-    None; over budget -> an actionable failure message.  The hook
-    itself is exercised by every tier-1 run."""
-    import os
-
-    from conftest import _DURATION_BUDGET_S, duration_budget_verdict
-
-    assert duration_budget_verdict(1.0, 20.0) is None
-    assert duration_budget_verdict(20.0, 20.0) is None
-    msg = duration_budget_verdict(25.0, 20.0)
-    assert "slow-mark" in msg and "25.0s" in msg
-    if "BYTEPS_TEST_DURATION_BUDGET_S" not in os.environ:
-        assert _DURATION_BUDGET_S == 20.0  # tier-1 default is guarded
-
-
 # ------------------------------------------------- optimizer local axis
 
 

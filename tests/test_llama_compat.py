@@ -81,7 +81,7 @@ def test_cached_decode_matches_hf_greedy():
     np.testing.assert_array_equal(np.asarray(out["tokens"]), want)
 
 
-@pytest.mark.slow  # ~11s (tier-1 duration budget); logits_match_torch + cached_decode_matches_hf_greedy keep fast llama parity
+@pytest.mark.slow  # ~11s; logits_match_torch + cached_decode_matches_hf_greedy keep fast llama parity
 def test_inference_stack_runs_on_llama():
     """Beam search, speculative (truncated self-draft), and int8
     weight-only quantization all run on converted LLaMA weights."""
@@ -142,7 +142,7 @@ def test_llama3_rope_scaling_and_head_dim_match_torch():
     np.testing.assert_allclose(got, want, atol=3e-5, rtol=3e-5)
 
 
-@pytest.mark.slow  # ~18s: stepwise HF forward per token (tier-1 duration budget); cached_decode_matches_hf_greedy keeps fast parity
+@pytest.mark.slow  # ~18s: stepwise HF forward per token; cached_decode_matches_hf_greedy keeps fast parity
 def test_llama3_cached_decode_matches_hf_forward_stepwise():
     """Cached decode under llama3 scaling + explicit head_dim must
     reproduce HF's forward logits at every step (teacher-forced).  NOT

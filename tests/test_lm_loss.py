@@ -27,7 +27,7 @@ def test_fused_head_matches_naive_loss_and_grads():
 
     naive = lm_loss_fn(model, fused_head=False)
     fused = lm_loss_fn(model, fused_head=True)
-    # (jitted: op by op this test sat at 14 s of the 20 s tier-1 budget)
+    # (jitted: op by op this test took 14 s)
     l_n, g_n = jax.jit(jax.value_and_grad(
         lambda p: naive(p, {}, batch)[0]))(params)
     l_f, g_f = jax.jit(jax.value_and_grad(
@@ -165,8 +165,7 @@ def test_early_exit_training_makes_truncated_draft_viable():
     early-exit readout (ln_f + head over block_0) untrained, so the
     truncated self-draft is rejected even by a CONVERGED target; adding
     the early_exit aux term trains the exit and speculative decoding
-    accepts the draft at a high rate.  (The bench's trained-speculative
-    row rides exactly this mode.)"""
+    accepts the draft at a high rate."""
     from byteps_tpu.inference import speculative_generate, truncated_draft
 
     cfg = TransformerConfig(vocab_size=64, num_layers=3, num_heads=4,

@@ -8,7 +8,7 @@ import pytest
 from byteps_tpu.models import ResNet18, ResNet50, VGG11, Transformer, TransformerConfig
 
 
-@pytest.mark.slow  # ~14s: full ResNet-50 compile (tier-1 duration budget); resnet_train_mode_updates_stats keeps fast resnet coverage
+@pytest.mark.slow  # ~14s: full ResNet-50 compile; resnet_train_mode_updates_stats keeps fast resnet coverage
 def test_resnet50_forward_shapes():
     model = ResNet50(num_classes=10, num_filters=8)
     x = jnp.zeros((2, 64, 64, 3))
@@ -34,7 +34,7 @@ def test_resnet_train_mode_updates_stats():
     assert any(not np.allclose(a, b) for a, b in zip(old, new))
 
 
-@pytest.mark.slow  # ~18s: 11-layer VGG compile flirts with the tier-1 duration budget under host load; resnet_train_mode_updates_stats keeps fast conv coverage
+@pytest.mark.slow  # ~18s: 11-layer VGG compile; resnet_train_mode_updates_stats keeps fast conv coverage
 def test_vgg_forward():
     model = VGG11(num_classes=10, channels=(8, 8, 16, 16, 16))
     x = jnp.zeros((2, 32, 32, 3))
@@ -121,7 +121,7 @@ def test_mobilenet_v2_forward_and_train_step():
     assert np.isfinite(float(metrics["loss"]))
 
 
-@pytest.mark.slow  # ~9s (tier-1 duration budget); resnet18/transformer forwards keep fast classic-model coverage
+@pytest.mark.slow  # ~9s; resnet18/transformer forwards keep fast classic-model coverage
 def test_lenet_alexnet_forward():
     from byteps_tpu.models import AlexNet, LeNet
 

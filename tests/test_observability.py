@@ -573,27 +573,3 @@ class TestServingObservability:
 # tests/test_analysis.py::test_every_config_knob_documented and
 # scripts/lint.py — AST-accurate, and extended to flag raw BYTEPS_*
 # environ reads anywhere in the package.
-
-
-# ------------------------------------------------------------ bench (slow)
-
-
-@pytest.mark.slow
-def test_bench_obs_overhead():
-    """Full observability ON must cost < 3% step time on the wire path
-    and < 3% burst time on the serve path (paired-median protocol —
-    see bench_obs.py's module doc for why min-of-reps cannot resolve
-    this on a throttled host)."""
-    proc = subprocess.run(
-        [sys.executable, os.path.join(REPO, "bench_obs.py"),
-         "--steps", "30", "--pairs", "9", "--requests", "6",
-         "--tokens", "16", "--no-archive"],
-        capture_output=True, text=True, timeout=900, cwd=REPO)
-    assert proc.returncode == 0, proc.stderr[-2000:]
-    rows = [json.loads(line) for line in proc.stdout.splitlines()
-            if line.startswith("{")]
-    by_metric = {r["metric"]: r for r in rows}
-    wire = by_metric["obs_overhead_wire"]
-    serve = by_metric["obs_overhead_serve"]
-    assert wire["overhead_pct"] < 3.0, wire
-    assert serve["overhead_pct"] < 3.0, serve

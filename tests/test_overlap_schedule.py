@@ -1,25 +1,22 @@
-"""Compiled-schedule overlap proof (VERDICT r2 #2).
+"""Where XLA:CPU places a step's gradient collectives in its schedule.
 
-``tests/test_overlap.py`` proves at the *jaxpr* level that the delayed-grad
-step's collectives are independent of the current batch — necessary but not
-sufficient.  These tests assert the property the user actually pays for: in
-the **optimized, scheduled HLO module** (``is_scheduled=true`` — instruction
-order in the entry computation *is* the execution schedule), the gradient
-collectives are placed in the middle of the compute stream, with substantial
-compute scheduled after them:
+``tests/test_overlap.py`` shows at the *jaxpr* level that the delayed-grad
+step's collectives are independent of the current batch.  These tests look
+one level down, at the **optimized, scheduled HLO module** the CPU backend
+compiles (``is_scheduled=true`` — instruction order in the entry
+computation is the order of issue), and hold two structural properties of
+the steps:
 
-  * sync bucketed step: early buckets' reduce-scatter is issued while later
-    backward compute is still scheduled behind it (per-bucket independence —
-    the reference's per-tensor hook overlap, torch/__init__.py:112-154);
+  * synchronous step: collectives are issued with backward compute still
+    scheduled behind them (nothing forces them to the end of the program);
   * delayed-grad step: the whole reduce chain (through the final all-gather)
-    straddles the batch's forward+backward (cross-iteration independence —
-    the ByteScheduler barrier removal, bytescheduler/torch/optimizer.py:180-214).
+    is issued with the batch's forward+backward still pending — impossible
+    for a synchronous step, whose update is terminal.
 
-On TPU backends collectives execute on the DMA/ICI queues, so mid-schedule
-issue = concurrent execution; the same structural check compiled against a
-real TPU topology (AOT, no chips needed) runs in
-``scripts/prove_overlap_schedule.py`` and its output is archived in
-``docs/overlap_proof.md``.
+This is a statement about program structure on the CPU backend, not about
+time on a chip: a mid-schedule issue does not say the collective is hidden.
+How much collective time a step exposes on the v5e is measured by the
+benchmark (``collectives.exposed_ms_per_step``, PERF.md §5).
 """
 
 import re
@@ -104,14 +101,8 @@ def mesh():
 
 
 def test_sync_step_buckets_straddle_backward(mesh):
-    """Bucketed DP step: the compiled schedule issues bucket collectives
-    with compute still behind them — per-bucket overlap with backward.
-
-    History: this carried ``xfail(strict=False)`` for an XLA:CPU
-    scheduler regression (collectives sunk to ~the end of the entry
-    schedule — PARITY.md) and silently xpassed once the build moved on.
-    The mark is dropped so a real schedule regression fails loudly
-    again (the delayed-grad variant below lost its mark the same way)."""
+    """Data-parallel step: the compiled schedule issues the gradient
+    collectives with compute still behind them."""
     tx = optax.sgd(0.1, momentum=0.9)
     step = make_data_parallel_step(_loss_fn, tx, mesh)
     state = jax.eval_shape(lambda p: create_train_state(p, step.tx), _PARAMS)
@@ -123,18 +114,13 @@ def test_sync_step_buckets_straddle_backward(mesh):
     assert before >= 2, f"no compute before first collective (idx {first})"
     assert after >= 3, (
         f"collectives scheduled after essentially all compute "
-        f"({after} compute ops after) — no overlap in the schedule")
+        f"({after} compute ops after)")
 
 
 def test_delayed_step_collectives_straddle_whole_batch_compute(mesh):
     """Delayed-grad step: the *entire* reduce chain — including the final
     all-gather — is scheduled with this batch's compute still pending,
-    which is impossible for a synchronous step (its update is terminal).
-
-    History: carried ``xfail(strict=False)`` for an XLA:CPU scheduler
-    placement divergence (1 compute op after the reduce chain where the
-    assertion demands >= 3 — PARITY.md).  jaxlib 0.9.0 schedules it as
-    asserted, so the mark is dropped and a regression fails loudly."""
+    which is impossible for a synchronous step (its update is terminal)."""
     tx = optax.sgd(0.1, momentum=0.9)
     step = make_delayed_grad_step(_loss_fn, tx, mesh)
     state = jax.eval_shape(

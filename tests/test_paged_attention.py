@@ -187,7 +187,7 @@ def test_engine_kernel_on_vs_gather_token_parity_seeded(tiny, prompts):
         np.testing.assert_array_equal(a, b)
 
 
-@pytest.mark.slow  # ~19s: two engines + verify-bucket compiles flirt with the tier-1 duration budget under host load; engine_kernel_on_vs_gather_token_parity keeps fast fused-kernel coverage, test_speculative keeps fast spec coverage
+@pytest.mark.slow  # ~19s: two engines + verify-bucket compiles; engine_kernel_on_vs_gather_token_parity keeps fast fused-kernel coverage, test_speculative keeps fast spec coverage
 def test_engine_kernel_spec_verify_parity(tiny):
     """Speculative decoding rides the SAME kernel at k+1 query
     positions: spec-on kernel streams match spec-off kernel streams
@@ -225,7 +225,7 @@ def test_engine_kernel_spec_verify_parity_seeded(tiny):
         np.testing.assert_array_equal(a, b)
 
 
-@pytest.mark.slow  # ~19s: three engine runs flirt with the tier-1 duration budget under host load; test_serving_paged preemption_under_block_pressure_greedy keeps fast preempt-resume coverage
+@pytest.mark.slow  # ~19s: three engine runs; test_serving_paged preemption_under_block_pressure_greedy keeps fast preempt-resume coverage
 def test_engine_kernel_preempt_resume_mid_stream(tiny):
     """Block pressure preempting a kernel-path request back to QUEUED
     and resuming it by re-prefill keeps the stream token-identical to

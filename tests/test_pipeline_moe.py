@@ -66,7 +66,7 @@ def test_pipeline_forward_matches_sequential():
     )
 
 
-@pytest.mark.slow  # ~19s: pipeline bwd compile (tier-1 duration budget); forward/remat/ep parity stay fast
+@pytest.mark.slow  # ~19s: pipeline bwd compile; forward/remat/ep parity stay fast
 def test_pipeline_grads_match_sequential():
     params = _stacked_params(jax.random.PRNGKey(2))
     micros = jax.random.normal(jax.random.PRNGKey(3), (N_MICRO, MB, D))

@@ -123,7 +123,7 @@ def test_quant_generate():
     assert "scale" not in variables["params"]["block_0"]["attn"]["q"]
 
 
-@pytest.mark.slow  # ~11s (tier-1 duration budget); int8_kv_cache_attention_close_to_fp + gqa/tp int8 parity stay fast
+@pytest.mark.slow  # ~11s; int8_kv_cache_attention_close_to_fp + gqa/tp int8 parity stay fast
 def test_int8_kv_cache_decode_matches_fp_cache():
     """Generation against the int8 KV cache (kv_quant=True) matches the
     fp-cache generation on a small model — the per-(position, head)

@@ -456,7 +456,7 @@ def test_shard_death_failover_restart_bitwise_recovery():
     heartbeat sees it, state migrates back; final pulled parameters are
     bit-for-bit identical to the no-fault run.
 
-    Slow-marked (PR 4 tier-1 budget): the full 30-step
+    Slow-marked: the full 30-step
     kill/degrade/restart/migrate cycle with heartbeat waits; the fast
     failover coverage stays in tier-1 via
     test_degraded_mode_routes_and_reinits_without_heartbeat,

@@ -71,7 +71,7 @@ def test_rope_flash_matches_local():
         atol=3e-5, rtol=3e-5)
 
 
-@pytest.mark.slow  # ~10s: naive reference decode loop (tier-1 duration budget); rope_swiglu_decode_matches_full_forward stays fast
+@pytest.mark.slow  # ~10s: naive reference decode loop; rope_swiglu_decode_matches_full_forward stays fast
 def test_rope_generate_matches_naive_and_int8_cache():
     cfg = TransformerConfig(**KW)
     m = Transformer(cfg)
@@ -91,7 +91,7 @@ def test_rope_generate_matches_naive_and_int8_cache():
                                   np.asarray(out["tokens"]))
 
 
-@pytest.mark.slow  # ~11s: full train-step compile (tier-1 duration budget); rope decode/generate/flash/ring parity stays fast
+@pytest.mark.slow  # ~11s: full train-step compile; rope decode/generate/flash/ring parity stays fast
 def test_rope_swiglu_train_step_decreases_loss():
     import optax
 

@@ -1,4 +1,4 @@
-"""CI wiring for scripts/router_chaos.py and the router bench legs.
+"""CI wiring for scripts/router_chaos.py.
 
 The chaos proof (ISSUE 11 acceptance): N in-process replicas behind
 the router with a fault-injecting proxy on every replica leg, a
@@ -42,38 +42,6 @@ def test_router_chaos_kill_and_drain(temperature):
     assert stats["killed_replica"] is not None
     assert stats["redispatches"] >= 1
     assert stats["drain_ok"] is True
-
-
-@pytest.mark.slow
-def test_bench_router_failover_completes_across_kill(tmp_path):
-    """The failover bench row: the kill leg completes EVERY request
-    token-identical (availability degrades to latency, never to
-    correctness) and actually exercised re-dispatch."""
-    import bench_serve
-
-    row = bench_serve.router_failover(
-        requests=10, tokens=16, slots=4,
-        out_path=str(tmp_path / "BENCH_SERVE.json"))
-    assert row["steady"]["completed"] == 10
-    assert row["steady"]["mismatches"] == 0
-    assert row["failover"]["completed"] == 10
-    assert row["failover"]["mismatches"] == 0
-    assert row["failover"]["failovers"] >= 1
-
-
-@pytest.mark.slow
-def test_bench_router_affinity_beats_round_robin(tmp_path):
-    """The placement bench row: on skewed shared-prefix traffic the
-    prefix-affinity router's aggregate cache hit rate must beat
-    round-robin (and be high in absolute terms)."""
-    import bench_serve
-
-    row = bench_serve.router_affinity(
-        out_path=str(tmp_path / "BENCH_SERVE.json"))
-    assert row["hit_rate_affinity"] > row["hit_rate_rr"], row
-    assert row["hit_rate_affinity"] >= 0.8, row
-    assert (row["prefill_tokens_affinity"]
-            < row["prefill_tokens_rr"]), row
 
 
 @pytest.mark.slow
@@ -125,24 +93,6 @@ def test_router_chaos_kill_prefill_mid_ship(temperature):
 
 
 @pytest.mark.slow
-def test_bench_serve_disagg_mixed_no_mismatch(tmp_path):
-    """The disaggregation bench row: the mixed long/short leg completes
-    with ZERO mismatches in both modes, actually ships blocks, and the
-    decode tier's short-request TPOT p99 grows no faster with prompt
-    length than colocated serving (the point of the split)."""
-    import bench_serve
-
-    row = bench_serve.disagg_ab(
-        out_path=str(tmp_path / "BENCH_SERVE.json"))
-    assert row["disagg"]["mismatches"] == 0, row
-    assert row["colocated"]["mismatches"] == 0, row
-    assert row["disagg"]["shipped_blocks"] > 0, row
-    assert row["disagg"]["fallbacks"] == 0, row
-    assert (row["disagg"]["tpot_p99_growth"]
-            <= row["colocated"]["tpot_p99_growth"]), row
-
-
-@pytest.mark.slow
 def test_router_chaos_load_spike():
     """The elastic-capacity chaos leg (ISSUE 18 acceptance): a 1x ->
     4x -> 1x load wave against a live autoscaling controller — the
@@ -165,42 +115,3 @@ def test_router_chaos_load_spike():
     assert stats["final_replicas"] == 1
     assert (stats["best_effort_ok"] + stats["best_effort_shed"]
             + stats["guaranteed_ok"] == stats["requests"])
-
-
-@pytest.mark.slow
-def test_bench_autoscale_spike(tmp_path):
-    """The elasticity bench row: the elastic leg scales 1 -> >1 -> 1,
-    sheds ZERO guaranteed requests, sheds strictly fewer best-effort
-    requests than the fixed single-replica leg under the same
-    sustained spike, and keeps the guaranteed spike p99 no worse than
-    fixed — elasticity converts would-be sheds into completions
-    without paying for it in the guaranteed tail."""
-    import bench_serve
-
-    row = bench_serve.autoscale_spike(
-        out_path=str(tmp_path / "BENCH_SERVE.json"))
-    el, fx = row["autoscale"], row["fixed"]
-    assert el["untyped"] == 0 and fx["untyped"] == 0
-    assert el["scale_ups"] >= 1 and el["scale_downs"] >= 1
-    assert el["shed_guaranteed"] == 0
-    assert el["peak_replicas"] > 1 and el["final_replicas"] == 1
-    assert el["shed_best_effort"] < fx["shed_best_effort"], row
-    assert el["spike_p99_s"] <= fx["spike_p99_s"] * 1.1, row
-
-
-@pytest.mark.slow
-def test_bench_router_ha_completes_across_router_kill(tmp_path):
-    """The router-HA bench row: the router-kill leg completes EVERY
-    request token-identical (availability degrades to takeover-window
-    latency, never to correctness) and exactly one takeover fired."""
-    import bench_serve
-
-    row = bench_serve.router_ha(
-        requests=10, tokens=16, slots=4,
-        out_path=str(tmp_path / "BENCH_SERVE.json"))
-    assert row["steady"]["completed"] == 10
-    assert row["steady"]["mismatches"] == 0
-    assert row["router_kill"]["completed"] == 10
-    assert row["router_kill"]["mismatches"] == 0
-    assert row["router_kill"]["takeovers"] == 1
-    assert row["completion_rate"] == 1.0

@@ -53,30 +53,6 @@ def test_serve_smoke_prefix_share_parity(temperature):
 
 
 @pytest.mark.slow
-def test_bench_serve_prefix_share_hit_rate_and_flop_reduction(tmp_path):
-    """The prefix-cache acceptance row: >= 90% hit rate on the shared-
-    system-prompt workload and a prefill-token reduction matching what
-    the hit rate buys (the throttle-proof FLOP/token criterion; the
-    wall-clock TTFT speedup is recorded in the archived row and
-    asserted on the real BENCH_SERVE.json run)."""
-    import bench_serve
-
-    row = bench_serve.prefix_share(
-        requests=10, shared_len=64, tail_len=6, tokens=8, slots=4,
-        d_model=128, layers=2, chunk=32, reps=1,
-        out_path=str(tmp_path / "BENCH_SERVE.json"))
-    assert row["mismatches"] == 0
-    assert row["hit_rate"] >= 0.9, row
-    # every hit skipped shared_len tokens of prefill compute
-    assert row["prefix_hit_tokens"] >= 0.9 * 10 * 64
-    assert row["prefill_tokens_on"] <= 0.5 * row["prefill_tokens_off"], row
-    # no wall-clock assert here: with reps=1 there is no min-of-reps
-    # noise floor, and this host's CPU throttle can swing a single
-    # timed run either way — the real BENCH_SERVE.json run (reps=3,
-    # interleaved) asserts the TTFT bar
-
-
-@pytest.mark.slow
 @pytest.mark.parametrize("temperature", [0.0, 0.8])
 def test_serve_smoke_paged_parity(temperature):
     """Paged KV cache under randomized threaded arrivals on a
@@ -119,91 +95,6 @@ def test_serve_smoke_paged_prefix_share_parity(temperature):
 
 
 @pytest.mark.slow
-def test_bench_serve_paged_concurrency_at_fixed_hbm(tmp_path):
-    """The paged acceptance row: at the SAME KV-byte budget, the paged
-    engine holds >= 2x the dense engine's concurrent requests on a
-    mixed long/short workload (dense is OOM-bounded by worst-case
-    max_seq rows), with bit-exact token parity between the engines.
-    TTFT/TPOT deltas are archived, not asserted — this 2-vCPU host's
-    throttle swings single timed runs (the real BENCH_SERVE.json run
-    records them)."""
-    import bench_serve
-
-    row = bench_serve.paged_ab(
-        long_reqs=2, long_len=96, short_reqs=10, short_len=16,
-        tokens=8, slots=12, dense_slots=3, d_model=128, layers=2,
-        max_seq=128, chunk=32,
-        out_path=str(tmp_path / "BENCH_SERVE.json"))
-    assert row["mismatches"] == 0
-    assert row["paged_peak_concurrent"] >= \
-        2 * row["dense_peak_concurrent"], row
-    assert row["compile_counts_paged"]["decode"] == \
-        row["compile_counts_paged"]["decode_buckets"]
-
-
-@pytest.mark.slow
-def test_bench_serve_tp_paged_ab(tmp_path):
-    """The tensor-parallel serving acceptance row (serve_tp_paged,
-    docs/parallel.md): a tp=2 paged engine is token-identical to tp=1
-    on the same mixed workload, and at a FIXED per-shard KV byte
-    budget (each shard's blocks are half the bytes, so the same
-    per-device budget buys 2x blocks) it sustains >= 1.3x the
-    concurrent residency.  Wall-clock is archived, not asserted — two
-    shard loops on a 2-vCPU host measure overhead, not the mesh."""
-    import bench_serve
-
-    row = bench_serve.tp_ab(
-        long_reqs=2, long_len=96, short_reqs=10, short_len=16,
-        tokens=32, slots=12, base_slots=1, d_model=128, layers=2,
-        max_seq=128, chunk=32,
-        out_path=str(tmp_path / "BENCH_SERVE.json"))
-    assert row["mismatches"] == 0
-    assert row["concurrency_ratio"] >= 1.3, row
-    assert row["tp_blocks"] == 2 * row["tp1_blocks"]
-
-
-@pytest.mark.slow
-def test_bench_serve_paged_kernel_ab(tmp_path):
-    """The fused-kernel acceptance row (serve_paged_kernel): kernel-on
-    decode is token-identical to the gather path and never gathers,
-    and the pos-capped fallback gather measurably shrinks gathered
-    bytes/tick vs the full table width PR 9 streamed (the
-    hardware-transferable number — kernel wall time on this CPU host
-    is interpret-mode and flagged as such in the row)."""
-    import bench_serve
-
-    row = bench_serve.paged_kernel_ab(
-        requests=8, tokens=8, prompt_lens=(8, 24, 56), slots=4,
-        d_model=128, layers=2, max_seq=128, block=16,
-        out_path=str(tmp_path / "BENCH_SERVE.json"))
-    assert row["mismatches"] == 0
-    assert row["kernel_gathered_blocks"] == 0
-    assert row["gather_bytes_reduction"] > 1.0, row
-    assert row["compile_counts_kernel"]["decode"] == 1
-
-
-@pytest.mark.slow
-def test_bench_serve_batching_beats_sequential(tmp_path):
-    """The acceptance bar: >= 1.5x aggregate tokens/sec at 8 concurrent
-    requests vs the sequential generate() baseline on CPU, with the
-    decode program traced exactly once per pool size (asserted inside
-    bench())."""
-    import bench_serve
-
-    result = bench_serve.bench(
-        out_path=str(tmp_path / "BENCH_SERVE.json"))
-    pts = {p["concurrency"]: p for p in result["points"]
-           if p["mode"] == "engine"}
-    assert pts[8]["speedup_vs_sequential"] >= 1.5, pts[8]
-    # continuous batching must scale from no-batching to batch-8 (strict
-    # 16>8 monotonicity is NOT asserted: a 2-core CI box saturates
-    # around batch 8 and 16-vs-8 is then noise), and the batch-16 point
-    # must still clear the same bar vs sequential
-    assert pts[8]["tokens_per_sec"] > 1.5 * pts[1]["tokens_per_sec"]
-    assert pts[16]["speedup_vs_sequential"] >= 1.5, pts[16]
-
-
-@pytest.mark.slow
 @pytest.mark.parametrize("temperature", [0.0, 0.8])
 def test_serve_smoke_spec_parity(temperature):
     """Speculative decoding under randomized threaded arrivals: n-gram
@@ -238,29 +129,6 @@ def test_serve_smoke_spec_paged_parity(temperature):
     assert stats["verify_traces"] == stats["verify_buckets"]
     assert stats["serve.requests_completed"] == 10
     assert stats["block_stats"]["used"] == 1  # every block reclaimed
-
-
-@pytest.mark.slow
-def test_bench_serve_spec_tokens_per_tick(tmp_path):
-    """The speculative-decoding acceptance row: >= 1.5x accepted-
-    tokens-per-decode-tick on the repetitive leg at zero mismatches,
-    with the proposer standing down on the non-repetitive leg (its
-    verify ticks a small fraction of decode ticks).  Wall-clock TPOT
-    deltas are archived, not asserted here — this 2-vCPU host's
-    throttle swings single timed runs (the real BENCH_SERVE.json run
-    with interleaved reps gates the <= 10% overhead bar)."""
-    import bench_serve
-
-    row = bench_serve.spec_decode(
-        reps=1, out_path=str(tmp_path / "BENCH_SERVE.json"))
-    assert row["mismatches"] == 0
-    rep = row["repetitive"]
-    assert rep["tokens_per_tick_ratio"] >= 1.5, rep
-    assert rep["compile_counts_on"]["verify"] == \
-        rep["compile_counts_on"]["verify_buckets"]
-    nonrep = row["nonrepetitive"]
-    assert nonrep["verify_ticks"] <= 0.2 * nonrep["decode_ticks_on"], \
-        nonrep
 
 
 @pytest.mark.slow

@@ -10,7 +10,7 @@ partial KV is never silently attended), ownership-transfer adoption
 on the paged pool, and the registered receive-buffer pool on the
 transport seam.
 
-Chaos (prefill killed mid-ship) and the bench A/B are slow-marked in
+Chaos (prefill killed mid-ship) is slow-marked in
 tests/test_router_chaos.py; this file is the fast tier-1 sibling.
 """
 
@@ -304,7 +304,7 @@ def test_int8_geometry_contract_and_typed_dtype_refusal(tiny):
     assert st8.stats()["staged"] == 0
 
 
-@pytest.mark.slow  # ~10s (tier-1 duration budget); test_int8_geometry_contract_and_typed_dtype_refusal keeps the int8 ship contract fast
+@pytest.mark.slow  # ~10s; test_int8_geometry_contract_and_typed_dtype_refusal keeps the int8 ship contract fast
 def test_disagg_int8_ship_parity_and_shipped_bytes(tiny, prompts):
     """End-to-end int8 disagg: shipped s8+scale blocks adopted by the
     decode replica reproduce a single int8 engine's stream exactly

@@ -239,7 +239,7 @@ def _run(model, variables, prompts, m, *, kv_dtype="int8", seed0=3,
     return [np.asarray(r.result()) for r in reqs], eng
 
 
-@pytest.mark.slow  # ~9s, >20s under load (tier-1 duration budget); kernel_matches_dequantized_gather_int8[1/2/5] keeps kernel-vs-gather parity fast
+@pytest.mark.slow  # ~9s; kernel_matches_dequantized_gather_int8[1/2/5] keeps kernel-vs-gather parity fast
 def test_engine_int8_kernel_vs_gather_parity_and_rerun(tiny, prompts):
     """The int8 acceptance anchor: fused-kernel (interpret) and
     gather-fallback engines emit IDENTICAL token streams from an int8
@@ -265,7 +265,7 @@ def test_engine_int8_kernel_vs_gather_parity_and_rerun(tiny, prompts):
     assert eng_g.pool.kv_dtype == "int8"
 
 
-@pytest.mark.slow  # ~8s (tier-1 duration budget); int8 pool sizing stays fast and test_serving_paged covers preemption fast
+@pytest.mark.slow  # ~8s; int8 pool sizing stays fast and test_serving_paged covers preemption fast
 def test_engine_int8_preempt_resume_parity(tiny):
     """Preempt/resume on quantized shared storage: under block
     pressure the victim re-prefills and must reproduce the ORIGINAL
@@ -287,7 +287,7 @@ def test_engine_int8_preempt_resume_parity(tiny):
     assert eng.pool.alloc.used_count == 1
 
 
-@pytest.mark.slow  # ~10s, >20s under load (tier-1 duration budget); test_serve_blocks COW-fork tests keep the fork semantics fast
+@pytest.mark.slow  # ~10s; test_serve_blocks COW-fork tests keep the fork semantics fast
 def test_engine_int8_cow_on_quantized_shared_blocks(tiny):
     """COW forks quantized shared blocks whole — s8 values AND scale
     rows ride in one generic fork program.  With min_prefill_bucket=16
@@ -389,7 +389,7 @@ def test_radix_store_partial_insert_and_leaf_only_eviction():
     assert alloc.used_count == 6  # only the callers' own alloc refs
 
 
-@pytest.mark.slow  # ~8s, >20s under load (tier-1 duration budget); the radix-store chain tests keep block-boundary sharing fast
+@pytest.mark.slow  # ~8s; the radix-store chain tests keep block-boundary sharing fast
 def test_engine_radix_share_without_single_entry_insert(tiny):
     """The acceptance pin: C shares a 4-block prefix assembled from TWO
     different requests' inserts (never one entry) — its admit hit
@@ -419,25 +419,3 @@ def test_engine_radix_share_without_single_entry_insert(tiny):
     np.testing.assert_array_equal(np.asarray(rC.result()), baseC[0])
     counts = eng.compile_counts()
     assert counts["prefix_copy"] == 0 and counts["prefix_extract"] == 0
-
-
-# ------------------------------------------------------- bench A/B (slow)
-
-
-@pytest.mark.slow
-def test_bench_kv_int8_capacity_tpot_and_reproducibility(tmp_path):
-    """The bench_serve --kv-int8 acceptance row: >= 1.8x peak
-    concurrent decoders at a FIXED KV byte budget, uniform-leg TPOT
-    within 1.1x of fp, and the pressured mixed leg (preempt/resume
-    live) bit-identical across two full runs."""
-    import bench_serve
-
-    row = bench_serve.kv_int8_ab(
-        out_path=str(tmp_path / "BENCH_SERVE.json"))
-    assert row["concurrency_ratio"] >= 1.8, row
-    assert row["uniform_tpot_overhead"] <= 0.10, row
-    assert row["rerun_mismatches"] == 0, row
-    # the mechanism: same bytes buy >= 1.8x more blocks
-    assert row["block_bytes_ratio"] >= 1.8, row
-    # pressure actually happened on the fp leg, not on the int8 leg
-    assert row["fp_preemptions"] > 0, row

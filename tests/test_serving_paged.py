@@ -248,7 +248,7 @@ def test_preemption_under_block_pressure_greedy(tiny):
 @pytest.mark.slow
 def test_preemption_under_block_pressure_seeded(tiny):
     """Slow sibling of the greedy preemption test above (sampling-path
-    compile; tier-1 duration budget).
+    compile).
     The preempt/resume cycle preserves the per-request sampling key
     chain: the resume prefill's sampled token and key split are
     discarded, the parked token + carried key continue the stream —

@@ -142,7 +142,7 @@ def test_chunked_prefill_greedy_parity_and_trace_counts(tiny):
     match generate() bit-for-bit; chunk-prefill traces are bounded by
     distinct chunk buckets (one here: everything pads to the 8 bucket)
     and nothing retraces on repeats.  Slow: multi-chunk prefill
-    compile + trace assertions (tier-1 duration budget);
+    compile + trace assertions;
     test_chunk_budget_bounds_tick_prefill and the prefix-reuse parity
     tests keep fast chunked-prefill coverage."""
     _, model, variables = tiny
@@ -337,8 +337,8 @@ def test_tiny_credit_budget_cannot_stall_prefix_resume(tiny):
 
 @pytest.mark.slow
 def test_shared_store_isolates_different_weights(tiny, shared_prompts):
-    """Slow: a second model init + its prefill compiles (tier-1
-    duration budget); test_prefix_cache_store_mechanics keeps the fast
+    """Slow: a second model init + its prefill compiles;
+    test_prefix_cache_store_mechanics keeps the fast
     store-keying coverage.
     Two engines serving DIFFERENT weights through one shared
     PrefixCache must never exchange K/V: the weights-fingerprint salt

@@ -115,8 +115,8 @@ def test_truncated_self_draft_exact_and_cheap():
 @pytest.mark.slow  # ~40s on CPU: trains the target model to convergence
 def test_truncated_draft_acceptance_rises_with_training():
     """The LayerSkip premise, empirically: on RANDOM weights a truncated
-    self-draft is uncorrelated with the full model (acceptance ~0, the
-    bench's honest finding), but once the model is TRAINED the early
+    self-draft is uncorrelated with the full model (acceptance ~0), but
+    once the model is TRAINED the early
     layers carry the signal and the same draft's proposals are accepted
     at a high rate.  (Output correctness is draft-independent either
     way — pinned by the other tests.)"""

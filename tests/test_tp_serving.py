@@ -203,7 +203,7 @@ def test_tp_gather_seeded_parity(tiny, prompts):
     np.testing.assert_array_equal(req.result(), base)
 
 
-@pytest.mark.slow  # ~5s (tier-1 duration budget); tp greedy parity stays fast and test_paged_attention covers prefix zero-copy fast
+@pytest.mark.slow  # ~5s; tp greedy parity stays fast and test_paged_attention covers prefix zero-copy fast
 def test_tp_prefix_hit_zero_copy_parity(tiny):
     """Prefix sharing under tp: block ids name the same token span on
     every shard, so hits stay refcount bumps (zero-copy) and chunked
@@ -232,7 +232,7 @@ def test_tp_prefix_hit_zero_copy_parity(tiny):
     assert eng.metrics.get(sm.PREFIX_HIT_TOKENS) == 16
 
 
-@pytest.mark.slow  # ~6s (tier-1 duration budget); tp gather greedy/seeded parity stays fast and test_serving_paged covers preemption fast
+@pytest.mark.slow  # ~6s; tp gather greedy/seeded parity stays fast and test_serving_paged covers preemption fast
 def test_tp_preempt_resume_parity(tiny):
     """Preemption under block pressure with tp=2: the victim re-prefills
     per-shard pools and both streams stay bit-identical to generate()."""
@@ -257,7 +257,7 @@ def test_tp_preempt_resume_parity(tiny):
     assert eng.pool.alloc.used_count == 1
 
 
-@pytest.mark.slow  # ~6s (tier-1 duration budget); test_sharded_kernel_int8_bit_identical keeps the int8 head-slice math fast
+@pytest.mark.slow  # ~6s; test_sharded_kernel_int8_bit_identical keeps the int8 head-slice math fast
 def test_tp_int8_pool_token_parity(tiny, prompts):
     """int8 per-shard pools: quantize-at-write is per-(position, head),
     so the sharded pool's bytes are an exact slice of the unsharded

@@ -98,7 +98,7 @@ def test_dp_training_loss_decreases():
     assert losses[-1] < losses[0] * 0.7, losses
 
 
-@pytest.mark.slow  # ~11s in-suite, ~31s cold ResNet compile (tier-1 duration budget); dp_step_matches_single_device + dp_training_loss_decreases keep fast dp-step coverage
+@pytest.mark.slow  # ~11s in-suite, ~31s cold ResNet compile; dp_step_matches_single_device + dp_training_loss_decreases keep fast dp-step coverage
 def test_resnet_dp_step_runs():
     """Full flax ResNet with BatchNorm state through the dp step."""
     mesh = _mesh()
