@@ -134,7 +134,7 @@ def ring_flash_attention(
     Causality: past blocks attend fully, the diagonal block runs the causal
     kernel (local positions == global on the diagonal), future blocks are
     nulled at the combine (lse_blk = -inf).  Differentiable end to end —
-    the lse cotangent of the combine flows into the flash backward kernels
+    the lse cotangent of the combine flows into the flash backward kernel
     (ops/flash_attention.py::_flash_backward).
     """
     from ..ops.flash_attention import flash_attention_with_lse

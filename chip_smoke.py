@@ -223,7 +223,7 @@ def _train_args(size: Size, batch: int) -> argparse.Namespace:
         attn="flash", fused_head=True, partition_bytes=4_096_000)
 
 
-TRAIN_KERNELS = ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv",
+TRAIN_KERNELS = ("flash_fwd", "flash_bwd_dq_flash_bwd_dkv",
                  "fused_ce_fwd", "fused_ce_bwd_dx", "fused_ce_bwd_dw")
 
 

@@ -41,6 +41,32 @@ def test_no_chip_invocation_fails_fast_and_names_the_tpu():
     assert [ln["leg"] for ln in lines] == ["device"]  # nothing ran
 
 
+def test_train_kernels_name_what_the_step_builds():
+    """The flash kernels the train leg looks for among the compiled
+    step's Mosaic calls are the ones a gradient at its shape builds."""
+    import importlib.util
+    import re
+
+    from byteps_tpu.ops.flash_attention import flash_attention
+
+    spec = importlib.util.spec_from_file_location("chip_smoke", SMOKE)
+    smoke = importlib.util.module_from_spec(spec)
+    sys.modules["chip_smoke"] = smoke   # (its dataclasses look it up)
+    try:
+        spec.loader.exec_module(smoke)
+    finally:
+        del sys.modules["chip_smoke"]
+    size = smoke.FULL
+    x = jax.ShapeDtypeStruct(
+        (size.train_batch, size.train_T, size.heads, size.d_head),
+        jnp.bfloat16)
+    jaxpr = str(jax.make_jaxpr(jax.grad(lambda q, k, v: jnp.sum(
+        flash_attention(q, k, v, True, interpret=True).astype(jnp.float32)),
+        (0, 1, 2)))(x, x, x))
+    built = set(re.findall(r"flash_(?:fwd|bwd_\w+)", jaxpr))
+    assert built == {k for k in smoke.TRAIN_KERNELS if k.startswith("flash")}
+
+
 @pytest.mark.slow
 def test_rehearsal_passes_on_cpu_without_a_verdict():
     """``--rehearse`` (~60 s: every leg at the tiny size, interpret-mode

@@ -300,6 +300,7 @@ def test_attention_window_with_key_mask():
 # that loop by it, and the gauges that say what a build visits.
 # --------------------------------------------------------------------------
 
+import re  # noqa: E402
 import sys  # noqa: E402
 
 from byteps_tpu.observability.metrics import get_registry  # noqa: E402
@@ -318,15 +319,37 @@ _SUB_TILE_CASES = [
     if causal or (window is None and extra != "alibi")]
 
 
-@pytest.mark.parametrize("block", [64, 128])
+def _take_backward(monkeypatch, backward):
+    """``fused``: what the shape picks here, the single kernel; ``split``:
+    no head's dq fits, so the dq and the dk/dv kernel."""
+    if backward == "split":
+        monkeypatch.setattr(fa, "_FUSED_BWD_DQ_BYTES", 0)
+
+
+@pytest.fixture(params=["fused", "split"])
+def backward(request, monkeypatch):
+    _take_backward(monkeypatch, request.param)
+    return request.param
+
+
+# (block, backward): every grid under the single backward kernel, the
+# two PR 26 built under the dq + dk/dv pair as well
+_GRID_CASES = [(128, "fused"), (64, "fused"), (32, "fused"),
+               (128, "split"), (64, "split")]
+
+
+@pytest.mark.parametrize("block,backward", _GRID_CASES)
 @pytest.mark.parametrize("causal,window,extra", _SUB_TILE_CASES)
 def test_flash_sub_tiles_match_reference(monkeypatch, causal, window, extra,
-                                         block):
-    """Forward AND dq, dk, dv against the plain reference with 32-wide
-    sub-tiles: block 128 = one grid block of 4 x 4 sub-tiles (the plan is
-    folded at trace time, no scratch carry), block 64 = a 2 x 2 grid of
-    2 x 2 (the plan chosen from the program ids, carry in scratch)."""
-    monkeypatch.setattr(fa, "_SUB_TILE", 32)
+                                         block, backward):
+    """Forward AND dq, dk, dv against the plain reference: block 128 = one
+    grid block of 4 x 4 32-wide sub-tiles (the plan is folded at trace
+    time, no scratch carry for dk / dv), block 64 = a 2 x 2 grid of 2 x 2
+    (the plan chosen from the program ids, carry in scratch), block 32 = a
+    4 x 4 grid of 2 x 2 16-wide ones (a row's dq adds up over four k
+    blocks)."""
+    monkeypatch.setattr(fa, "_SUB_TILE", 16 if block == 32 else 32)
+    _take_backward(monkeypatch, backward)
     B, T, H, D = 1, 128, 4, 32
     hkv = {"gqa_hkv1": 1, "gqa_hkv2": 2}.get(extra, H)
     ks = jax.random.split(jax.random.PRNGKey(26), 3)
@@ -519,31 +542,109 @@ def test_sub_tile_leaves_an_undivided_side_whole():
     assert fa._sub_tile(64, 64) == (64, 64)
 
 
+def _bwd_kernels(*shape_dtype, **kw):
+    """Names of the backward kernels a gradient of this shape traces."""
+    x = jax.ShapeDtypeStruct(*shape_dtype)
+    jaxpr = str(jax.make_jaxpr(jax.grad(lambda q, k, v: jnp.sum(
+        flash_attention(q, k, v, True, interpret=True, **kw).astype(
+            jnp.float32)), (0, 1, 2)))(x, x, x))
+    return sorted(set(re.findall(r"flash_bwd_\w+", jaxpr)))
+
+
+def test_backward_is_chosen_by_shape():
+    """One backward kernel wherever a head's dq — fp32 accumulator plus
+    the two buffers of its output block, rows padded to 128 lanes — fits
+    the budget; the dq + dk/dv pair above it.  Nothing but T, D and the
+    dtype enters."""
+    bf16, f32 = jnp.bfloat16, jnp.float32
+    assert fa._fused_bwd_fits(1024, 64, bf16)        # the gpt2-medium cells
+    assert fa._fused_bwd_fits(8192, 192, bf16)       # the joyai cell
+    budget = fa._FUSED_BWD_DQ_BYTES
+    assert budget == 32 * 2 ** 20 < fa._FUSED_BWD_VMEM_LIMIT
+    # D = 64 pads to 128 lanes, 192 to 256: 8 and 16 bytes a padded lane
+    assert fa._fused_bwd_fits(32768, 64, bf16)
+    assert fa._fused_bwd_fits(32768, 128, bf16)
+    assert not fa._fused_bwd_fits(32768 + 8, 128, bf16)
+    assert fa._fused_bwd_fits(16384, 192, bf16)
+    assert not fa._fused_bwd_fits(32768, 192, bf16)
+    assert not fa._fused_bwd_fits(131072, 128, bf16)  # a 128k context
+    # fp32 operands: 12 bytes a padded lane
+    assert fa._fused_bwd_fits(16384, 128, f32)
+    assert not fa._fused_bwd_fits(32768, 128, f32)
+
+    reg = get_registry()
+    assert _bwd_kernels((1, 1024, 2, 64), bf16) == ["flash_bwd_dq_flash_bwd_dkv"]
+    assert reg.get("flash.bwd_fused").value == 1
+    # above the budget (tracing only: nothing of this size runs here)
+    assert _bwd_kernels((1, 65536, 1, 128), bf16) == [
+        "flash_bwd_dkv", "flash_bwd_dq"]
+    assert reg.get("flash.bwd_fused").value == 0
+
+
+@pytest.mark.parametrize("causal", [False, True])
+@pytest.mark.parametrize("block", [32, 64])
+def test_flash_value_heads_of_their_own_width(monkeypatch, block, causal,
+                                              backward):
+    """q / k heads 48 wide, v / o heads 32 (latent attention's 192 / 128
+    in small): dq and dk carry D, dv carries Dv, on a 2 x 2 and a 4 x 4
+    grid."""
+    monkeypatch.setattr(fa, "_SUB_TILE", 16)
+    B, T, H, D, Dv = 2, 128, 2, 48, 32
+    ks = jax.random.split(jax.random.PRNGKey(30), 3)
+    q = jax.random.normal(ks[0], (B, T, H, D))
+    k = jax.random.normal(ks[1], (B, T, H, D))
+    v = jax.random.normal(ks[2], (B, T, H, Dv))
+
+    def flash(a, b, c):
+        return flash_attention(a, b, c, causal, None, block, block, True)
+
+    out = flash(q, k, v)
+    assert out.shape == (B, T, H, Dv)
+    np.testing.assert_allclose(np.asarray(out),
+                               np.asarray(_reference(q, k, v, causal)),
+                               rtol=2e-4, atol=2e-5)
+    gf = jax.grad(lambda *a: jnp.sum(flash(*a) ** 2), (0, 1, 2))(q, k, v)
+    gr = jax.grad(lambda *a: jnp.sum(_reference(*a, causal) ** 2),
+                  (0, 1, 2))(q, k, v)
+    for a, b in zip(gf, gr):
+        assert a.shape == b.shape
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=2e-3, atol=2e-4)
+
+
 @pytest.mark.parametrize("causal", [True, False])
-def test_flash_tiles_gauges(causal):
+def test_flash_tiles_gauges(causal, backward):
     """Each pallas_call build records what it visits, per kernel; at the
     cells' shape that is the triangle of the tuned s, and everything for
     a non-causal call.  Tracing alone records — nothing runs here."""
     reg = get_registry()
-    reg.remove_prefix("flash.tiles_")
+    reg.remove_prefix("flash.")
     x = jax.ShapeDtypeStruct((1, 1024, 2, 64), jnp.bfloat16)
     jax.eval_shape(jax.grad(lambda q, k, v: jnp.sum(flash_attention(
         q, k, v, causal, interpret=True).astype(jnp.float32)), (0, 1, 2)),
         x, x, x)
     n = 1024 // fa._SUB_TILE
     assert (n, n * (n + 1) // 2) == (4, 10)     # s = 256: 10 of 16
-    for kernel in ("fwd", "bwd_dq", "bwd_dkv"):
+    assert reg.get("flash.bwd_fused").value == (backward == "fused")
+    kernels = {"fused": ("fwd", "bwd"),
+               "split": ("fwd", "bwd_dq", "bwd_dkv")}[backward]
+    for kernel in kernels:
         visited = reg.get("flash.tiles_visited", kernel=kernel).value
         total = reg.get("flash.tiles_total", kernel=kernel).value
         assert total == n * n
         assert visited == (n * (n + 1) // 2 if causal else total)
+    for absent in {"bwd", "bwd_dq", "bwd_dkv"} - set(kernels):
+        assert reg.get("flash.tiles_visited", kernel=absent) is None
 
 
-def test_layers_share_one_pallas_call_build():
+def test_layers_share_one_pallas_call_build(backward):
     """Three layers of one configuration trace and lower each kernel once:
     the builders are cached, and the callable they return is a jit."""
-    x = jax.ShapeDtypeStruct((1, 128, 2, 32), jnp.float32)
-    builders = (fa._forward_call, fa._dq_call, fa._dkv_call)
+    builders = [fa._forward_call, fa._dkv_call]
+    if backward == "split":
+        builders.append(fa._dq_call)
+    # (a shape of its own per case: the caches outlive a test)
+    x = jax.ShapeDtypeStruct((1, 128, 2 + len(builders), 32), jnp.float32)
     before = [b.cache_info() for b in builders]
 
     def loss(q, k, v):

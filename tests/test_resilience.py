@@ -817,18 +817,31 @@ def test_profiler_record_after_close_drops_loudly():
 
 def test_flash_bwd_blocks_distinguish_explicit_choice():
     """Satellite: explicit block_q/block_k — including an explicit
-    1024x1024 equal to the old defaults — bind the backward kernels;
-    only unset (None) picks the swept bwd defaults."""
-    from byteps_tpu.ops.flash_attention import (DEFAULT_BWD_DKV_BLOCKS,
-                                                DEFAULT_BWD_DQ_BLOCKS,
-                                                _bwd_blocks)
+    1024x1024 equal to the defaults — bind the backward as they bind the
+    forward: one pair of blocks serves every kernel of a call."""
+    import jax
+    import jax.numpy as jnp
 
-    assert _bwd_blocks(None, None) == (DEFAULT_BWD_DQ_BLOCKS,
-                                       DEFAULT_BWD_DKV_BLOCKS)
-    assert _bwd_blocks(1024, 1024) == ((1024, 1024), (1024, 1024))
-    assert _bwd_blocks(128, 256) == ((128, 256), (128, 256))
-    # one side explicit: the other resolves to its fwd default
-    assert _bwd_blocks(512, None) == ((512, 1024), (512, 1024))
+    from byteps_tpu.observability.metrics import get_registry
+    from byteps_tpu.ops.flash_attention import (DEFAULT_BLOCK_K,
+                                                DEFAULT_BLOCK_Q,
+                                                _fwd_blocks, flash_attention)
+
+    assert _fwd_blocks(None, None) == (DEFAULT_BLOCK_Q, DEFAULT_BLOCK_K)
+    assert _fwd_blocks(1024, 1024) == (1024, 1024)
+    assert _fwd_blocks(128, 256) == (128, 256)
+    # one side explicit: the other resolves to its default
+    assert _fwd_blocks(512, None) == (512, DEFAULT_BLOCK_K)
+
+    # [2048 / 512, 2048 / 1024] grid blocks of 2 x 4 sub-tiles, forward
+    # and backward alike
+    x = jax.ShapeDtypeStruct((1, 2048, 1, 64), jnp.bfloat16)
+    jax.eval_shape(jax.grad(lambda q, k, v: jnp.sum(flash_attention(
+        q, k, v, False, None, 512, None, True).astype(jnp.float32)),
+        (0, 1, 2)), x, x, x)
+    reg = get_registry()
+    for kernel in ("fwd", "bwd"):
+        assert reg.get("flash.tiles_total", kernel=kernel).value == 64
 
 
 def test_flash_attention_none_defaults_still_run():

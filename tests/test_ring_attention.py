@@ -32,6 +32,22 @@ def _mesh(n):
     return Mesh(np.array(jax.devices()[:n]), ("sp",))
 
 
+def _fa():
+    import sys
+
+    # (``byteps_tpu.ops`` shadows the submodule with the function)
+    return sys.modules["byteps_tpu.ops.flash_attention"]
+
+
+@pytest.fixture(params=["fused", "split"])
+def backward(request, monkeypatch):
+    """The flash backward: the single kernel the shape picks here, or —
+    no head's dq fits — the dq and the dk/dv kernel."""
+    if request.param == "split":
+        monkeypatch.setattr(_fa(), "_FUSED_BWD_DQ_BYTES", 0)
+    return request.param
+
+
 @pytest.mark.parametrize("impl", [ring_attention, ulysses_attention])
 @pytest.mark.parametrize("causal", [False, True])
 @pytest.mark.parametrize("nshards", [2, 4])
@@ -95,9 +111,10 @@ def test_ring_flash_matches_local(causal, nshards):
                                atol=2e-5, rtol=2e-5)
 
 
-def test_ring_flash_grad_matches_local():
+def test_ring_flash_grad_matches_local(backward):
     """End-to-end differentiability of flash (x) sp — the lse cotangent
-    path through the Pallas backward kernels."""
+    path through the Pallas backward: the single kernel, and the dq +
+    dk/dv pair a local sequence too long for it takes."""
     q, k, v = _qkv(4)
     mesh = _mesh(4)
 
@@ -129,21 +146,22 @@ def test_flash_with_lse_grads():
 
 
 @pytest.mark.parametrize("causal", [False, True])
-def test_flash_with_lse_grads_across_sub_tiles(monkeypatch, causal):
+@pytest.mark.parametrize("block,dv", [(T, D), (T // 2, D), (T // 4, 2 * D)])
+def test_flash_with_lse_grads_across_sub_tiles(monkeypatch, block, dv,
+                                               causal, backward):
     """The same with a non-zero ``dlse`` through a block of 4 x 4
-    sub-tiles — ring attention's diagonal block is the causal case, its
-    off-diagonal blocks the non-causal one."""
-    import sys
-
-    fa = sys.modules["byteps_tpu.ops.flash_attention"]
-    monkeypatch.setattr(fa, "_SUB_TILE", 8)
-    _check_with_lse_grads(causal, T)
+    sub-tiles, a 2 x 2 grid of 2 x 2 and a 4 x 4 grid whose v / o heads
+    are wider than q / k — ring attention's diagonal block is the causal
+    case, its off-diagonal blocks the non-causal one."""
+    monkeypatch.setattr(_fa(), "_SUB_TILE", 8)
+    _check_with_lse_grads(causal, block, dv)
 
 
-def _check_with_lse_grads(causal, block):
+def _check_with_lse_grads(causal, block, dv=D):
     from byteps_tpu.ops.flash_attention import flash_attention_with_lse
 
     q, k, v = _qkv(5)
+    v = jax.random.normal(jax.random.PRNGKey(6), (B, T, H, dv), jnp.float32)
     scale = D ** -0.5
 
     def dense(q, k, v):
