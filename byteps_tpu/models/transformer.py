@@ -61,6 +61,16 @@ class TransformerConfig:
     # Mistral-style causal sliding window (flash impl only, no sp axis):
     # each position attends to the last `attn_window` positions
     attn_window: Optional[int] = None
+    # an attention kind per layer (window/global hybrids): one entry a
+    # layer, each axis a tuple (tuples keep the config hashable).
+    # ``attn_window_layout[i]`` is layer i's window or None (attend the
+    # whole causal prefix); ``rope_layout[i]`` says whether layer i
+    # rotates q/k (``pos_emb`` must then be "rope" or "none": there is
+    # no learned table beside a per-layer rotation).  Without a layout
+    # the scalars ``attn_window`` / ``pos_emb`` hold for every layer.
+    # Training path only: the cache paths raise on a layout.
+    attn_window_layout: Optional[tuple] = None
+    rope_layout: Optional[tuple] = None
     # architecture axes for GPT-2-family compatibility
     # (integrations/gpt2.py): pre-norm layer norm with bias, biased
     # projections, and an lm_head tied to the input embedding
@@ -103,9 +113,15 @@ class TransformerConfig:
     # fine-grained experts (parallel/moe.py): with ``moe_experts`` > 0
     # the first ``dense_layers`` blocks keep the dense MLP and every
     # later one routes each token to ``moe_top_k`` of ``moe_experts``
-    # SwiGLU experts of width ``moe_d_ff`` (sigmoid scores, weights
-    # normalised and multiplied by ``moe_scale``) beside ``moe_shared``
-    # shared experts.  ``moe_held = (first, count)`` is the contiguous
+    # gated experts of width ``moe_d_ff`` beside ``moe_shared`` shared
+    # experts.  ``moe_scoring`` is the router's rule (``sigmoid``:
+    # sigmoid scores and a balancing bias, weights normalised;
+    # ``softmax_topk``: the top k of the logits, weights their softmax,
+    # no bias), the weights multiplied by ``moe_scale``; ``moe_act`` the
+    # gate's activation (``silu`` | ``relu``); with
+    # ``moe_router_pre_attn`` the router scores the block's normalised
+    # ATTENTION input (the experts still read the feed-forward input).
+    # ``moe_held = (first, count)`` is the contiguous
     # slice of experts THIS rank holds (default: all): the router keeps
     # all its outputs, the layer computes its own experts' part.  With
     # ``moe_ep_axis`` (inside shard_map over that axis) the rank's
@@ -116,6 +132,9 @@ class TransformerConfig:
     moe_d_ff: int = 0
     moe_shared: int = 0
     moe_scale: float = 1.0
+    moe_scoring: str = "sigmoid"
+    moe_act: str = "silu"
+    moe_router_pre_attn: bool = False
     moe_held: Optional[tuple] = None
     moe_ep_axis: Optional[str] = None
     dense_layers: int = 0
@@ -147,6 +166,34 @@ class TransformerConfig:
             raise ValueError(
                 f"num_kv_heads {kv} must divide num_heads {self.num_heads}")
         return kv
+
+    @property
+    def has_attn_layout(self) -> bool:
+        return (self.attn_window_layout is not None
+                or self.rope_layout is not None)
+
+    def _layout_entry(self, layout, layer, default):
+        if layout is None or layer is None:
+            return default
+        if len(layout) != self.num_layers:
+            raise ValueError(
+                f"a per-layer layout has {len(layout)} entries, the model "
+                f"{self.num_layers} layers")
+        return layout[layer]
+
+    def layer_window(self, layer: Optional[int]) -> Optional[int]:
+        """Layer ``layer``'s attention window (None: the whole causal
+        prefix); ``attn_window`` without a layout or a layer."""
+        return self._layout_entry(self.attn_window_layout, layer,
+                                  self.attn_window)
+
+    def layer_rope(self, layer: Optional[int]) -> bool:
+        """Does layer ``layer`` rotate q/k?"""
+        if self.rope_layout is not None and self.pos_emb == "learned":
+            raise ValueError("rope_layout needs pos_emb 'rope' or 'none', "
+                             "not a learned table")
+        return bool(self._layout_entry(self.rope_layout, layer,
+                                       self.pos_emb == "rope"))
 
     def partition(self, init, spec):
         """Wrap an initializer with tp-sharding metadata — only when this
@@ -187,17 +234,17 @@ class TransformerConfig:
                 and self.sp_axis in self.mesh.axis_names
                 and self.mesh.shape[self.sp_axis] > 1)
 
-    def attention_fn(self):
+    def attention_fn(self, layer: Optional[int] = None):
         causal = self.causal
         names = set(self.mesh.axis_names) if self.mesh is not None else set()
         has_sp = self.has_sp
         if self.attn_impl == "flash" and not has_sp:
             from ..ops.flash_attention import flash_attention
 
-            window = self.attn_window
+            window = self.layer_window(layer)
             return lambda q, k, v: flash_attention(q, k, v, causal=causal,
                                                    window=window)
-        if self.attn_window is not None:
+        if self.layer_window(layer) is not None:
             raise ValueError(
                 "attn_window requires attn_impl='flash' without an active "
                 f"sp axis (got attn_impl={self.attn_impl!r})")
@@ -504,12 +551,20 @@ def _cached_attention(q, ck, cv, pos, window=None):
 
 class Attention(nn.Module):
     cfg: TransformerConfig
+    # the layer's index, read against the config's per-layer layout
+    # (window or whole prefix, RoPE or none); None: the scalars hold
+    layer: Optional[int] = None
 
     @nn.compact
     def __call__(self, x, key_mask=None, cache=None, pos=None):
         cfg = self.cfg
         H, D = cfg.num_heads, cfg.d_head
         KV = cfg.kv_heads
+        if cache is not None and cfg.has_attn_layout:
+            raise NotImplementedError(
+                "a per-layer attention layout is built for training: no "
+                "cache knows a layer's kind yet")
+        window = cfg.layer_window(self.layer)
         proj = partial(
             QuantDense, dtype=cfg.dtype, use_bias=cfg.use_bias,
             kernel_init=cfg.partition(
@@ -530,7 +585,7 @@ class Attention(nn.Module):
                               kernel_init=nn.initializers.xavier_uniform())
         k = kv_proj(features=(KV, D), name="k")(x)
         v = kv_proj(features=(KV, D), name="v")(x)
-        if cfg.pos_emb == "rope":
+        if cfg.layer_rope(self.layer):
             # rotate q/k before the cache write and before any attention
             # path (flash/local/ring all consume rotated q/k; cached K
             # is stored rotated — RoPE's relative-position property
@@ -804,9 +859,9 @@ class Attention(nn.Module):
 
                 out = flash_attention(q, k, v, cfg.causal,
                                       segment_ids=key_mask,
-                                      window=cfg.attn_window)
+                                      window=window)
             else:
-                if cfg.attn_window is not None:
+                if window is not None:
                     raise ValueError(
                         "attn_window requires attn_impl='flash' without an "
                         f"active sp axis (got attn_impl={cfg.attn_impl!r})")
@@ -815,7 +870,7 @@ class Attention(nn.Module):
                 out = local_attention(q, k, v, causal=cfg.causal,
                                       key_mask=key_mask)
         else:
-            out = cfg.attention_fn()(q, k, v)
+            out = cfg.attention_fn(self.layer)(q, k, v)
         return o_proj(out)
 
 
@@ -912,31 +967,37 @@ class _Leaves(nn.Module):
 
 class ExpertLayer(nn.Module):
     """The routed feed-forward of a block: the held experts' part
-    (``parallel/moe.py:expert_layer``) plus the shared expert.  Sows the
-    layer's two counts (``assignments_held``, ``rows_computed``) into the
-    ``moe_stats`` collection (when the caller makes it mutable)."""
+    (``parallel/moe.py:expert_layer``) plus the shared expert.
+    ``router_x`` is what the router scores where that is not ``x`` (the
+    block's normalised attention input under ``moe_router_pre_attn``).
+    Sows the layer's two counts (``assignments_held``,
+    ``rows_computed``) into the ``moe_stats`` collection (when the
+    caller makes it mutable)."""
 
     cfg: TransformerConfig
 
     @nn.compact
-    def __call__(self, x):
+    def __call__(self, x, router_x=None):
         from ..parallel.moe import expert_layer
 
         cfg = self.cfg
         d, f, E = cfg.d_model, cfg.moe_d_ff, cfg.moe_experts
         held = cfg.moe_held or (0, E)
         init = nn.initializers.normal(stddev=0.02)
-        router = _Leaves((("kernel", (d, E), init),
-                          ("bias", (E,), nn.initializers.zeros)),
-                         name="router")()
+        leaves = (("kernel", (d, E), init),)
+        if cfg.moe_scoring == "sigmoid":      # the balancing bias's rule
+            leaves += (("bias", (E,), nn.initializers.zeros),)
+        router = _Leaves(leaves, name="router")()
         w = _Leaves((("gate", (held[1], d, f), init),
                      ("up", (held[1], d, f), init),
                      ("down", (held[1], f, d), init)), name="experts")()
         flat = x.reshape(-1, d)
         y, counts = expert_layer(
-            flat, router["kernel"], router["bias"], w["gate"], w["up"],
+            flat, router["kernel"], router.get("bias"), w["gate"], w["up"],
             w["down"], top_k=cfg.moe_top_k, scale=cfg.moe_scale,
-            held=held, axis_name=cfg.moe_ep_axis)
+            held=held, axis_name=cfg.moe_ep_axis,
+            router_x=None if router_x is None else router_x.reshape(-1, d),
+            scoring=cfg.moe_scoring, act=cfg.moe_act)
         for name, n in zip(("assignments_held", "rows_computed"), counts):
             self.sow("moe_stats", name, n, reduce_fn=lambda a, b: a + b,
                      init_fn=lambda: jnp.zeros((), jnp.int32))
@@ -951,12 +1012,16 @@ class ExpertLayer(nn.Module):
 class Block(nn.Module):
     cfg: TransformerConfig
     experts: bool = False     # the feed-forward is an expert layer
+    layer: Optional[int] = None   # index into a per-layer layout
 
     @nn.compact
     def __call__(self, x, key_mask=None, cache=None, pos=None):
         attention = (LatentAttention if self.cfg.attn_kind == "mla"
-                     else Attention)
+                     else partial(Attention, layer=self.layer))
         y = self.cfg.make_norm("ln1")(x)
+        # the router may score the attention input: kept for the expert
+        # layer below, which still reads the feed-forward input
+        router_x = y if self.cfg.moe_router_pre_attn else None
         if cache is not None:
             if key_mask is not None:
                 raise ValueError(
@@ -970,7 +1035,7 @@ class Block(nn.Module):
             x = x + attention(self.cfg, name="attn")(y, key_mask=key_mask)
         y = self.cfg.make_norm("ln2")(x)
         if self.experts:
-            x = x + ExpertLayer(self.cfg, name="moe")(y)
+            x = x + ExpertLayer(self.cfg, name="moe")(y, router_x)
         else:
             x = x + MLP(self.cfg, name="mlp")(y)
         return (x, new_cache) if cache is not None else x
@@ -1025,7 +1090,7 @@ class Transformer(nn.Module):
         block = cfg.block_cls()
         self.blocks = [
             block(cfg, experts=cfg.moe_experts > 0 and i >= cfg.dense_layers,
-                  name=f"block_{i}")
+                  layer=i, name=f"block_{i}")
             for i in range(cfg.num_layers)
         ]
         self.ln_f = cfg.make_norm("ln_f")
@@ -1047,8 +1112,16 @@ class Transformer(nn.Module):
     def hidden(self, tokens):
         """Everything up to (and including) the final norm:
         ``[B, T] -> [B, T, d_model]``."""
+        from ..observability.metrics import get_registry
+
+        cfg = self.cfg
+        windowed = sum(cfg.layer_window(i) is not None
+                       for i in range(cfg.num_layers))
+        reg = get_registry()        # set when the model is traced
+        reg.gauge("attn.layers", kind="window").set(windowed)
+        reg.gauge("attn.layers", kind="full").set(cfg.num_layers - windowed)
         x = self.embed(tokens)
-        if self.cfg.pos_emb == "learned":
+        if cfg.pos_emb == "learned":
             x = x + self.pos(jnp.arange(tokens.shape[1])[None, :])
         for block in self.blocks:
             x = block(x)

@@ -49,9 +49,12 @@ program ids, or unrolled tile by tile with the online softmax carried
 across k sub-tiles, was measured and lost (see the tuning notes below).
 Each ``pallas_call`` build records what it will visit in
 ``flash.tiles_visited`` / ``flash.tiles_total`` (gauges labelled
-``kernel=fwd|bwd`` — ``bwd_dq`` and ``bwd_dkv`` where the pair is built:
-sub-tiles per head over the whole grid; ``tile_visits`` is the count,
-shared with the tests).
+``kernel=fwd|bwd`` — ``bwd_dq`` and ``bwd_dkv`` where the pair is built —
+and ``window=<W>|none``, the band, so that the two attention kinds of a
+window/global hybrid keep a record each: sub-tiles per head over the
+whole grid; ``tile_visits`` is the count, shared with the tests).  A
+windowed build's kernels carry the band in their names too
+(``flash_fwd_w4096``, ``flash_bwd_dq_flash_bwd_dkv_w4096``).
 
 The ``pallas_call`` objects are built once per static configuration
 (``_forward_call`` / ``_dkv_call`` / ``_dq_call``, ``lru_cache``): a
@@ -85,7 +88,8 @@ of recomputing S and dP in both kernels, 7 products:
     blocks, q blocks): accumulates dk/dv for its k block across the
     q-block dim.
 
-Gauge ``flash.bwd_fused`` (1 / 0) says which the last build took.  Combined
+Gauge ``flash.bwd_fused`` (1 / 0, labelled ``window=`` like the tile
+gauges) says which the last build of that band took.  Combined
 with ``parallel/ring_attention.py`` (which shards T across chips and calls
 this kernel per ring block — ``attn_impl="flash"`` composes with the ``sp``
 axis) this covers both the single-chip memory story and the multi-chip
@@ -286,14 +290,30 @@ def tile_visits(T: int, bq: int, bk: int, s, causal: bool, window=None):
     return visited, nqs * nks
 
 
+def _band_label(window) -> str:
+    """The ``window=`` label of the gauges: a model with two attention
+    kinds builds each kernel once a band, and neither build may
+    overwrite the other's record."""
+    return "none" if window is None else str(window)
+
+
+def _band_name(name: str, window) -> str:
+    """A windowed build's kernel carries its band in its ``name=``
+    (``flash_fwd_w4096``), so a trace tells the kinds of one model apart
+    while ``flash_fwd`` / ``flash_bwd`` still match both."""
+    return name if window is None else f"{name}_w{window}"
+
+
 def _record_tiles(kernel: str, T, bq, bk, sub, causal, window) -> None:
     """Trace-time record of what this ``pallas_call`` build visits: tiles
     per head, summed over the grid."""
     visited, total = tile_visits(T, bq, bk, sub, causal, window)
     reg = get_registry()
-    reg.gauge("flash.tiles_visited", kernel=kernel).set(
+    band = _band_label(window)
+    reg.gauge("flash.tiles_visited", kernel=kernel, window=band).set(
         sum(visited.values()))
-    reg.gauge("flash.tiles_total", kernel=kernel).set(total * len(visited))
+    reg.gauge("flash.tiles_total", kernel=kernel, window=band).set(
+        total * len(visited))
 
 
 def _strips(d: int, n_outer: int, span, always_mask: bool):
@@ -613,7 +633,7 @@ def _forward_call(B, T, H, Hkv, D, Dv, dtype, bq, bk, sub, causal, scale,
             _fwd_kernel, nq=nq, nk=nk, plans=plans, sub=sub, causal=causal,
             scale=scale, has_seg=has_seg, has_alibi=has_alibi,
             window=window),
-        name="flash_fwd",
+        name=_band_name("flash_fwd", window),
         grid=(B * H, nq, nk),
         in_specs=in_specs,
         out_specs=[
@@ -910,7 +930,7 @@ def _dq_call(B, T, H, D, Dv, dtype, bq, bk, sub, causal, scale, interpret,
                           sub=sub, causal=causal, scale=scale,
                           has_seg=has_seg, has_alibi=has_alibi,
                           window=window),
-        name="flash_bwd_dq",
+        name=_band_name("flash_bwd_dq", window),
         grid=(B * H, nq, nk),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, bq, D), q_idx),
@@ -993,7 +1013,8 @@ def _dkv_call(B, T, H, D, Dv, q_dtype, k_dtype, v_dtype, bq, bk, sub, causal,
         # finds the backward by either substring (its rooflines) and asks
         # the compiled step for each of the two (its traffic files'
         # ``kernels``); one name once those lists say so (PERF.md §7)
-        name="flash_bwd_dq_flash_bwd_dkv" if fused else "flash_bwd_dkv",
+        name=_band_name("flash_bwd_dq_flash_bwd_dkv" if fused
+                        else "flash_bwd_dkv", window),
         grid=(B * H, nk, nq),
         in_specs=in_specs,
         out_specs=out_specs,
@@ -1043,7 +1064,8 @@ def _flash_backward(q, k, v, o, lse, do, dlse, causal, scale, block_q,
     bq, bk = _fit_block(block_q, T), _fit_block(block_k, T)
     sub = _sub_tile(bq, bk)
     fused = _fused_bwd_fits(T, D, q.dtype)
-    get_registry().gauge("flash.bwd_fused").set(int(fused))
+    get_registry().gauge(
+        "flash.bwd_fused", window=_band_label(window)).set(int(fused))
     for kernel in ("bwd",) if fused else ("bwd_dq", "bwd_dkv"):
         _record_tiles(kernel, T, bq, bk, sub, causal, window)
 

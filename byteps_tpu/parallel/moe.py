@@ -6,18 +6,26 @@ contiguous slice of the experts it holds (``held = (first, count)``),
 routes every token over ALL the experts, and computes the part of the
 result that its own experts give:
 
-  router    ``s = sigmoid(x W_g)`` in float32; the top ``k`` of ``s + b``
-            (``b`` is the balancing bias: it takes part in the choice and
-            in nothing else, and receives no gradient); weights are the
-            uncorrected ``s`` of the chosen, normalised to sum to one and
-            multiplied by ``scale``.
+  router    one of two scoring rules, in float32.  ``sigmoid``: ``s =
+            sigmoid(x W_g)``; the top ``k`` of ``s + b`` (``b`` is the
+            balancing bias: it takes part in the choice and in nothing
+            else, and receives no gradient); weights are the uncorrected
+            ``s`` of the chosen, normalised to sum to one.
+            ``softmax_topk``: the top ``k`` of the logits ``x W_g``;
+            weights are the softmax over the chosen ``k`` (equal to a
+            softmax over all, renormalised over the chosen).  Either way
+            the weights are multiplied by ``scale``.  The router may read
+            another input than the experts do (``router_x``: a model
+            that routes from the block's attention input so that experts
+            can be fetched while attention runs).
   dispatch  the assignments whose expert is held, sorted by expert, laid
             out group after group in ONE static row buffer — every group
             from a row-tile boundary, so a tile belongs to one expert —
             and the tokens gathered into it.  The assignments of experts
             held elsewhere fall in a tail group that is never laid out.
   experts   three grouped matrix products (gate, up, down) over the real
-            tiles, ``ops/grouped_matmul.py``.
+            tiles, ``ops/grouped_matmul.py``; the gate's activation is
+            SiLU or ReLU.
   combine   each token sums its held assignments' rows by their weights.
 
 **No capacity factor and no dropped assignment.**  The buffer holds the
@@ -76,19 +84,32 @@ class Plan(NamedTuple):
     count: jax.Array       # [count] assignments per held expert
 
 
-def route(x, kernel, bias, top_k: int, scale: float):
+SCORING = ("sigmoid", "softmax_topk")
+GATES = {"silu": jax.nn.silu, "relu": jax.nn.relu}
+
+
+def route(x, kernel, bias, top_k: int, scale: float,
+          scoring: str = "sigmoid"):
     """``(idx [T, k] int32, weights [T, k] float32)`` for tokens
-    ``x [T, d]``: sigmoid scores in float32 (a float32 product at full
-    precision: a bf16 pass flips choices), selection on ``score +
-    bias``, weights from the uncorrected scores."""
-    scores = jax.nn.sigmoid(jnp.dot(
-        x.astype(jnp.float32), kernel.astype(jnp.float32),
-        precision=lax.Precision.HIGHEST))
-    _, idx = lax.top_k(scores + lax.stop_gradient(
-        bias.astype(jnp.float32)), top_k)
+    ``x [T, d]``, the product in float32 at full precision (a bf16 pass
+    flips choices).  ``sigmoid``: selection on ``sigmoid score + bias``,
+    weights the uncorrected scores of the chosen over their sum.
+    ``softmax_topk``: selection on the logits (``+ bias`` where there is
+    one), weights the softmax over the chosen logits."""
+    if scoring not in SCORING:
+        raise ValueError(f"unknown scoring rule {scoring!r}: {SCORING}")
+    logits = jnp.dot(x.astype(jnp.float32), kernel.astype(jnp.float32),
+                     precision=lax.Precision.HIGHEST)
+    scores = jax.nn.sigmoid(logits) if scoring == "sigmoid" else logits
+    select = scores if bias is None else scores + lax.stop_gradient(
+        bias.astype(jnp.float32))
+    _, idx = lax.top_k(select, top_k)
     idx = checkpoint_name(idx, PLAN)
     picked = jnp.take_along_axis(scores, idx, axis=-1)
-    weights = picked / (jnp.sum(picked, axis=-1, keepdims=True) + 1e-20)
+    if scoring == "sigmoid":
+        weights = picked / (jnp.sum(picked, axis=-1, keepdims=True) + 1e-20)
+    else:
+        weights = jax.nn.softmax(picked, axis=-1)
     return idx.astype(jnp.int32), weights * scale
 
 
@@ -189,12 +210,14 @@ combine.defvjp(_combine_fwd, _combine_bwd)
 # ------------------------------------------------------------ the layer
 
 
-def experts_ffn(rows, gate, up, down, p: Plan, interpret=None):
-    """The held experts' SwiGLU over the row buffer: ``gate, up
-    [count, d, f]``, ``down [count, f, d]``."""
+def experts_ffn(rows, gate, up, down, p: Plan, interpret=None,
+                act: str = "silu"):
+    """The held experts' gated feed-forward over the row buffer —
+    ``act`` of the gate (``silu``: SwiGLU; ``relu``: ReGLU) times up,
+    then down: ``gate, up [count, d, f]``, ``down [count, f, d]``."""
     mm = functools.partial(grouped_matmul, tile_group=p.tile_group,
                            n_active=p.n_active, interpret=interpret)
-    h = jax.nn.silu(mm(rows, gate.astype(rows.dtype))) * mm(
+    h = GATES[act](mm(rows, gate.astype(rows.dtype))) * mm(
         rows, up.astype(rows.dtype))
     return mm(h, down.astype(rows.dtype))
 
@@ -213,7 +236,8 @@ def served(idx, first, count: int, p: Plan):
             jnp.sum(p.valid, dtype=jnp.int32))
 
 
-def _held_part(x, idx, weights, first, gate, up, down, tile, interpret):
+def _held_part(x, idx, weights, first, gate, up, down, tile, interpret,
+               act):
     """The held experts' part for tokens ``x [T, d]`` already routed
     (``idx, weights [T, k]``), and its two counts."""
     count = gate.shape[0]
@@ -224,7 +248,7 @@ def _held_part(x, idx, weights, first, gate, up, down, tile, interpret):
     reg.gauge("moe.rows_buffer").set(rows.shape[0])
     reg.gauge("moe.experts_held").set(count)
     with jax.named_scope("experts"):
-        out = experts_ffn(rows, gate, up, down, p, interpret)
+        out = experts_ffn(rows, gate, up, down, p, interpret, act)
     with jax.named_scope("combine"):
         y = combine(out, weights, p)
     return y, served(idx, first, count, p)
@@ -234,13 +258,16 @@ def expert_layer(x, router_kernel, router_bias, gate, up, down, *,
                  top_k: int, scale: float,
                  held: Optional[Tuple[int, int]] = None,
                  axis_name: Optional[str] = None, tile: int = ROW_TILE,
-                 interpret=None):
+                 interpret=None, router_x=None, scoring: str = "sigmoid",
+                 act: str = "silu"):
     """``(y [T, d], (chosen, served))``: the routed part of the layer
     for tokens ``x [T, d]`` — the sum over each token's chosen experts
     that THIS rank holds (``gate.shape[0]`` of them, from ``held[0]``;
     all of them by default) — and the two counts of ``served``.  The
     shared expert is the caller's (every rank computes it alike: it
-    counts once).
+    counts once).  ``router_x [T, d]`` is what the router scores where
+    that is not ``x`` (the experts always read ``x``); ``scoring`` is
+    ``route``'s rule, ``act`` the gate's activation (``GATES``).
 
     With ``axis_name`` (inside ``shard_map``): ``x`` is this rank's
     tokens, the rank holds the experts from ``axis_index * count``,
@@ -251,11 +278,16 @@ def expert_layer(x, router_kernel, router_bias, gate, up, down, *,
     if held is not None and held[1] != count:
         raise ValueError(f"held {held} names {held[1]} experts, the "
                          f"weights hold {count}")
+    # the default rule goes by omission: ``benchmark/controls.py`` swaps
+    # ``route`` for a stand-in of the five arguments it had
+    rule = {} if scoring == "sigmoid" else {"scoring": scoring}
     with jax.named_scope("router"):
-        idx, weights = route(x, router_kernel, router_bias, top_k, scale)
+        idx, weights = route(x if router_x is None else router_x,
+                             router_kernel, router_bias, top_k, scale,
+                             **rule)
     if axis_name is None:
         return _held_part(x, idx, weights, first, gate, up, down, tile,
-                          interpret)
+                          interpret, act)
     ranks = lax.psum(1, axis_name)
     first = lax.axis_index(axis_name) * count
     T, d = x.shape
@@ -271,7 +303,7 @@ def expert_layer(x, router_kernel, router_bias, gate, up, down, *,
     part, counts = _held_part(
         got.reshape(ranks * T, d), idx_all.reshape(ranks * T, top_k),
         w_all.reshape(ranks * T, top_k), first, gate, up, down, tile,
-        interpret)
+        interpret, act)
     with jax.named_scope("exchange"):
         back = lax.all_to_all(part.reshape(ranks, T, d), axis_name, 0, 0)
         y = jnp.sum(back.astype(jnp.float32), axis=0).astype(x.dtype)

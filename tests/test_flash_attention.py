@@ -574,11 +574,11 @@ def test_backward_is_chosen_by_shape():
 
     reg = get_registry()
     assert _bwd_kernels((1, 1024, 2, 64), bf16) == ["flash_bwd_dq_flash_bwd_dkv"]
-    assert reg.get("flash.bwd_fused").value == 1
+    assert reg.get("flash.bwd_fused", window="none").value == 1
     # above the budget (tracing only: nothing of this size runs here)
     assert _bwd_kernels((1, 65536, 1, 128), bf16) == [
         "flash_bwd_dkv", "flash_bwd_dq"]
-    assert reg.get("flash.bwd_fused").value == 0
+    assert reg.get("flash.bwd_fused", window="none").value == 0
 
 
 @pytest.mark.parametrize("causal", [False, True])
@@ -625,16 +625,17 @@ def test_flash_tiles_gauges(causal, backward):
         x, x, x)
     n = 1024 // fa._SUB_TILE
     assert (n, n * (n + 1) // 2) == (4, 10)     # s = 256: 10 of 16
-    assert reg.get("flash.bwd_fused").value == (backward == "fused")
+    band = {"window": "none"}
+    assert reg.get("flash.bwd_fused", **band).value == (backward == "fused")
     kernels = {"fused": ("fwd", "bwd"),
                "split": ("fwd", "bwd_dq", "bwd_dkv")}[backward]
     for kernel in kernels:
-        visited = reg.get("flash.tiles_visited", kernel=kernel).value
-        total = reg.get("flash.tiles_total", kernel=kernel).value
+        visited = reg.get("flash.tiles_visited", kernel=kernel, **band).value
+        total = reg.get("flash.tiles_total", kernel=kernel, **band).value
         assert total == n * n
         assert visited == (n * (n + 1) // 2 if causal else total)
     for absent in {"bwd", "bwd_dq", "bwd_dkv"} - set(kernels):
-        assert reg.get("flash.tiles_visited", kernel=absent) is None
+        assert reg.get("flash.tiles_visited", kernel=absent, **band) is None
 
 
 def test_layers_share_one_pallas_call_build(backward):

@@ -841,7 +841,8 @@ def test_flash_bwd_blocks_distinguish_explicit_choice():
         (0, 1, 2)), x, x, x)
     reg = get_registry()
     for kernel in ("fwd", "bwd"):
-        assert reg.get("flash.tiles_total", kernel=kernel).value == 64
+        assert reg.get("flash.tiles_total", kernel=kernel,
+                       window="none").value == 64
 
 
 def test_flash_attention_none_defaults_still_run():
