@@ -662,9 +662,12 @@ def lm_loss_fn(model, fused_head: bool = False,
     element, ``{"moe_assignments_held": n, "moe_rows_computed": r}``
     (``parallel/moe.py:served``, summed over its expert layers), which
     the step reports beside ``loss``.
-    ``block_n``/``block_v`` pass
-    through to the kernel for vocab/batch sizes its auto-fit cannot
-    divide (e.g. GPT-2's 50257).
+    ``block_n``/``block_v`` are overrides handed to the kernels as they
+    are; left ``None`` each of the three kernels reads its own blocks
+    from ``(N, d, vocab)`` and the VMEM it may use
+    (``ops/fused_cross_entropy.py:choose_blocks``).  They are no remedy
+    for a table coprime to 128 such as GPT-2's 50257: pad the table to
+    a multiple of 128 (the benchmark's gpt2 cells build 50304 rows).
 
     Padded streams: pass ``batch["labels"]`` with ``-100`` on ignored
     positions (the HF convention; ``tokens`` keep an embeddable pad id).
