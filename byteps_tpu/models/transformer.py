@@ -38,6 +38,9 @@ from ..parallel.ring_attention import (
 )
 
 
+LAYER_KINDS = ("mamba", "attn", "moe", "mlp")
+
+
 @dataclasses.dataclass(frozen=True)
 class TransformerConfig:
     vocab_size: int = 32000
@@ -95,7 +98,7 @@ class TransformerConfig:
     # explicit per-head dim (Llama-3.x checkpoints may set
     # head_dim != hidden_size / num_heads); None derives it
     head_dim: Optional[int] = None
-    mlp: str = "gelu"  # gelu | swiglu
+    mlp: str = "gelu"  # gelu | swiglu | relu2 (non-gated: down(relu(up)^2))
     # latent attention (DeepSeek-V2 report, section 2.1): queries through
     # a ``q_lora_rank`` latent, keys and values through a
     # ``kv_lora_rank`` latent, each head's key the latent's
@@ -118,7 +121,10 @@ class TransformerConfig:
     # sigmoid scores and a balancing bias, weights normalised;
     # ``softmax_topk``: the top k of the logits, weights their softmax,
     # no bias), the weights multiplied by ``moe_scale``; ``moe_act`` the
-    # gate's activation (``silu`` | ``relu``); with
+    # expert's activation — of the gate for a gated expert (``silu`` |
+    # ``relu``), of the up-projection for a non-gated one (``relu2``:
+    # ``down(relu(up x)^2)``, two matrices an expert, and the shared
+    # expert in the same form); with
     # ``moe_router_pre_attn`` the router scores the block's normalised
     # ATTENTION input (the experts still read the feed-forward input).
     # ``moe_held = (first, count)`` is the contiguous
@@ -138,6 +144,25 @@ class TransformerConfig:
     moe_held: Optional[tuple] = None
     moe_ep_axis: Optional[str] = None
     dense_layers: int = 0
+    # a KIND per layer (state-space / attention / expert hybrids): one
+    # entry a layer of ``mamba`` | ``attn`` | ``moe`` | ``mlp``.  With a
+    # kind layout every block is ONE sublayer, ``x + sublayer(norm(x))``
+    # (``SublayerBlock``); None means ``Block`` (attention then
+    # feed-forward) in every layer.  Training path only: the cache paths
+    # raise on a kind layout.
+    layer_kinds: Optional[tuple] = None
+    # the Mamba-2 mixer of a ``mamba`` layer (``Mamba2Mixer``,
+    # ``ops/ssd_scan.py``): ``ssm_heads`` heads of ``ssm_head_dim``
+    # channels, a state of ``ssm_state`` a channel, ``B`` and ``C``
+    # shared by the heads of each of ``ssm_groups`` groups, a causal
+    # depthwise convolution of ``ssm_conv`` taps, the scan in chunks of
+    # ``ssm_chunk`` positions
+    ssm_heads: int = 0
+    ssm_head_dim: int = 0
+    ssm_groups: int = 1
+    ssm_state: int = 0
+    ssm_conv: int = 4
+    ssm_chunk: int = 128
     # multi-token prediction (DeepSeek-V3 report, section 2.2): one module
     # off the final hidden state that predicts the token after next;
     # ``lm_loss_fn`` adds ``mtp_loss_weight`` times its cross-entropy
@@ -214,18 +239,33 @@ class TransformerConfig:
                           name=name)
 
     def block_cls(self):
-        """``Block``, or with ``remat`` a block recomputed in the backward
-        pass — all of it but what is small to keep and dear to make
-        again: the flash forward kernel's output and log-sum-exp (the
-        kernel would otherwise run twice) and the expert layer's choice
-        and layout (a top-k and a sort)."""
-        if not self.remat:
-            return Block
+        """``Block`` (``SublayerBlock`` under a kind layout), or with
+        ``remat`` that block recomputed in the backward pass — all of it
+        but what is small to keep and dear to make again: the flash
+        forward kernel's output and log-sum-exp (the kernel would
+        otherwise run twice), the expert layer's choice and layout (a
+        top-k and a sort) and, under a kind layout, the state-space
+        scan's output and chunk-boundary states (its sequential walk
+        would otherwise run twice)."""
         from ..ops.flash_attention import FLASH_OUT
         from ..parallel.moe import PLAN
 
-        return nn.remat(Block, policy=jax.checkpoint_policies.
-                        save_only_these_names(FLASH_OUT, PLAN))
+        cls, keep = Block, (FLASH_OUT, PLAN)
+        if self.layer_kinds is not None:
+            from ..ops.ssd_scan import SSD_OUT
+
+            cls, keep = SublayerBlock, keep + (SSD_OUT,)
+        if not self.remat:
+            return cls
+        return nn.remat(cls, policy=jax.checkpoint_policies.
+                        save_only_these_names(*keep))
+
+    def layer_kind(self, layer: int) -> str:
+        """Layer ``layer``'s kind under a kind layout."""
+        kind = self._layout_entry(self.layer_kinds, layer, None)
+        if kind not in LAYER_KINDS:
+            raise ValueError(f"unknown layer kind {kind!r}: {LAYER_KINDS}")
+        return kind
 
     @property
     def has_sp(self) -> bool:
@@ -927,6 +967,8 @@ class MLP(nn.Module):
 
     @nn.compact
     def __call__(self, x):
+        from ..parallel.moe import UNGATED
+
         cfg = self.cfg
         col = partial(
             QuantDense, features=cfg.d_ff, dtype=cfg.dtype,
@@ -942,6 +984,8 @@ class MLP(nn.Module):
             h = nn.silu(col(name="gate")(x)) * col(name="up")(x)
         elif cfg.mlp == "gelu":
             h = nn.gelu(col(name="up")(x))
+        elif cfg.mlp in UNGATED:
+            h = UNGATED[cfg.mlp](col(name="up")(x))
         else:
             raise ValueError(f"unknown mlp {cfg.mlp!r}")
         return QuantDense(
@@ -970,6 +1014,8 @@ class ExpertLayer(nn.Module):
     (``parallel/moe.py:expert_layer``) plus the shared expert.
     ``router_x`` is what the router scores where that is not ``x`` (the
     block's normalised attention input under ``moe_router_pre_attn``).
+    A non-gated expert (``moe_act`` of ``parallel/moe.py:UNGATED``) has
+    no ``gate`` leaf, and the shared expert takes the same form.
     Sows the layer's two counts (``assignments_held``,
     ``rows_computed``) into the ``moe_stats`` collection (when the
     caller makes it mutable)."""
@@ -978,9 +1024,10 @@ class ExpertLayer(nn.Module):
 
     @nn.compact
     def __call__(self, x, router_x=None):
-        from ..parallel.moe import expert_layer
+        from ..parallel.moe import UNGATED, expert_layer
 
         cfg = self.cfg
+        gated = cfg.moe_act not in UNGATED
         d, f, E = cfg.d_model, cfg.moe_d_ff, cfg.moe_experts
         held = cfg.moe_held or (0, E)
         init = nn.initializers.normal(stddev=0.02)
@@ -988,13 +1035,14 @@ class ExpertLayer(nn.Module):
         if cfg.moe_scoring == "sigmoid":      # the balancing bias's rule
             leaves += (("bias", (E,), nn.initializers.zeros),)
         router = _Leaves(leaves, name="router")()
-        w = _Leaves((("gate", (held[1], d, f), init),
-                     ("up", (held[1], d, f), init),
-                     ("down", (held[1], f, d), init)), name="experts")()
+        w = _Leaves(
+            ((("gate", (held[1], d, f), init),) if gated else ())
+            + (("up", (held[1], d, f), init),
+               ("down", (held[1], f, d), init)), name="experts")()
         flat = x.reshape(-1, d)
         y, counts = expert_layer(
-            flat, router["kernel"], router.get("bias"), w["gate"], w["up"],
-            w["down"], top_k=cfg.moe_top_k, scale=cfg.moe_scale,
+            flat, router["kernel"], router.get("bias"), w.get("gate"),
+            w["up"], w["down"], top_k=cfg.moe_top_k, scale=cfg.moe_scale,
             held=held, axis_name=cfg.moe_ep_axis,
             router_x=None if router_x is None else router_x.reshape(-1, d),
             scoring=cfg.moe_scoring, act=cfg.moe_act)
@@ -1004,7 +1052,8 @@ class ExpertLayer(nn.Module):
         y = y.reshape(x.shape)
         if cfg.moe_shared:
             shared = dataclasses.replace(
-                cfg, mlp="swiglu", d_ff=f * cfg.moe_shared)
+                cfg, mlp="swiglu" if gated else cfg.moe_act,
+                d_ff=f * cfg.moe_shared)
             y = y + MLP(shared, name="shared")(x)
         return y
 
@@ -1039,6 +1088,115 @@ class Block(nn.Module):
         else:
             x = x + MLP(self.cfg, name="mlp")(y)
         return (x, new_cache) if cache is not None else x
+
+
+def causal_depthwise_conv(u, w):
+    """``conv(u)[t, c] = sum_k w[k, c] u[t - (K - 1) + k, c]`` for ``u
+    [B, T, C]`` and ``w [K, C]``, zeros before the start: position ``t``
+    sees ``t`` and the ``K - 1`` before it, nothing after."""
+    K, T = w.shape[0], u.shape[1]
+    padded = jnp.pad(u, ((0, 0), (K - 1, 0), (0, 0)))
+    return sum(padded[:, k:k + T] * w[k] for k in range(K))
+
+
+def gated_group_norm(y, z, scale, groups: int, eps: float):
+    """Mamba-2's gated norm, the gate FIRST: ``y * silu(z)``, then an RMS
+    norm over each of ``groups`` equal runs of channels, times
+    ``scale``; statistics in float32."""
+    dtype = y.dtype
+    g = (y * nn.silu(z)).astype(jnp.float32)
+    g = g.reshape(g.shape[:-1] + (groups, -1))
+    g = g * jax.lax.rsqrt(jnp.mean(jnp.square(g), axis=-1, keepdims=True)
+                          + eps)
+    return (g.reshape(y.shape) * scale).astype(dtype)
+
+
+class Mamba2Mixer(nn.Module):
+    """The Mamba-2 mixer (Dao & Gu, arXiv:2405.21060), training path:
+
+        [z | xBC | dt] = x W_in            widths d_in | d_in + 2 G N | H
+        xBC = silu(conv(xBC) + b)          causal, depthwise, K taps
+        [X | B | C] = xBC                  X [T, H, P]; B, C [T, G, N]
+        dt = softplus(dt + dt_bias);  A = -exp(A_log)
+        Y = ssd_scan(X, dt, A, B, C, D)    ops/ssd_scan.py
+        out = norm(Y * silu(z)) W_out      RMS over each group's channels
+
+    ``d_in = H P``; head ``h`` reads group ``h // (H / G)``.  ``dt``, the
+    decay and the carried state are float32 whatever the compute dtype.
+    Scopes under the module: ``in_proj``, ``conv``, ``ssd``, ``norm``,
+    ``out_proj``."""
+
+    cfg: TransformerConfig
+
+    @nn.compact
+    def __call__(self, x):
+        from ..ops.ssd_scan import ssd_scan
+
+        cfg = self.cfg
+        H, P, G, N = (cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_groups,
+                      cfg.ssm_state)
+        d_in, gn = H * P, G * N
+        dense = partial(QuantDense, dtype=cfg.dtype,
+                        kernel_init=nn.initializers.normal(stddev=0.02))
+        z, xbc, dt = jnp.split(
+            dense(features=2 * d_in + 2 * gn + H, name="in_proj")(x),
+            (d_in, 2 * d_in + 2 * gn), axis=-1)
+        conv = _Leaves((
+            ("kernel", (cfg.ssm_conv, d_in + 2 * gn),
+             nn.initializers.normal(stddev=0.02)),
+            ("bias", (d_in + 2 * gn,), nn.initializers.zeros)),
+            name="conv")()
+        with jax.named_scope("conv"):
+            xbc = nn.silu(causal_depthwise_conv(
+                xbc, conv["kernel"].astype(cfg.dtype))
+                + conv["bias"].astype(cfg.dtype))
+        ssd = _Leaves((
+            ("A_log", (H,), lambda key, shape: jnp.log(
+                jnp.arange(1, shape[0] + 1, dtype=jnp.float32))),
+            ("dt_bias", (H,), nn.initializers.zeros),
+            ("D", (H,), nn.initializers.ones)), name="ssd")()
+        lead = x.shape[:-1]
+        with jax.named_scope("ssd"):
+            y = ssd_scan(
+                xbc[..., :d_in].reshape(lead + (H, P)),
+                jax.nn.softplus(dt.astype(jnp.float32) + ssd["dt_bias"]),
+                -jnp.exp(ssd["A_log"].astype(jnp.float32)),
+                xbc[..., d_in:d_in + gn].reshape(lead + (G, N)),
+                xbc[..., d_in + gn:].reshape(lead + (G, N)),
+                ssd["D"], chunk=cfg.ssm_chunk)
+        scale = _Leaves((("scale", (d_in,), nn.initializers.ones),),
+                        name="norm")()["scale"]
+        with jax.named_scope("norm"):
+            y = gated_group_norm(y.reshape(lead + (d_in,)), z, scale, G,
+                                 cfg.norm_eps)
+        return dense(features=cfg.d_model, name="out_proj")(y)
+
+
+class SublayerBlock(nn.Module):
+    """A block of ONE sublayer, ``x + sublayer(norm(x))``, its kind read
+    from the config's kind layout: ``mamba`` (``Mamba2Mixer``), ``attn``
+    (``Attention``), ``moe`` (``ExpertLayer``) or ``mlp``.  Training
+    path only: no cache knows a recurrent state yet."""
+
+    cfg: TransformerConfig
+    layer: int = 0
+
+    @nn.compact
+    def __call__(self, x, key_mask=None, cache=None, pos=None):
+        if cache is not None:
+            raise NotImplementedError(
+                "a per-layer kind layout is built for training: no cache "
+                "holds a recurrent state or knows a layer's kind yet")
+        cfg = self.cfg
+        kind = cfg.layer_kind(self.layer)
+        y = cfg.make_norm("norm")(x)
+        if kind == "mamba":
+            return x + Mamba2Mixer(cfg, name="mamba")(y)
+        if kind == "attn":
+            return x + Attention(cfg, name="attn")(y, key_mask=key_mask)
+        if kind == "moe":
+            return x + ExpertLayer(cfg, name="moe")(y)
+        return x + MLP(cfg, name="mlp")(y)
 
 
 class MTPModule(nn.Module):
@@ -1088,11 +1246,21 @@ class Transformer(nn.Module):
         elif cfg.pos_emb not in ("rope", "none"):
             raise ValueError(f"unknown pos_emb {cfg.pos_emb!r}")
         block = cfg.block_cls()
-        self.blocks = [
-            block(cfg, experts=cfg.moe_experts > 0 and i >= cfg.dense_layers,
-                  layer=i, name=f"block_{i}")
-            for i in range(cfg.num_layers)
-        ]
+        if cfg.layer_kinds is not None:
+            if cfg.mtp_layers or cfg.has_attn_layout:
+                raise ValueError(
+                    "a kind layout is built without a multi-token-"
+                    "prediction module and without a per-layer attention "
+                    "layout")
+            self.blocks = [block(cfg, layer=i, name=f"block_{i}")
+                           for i in range(cfg.num_layers)]
+        else:
+            self.blocks = [
+                block(cfg,
+                      experts=cfg.moe_experts > 0 and i >= cfg.dense_layers,
+                      layer=i, name=f"block_{i}")
+                for i in range(cfg.num_layers)
+            ]
         self.ln_f = cfg.make_norm("ln_f")
         if cfg.mtp_layers > 1:
             raise ValueError("one multi-token-prediction depth is built; "
@@ -1115,11 +1283,17 @@ class Transformer(nn.Module):
         from ..observability.metrics import get_registry
 
         cfg = self.cfg
-        windowed = sum(cfg.layer_window(i) is not None
-                       for i in range(cfg.num_layers))
         reg = get_registry()        # set when the model is traced
+        attn_layers = range(cfg.num_layers)
+        if cfg.layer_kinds is not None:
+            kinds = [cfg.layer_kind(i) for i in attn_layers]
+            for kind in sorted(set(kinds)):
+                reg.gauge("model.layers", kind=kind).set(kinds.count(kind))
+            attn_layers = [i for i in attn_layers if kinds[i] == "attn"]
+        windowed = sum(cfg.layer_window(i) is not None for i in attn_layers)
         reg.gauge("attn.layers", kind="window").set(windowed)
-        reg.gauge("attn.layers", kind="full").set(cfg.num_layers - windowed)
+        reg.gauge("attn.layers", kind="full").set(
+            len(attn_layers) - windowed)
         x = self.embed(tokens)
         if cfg.pos_emb == "learned":
             x = x + self.pos(jnp.arange(tokens.shape[1])[None, :])

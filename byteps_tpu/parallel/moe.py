@@ -23,9 +23,10 @@ result that its own experts give:
             from a row-tile boundary, so a tile belongs to one expert —
             and the tokens gathered into it.  The assignments of experts
             held elsewhere fall in a tail group that is never laid out.
-  experts   three grouped matrix products (gate, up, down) over the real
-            tiles, ``ops/grouped_matmul.py``; the gate's activation is
-            SiLU or ReLU.
+  experts   grouped matrix products over the real tiles,
+            ``ops/grouped_matmul.py``: three for a gated expert (gate,
+            up, down; the gate's activation is SiLU or ReLU), two for a
+            non-gated one (``down(relu(up x)^2)``: no gate leaf).
   combine   each token sums its held assignments' rows by their weights.
 
 **No capacity factor and no dropped assignment.**  The buffer holds the
@@ -84,8 +85,13 @@ class Plan(NamedTuple):
     count: jax.Array       # [count] assignments per held expert
 
 
+def relu2(h):
+    return jnp.square(jax.nn.relu(h))
+
+
 SCORING = ("sigmoid", "softmax_topk")
-GATES = {"silu": jax.nn.silu, "relu": jax.nn.relu}
+GATES = {"silu": jax.nn.silu, "relu": jax.nn.relu}   # act(gate x) * up x
+UNGATED = {"relu2": relu2}                           # act(up x): no gate
 
 
 def route(x, kernel, bias, top_k: int, scale: float,
@@ -212,13 +218,18 @@ combine.defvjp(_combine_fwd, _combine_bwd)
 
 def experts_ffn(rows, gate, up, down, p: Plan, interpret=None,
                 act: str = "silu"):
-    """The held experts' gated feed-forward over the row buffer —
-    ``act`` of the gate (``silu``: SwiGLU; ``relu``: ReGLU) times up,
-    then down: ``gate, up [count, d, f]``, ``down [count, f, d]``."""
+    """The held experts' feed-forward over the row buffer: ``up
+    [count, d, f]``, ``down [count, f, d]``.  Gated (``gate [count, d,
+    f]``): ``act`` of the gate (``GATES``: ``silu`` SwiGLU, ``relu``
+    ReGLU) times up, then down — three products.  Non-gated (``gate``
+    None): ``act`` of up (``UNGATED``: ``relu2``), then down — two."""
     mm = functools.partial(grouped_matmul, tile_group=p.tile_group,
                            n_active=p.n_active, interpret=interpret)
-    h = GATES[act](mm(rows, gate.astype(rows.dtype))) * mm(
-        rows, up.astype(rows.dtype))
+    if gate is None:
+        h = UNGATED[act](mm(rows, up.astype(rows.dtype)))
+    else:
+        h = GATES[act](mm(rows, gate.astype(rows.dtype))) * mm(
+            rows, up.astype(rows.dtype))
     return mm(h, down.astype(rows.dtype))
 
 
@@ -240,7 +251,7 @@ def _held_part(x, idx, weights, first, gate, up, down, tile, interpret,
                act):
     """The held experts' part for tokens ``x [T, d]`` already routed
     (``idx, weights [T, k]``), and its two counts."""
-    count = gate.shape[0]
+    count = up.shape[0]
     with jax.named_scope("dispatch"):
         p = plan(idx, first, count, tile)
         rows = dispatch(x, p)
@@ -262,18 +273,19 @@ def expert_layer(x, router_kernel, router_bias, gate, up, down, *,
                  act: str = "silu"):
     """``(y [T, d], (chosen, served))``: the routed part of the layer
     for tokens ``x [T, d]`` — the sum over each token's chosen experts
-    that THIS rank holds (``gate.shape[0]`` of them, from ``held[0]``;
+    that THIS rank holds (``up.shape[0]`` of them, from ``held[0]``;
     all of them by default) — and the two counts of ``served``.  The
     shared expert is the caller's (every rank computes it alike: it
     counts once).  ``router_x [T, d]`` is what the router scores where
     that is not ``x`` (the experts always read ``x``); ``scoring`` is
-    ``route``'s rule, ``act`` the gate's activation (``GATES``).
+    ``route``'s rule; ``act`` with ``gate`` is ``experts_ffn``'s form
+    (``gate`` None: a non-gated expert, ``act`` of ``UNGATED``).
 
     With ``axis_name`` (inside ``shard_map``): ``x`` is this rank's
     tokens, the rank holds the experts from ``axis_index * count``,
     ``y`` is complete — every rank's part, summed — and the counts are
     the group's."""
-    count = gate.shape[0]
+    count = up.shape[0]
     first = 0 if held is None else held[0]
     if held is not None and held[1] != count:
         raise ValueError(f"held {held} names {held[1]} experts, the "

@@ -192,8 +192,13 @@ def test_staggered_arrivals_and_compile_stability(tiny, prompts,
                                                   greedy_base, greedy_eng):
     """Requests admitted mid-flight (others already decoding) still match
     their sequential baselines — batch composition cannot leak — and the
-    decode program never retraces after warmup."""
+    decode program never retraces after warmup.  The test warms the
+    module-scoped engine itself: under ``--dist load`` no earlier test of
+    this file need have run on this worker."""
     eng = greedy_eng
+    warm = eng.submit(prompts[0], M)
+    eng.drain(timeout=120)
+    np.testing.assert_array_equal(warm.result(), greedy_base[0])
     counts = eng.compile_counts()
     assert counts["decode"] == 1, counts
     r0 = eng.submit(prompts[0], M)

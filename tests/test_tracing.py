@@ -73,7 +73,13 @@ def test_debug_sample_tensor_logs(monkeypatch):
 
     import byteps_tpu as bps
 
+    from byteps_tpu.common.config import reset_config
+
     bps.shutdown()  # drop engine + config so the env var is re-read
+    # shutdown() of an engine that is not up returns before it drops the
+    # config: one cached by an earlier test of this worker (any
+    # get_config() after a shutdown) would hide the variable
+    reset_config()
     monkeypatch.setenv("BYTEPS_DEBUG_SAMPLE_TENSOR", "dbg_probe")
     bps.init()
     # the byteps_tpu logger doesn't propagate and caches its level from
@@ -106,4 +112,5 @@ def test_debug_sample_tensor_logs(monkeypatch):
         # the restored engine for the rest of the session
         monkeypatch.delenv("BYTEPS_DEBUG_SAMPLE_TENSOR", raising=False)
         bps.shutdown()
+        reset_config()
         bps.init()  # restore a clean engine for subsequent tests
