@@ -18,10 +18,12 @@ index maps can read them:
 
 A step past ``n_active`` does nothing and moves nothing: its index maps
 clamp to the last active tile, and an unchanged block index performs no
-copy.  **Rows of inactive tiles are left unwritten** — the caller masks
-them (``parallel/moe.py`` does, at the gather and before the combine).
+copy.  **Rows of inactive tiles are left unwritten** — the caller never
+reads them (``parallel/moe.py`` gathers real rows only, and masks the
+buffer where it reduces over all of its rows).
 Every group must own at least one tile, or its ``dw`` block is never
-written; the layout gives an empty group one tile of zero rows.
+written; the layout gives an empty group one tile whose rows hold no
+assignment (their ``dy`` is zero).
 
 No capacity, no drop: the buffer is sized for the worst routing and the
 cost follows the tiles that are real.
@@ -41,13 +43,21 @@ from ._pallas_utils import resolve_interpret
 
 # One [K, N] matrix of a group (double-buffered) beside a row tile and
 # its result: 2048 x 768 bf16 is 3 MB a buffer, f32 accumulation of the
-# same block 6 MB — over the 16 MiB default of the scoped limit.
-_VMEM_LIMIT = 64 * 1024 * 1024
+# same block 6 MB — over the 16 MiB default of the scoped limit, so each
+# call asks for what its blocks count to, a quarter over, up to 64 MiB.
+# Not simply 64: the limit is VMEM the compiler sets ASIDE around the
+# call, and the gather that feeds it (``parallel/moe.py``) keeps its
+# whole ``[T, d]`` source in VMEM only if both fit the chip's 128 MiB —
+# 84 MB at 16 384 x 2560 bf16 beside 64 MiB do not, and the gather then
+# reads HBM row by row: 4.7 ms where 0.8 (v5e, one layer, PR 35).
+_VMEM_FLOOR, _VMEM_LIMIT = 16 * 1024 * 1024, 64 * 1024 * 1024
 
 
-def _params():
-    return pltpu.CompilerParams(dimension_semantics=("arbitrary",),
-                                vmem_limit_bytes=_VMEM_LIMIT)
+def _params(counted: int):
+    return pltpu.CompilerParams(
+        dimension_semantics=("arbitrary",),
+        vmem_limit_bytes=min(_VMEM_LIMIT, max(_VMEM_FLOOR,
+                                              counted + counted // 4)))
 
 
 def _row(i, tile_group, n_active):
@@ -86,7 +96,10 @@ def _gmm_call(R, K, N, tm, dtype, transpose_w, interpret):
                                    _group)],
             out_specs=pl.BlockSpec((tm, N), _row)),
         out_shape=jax.ShapeDtypeStruct((R, N), dtype),
-        compiler_params=_params(),
+        # both operands' blocks and the result's, double-buffered, and
+        # the float32 product before its cast
+        compiler_params=_params(2 * (tm * K + K * N + tm * N)
+                                * jnp.dtype(dtype).itemsize + 4 * tm * N),
         interpret=interpret,
     )
 
@@ -130,7 +143,10 @@ def _dw_call(R, K, N, G, tm, dtype, interpret):
             out_specs=pl.BlockSpec((1, K, N), _group),
             scratch_shapes=[pltpu.VMEM((K, N), jnp.float32)]),
         out_shape=jax.ShapeDtypeStruct((G, K, N), dtype),
-        compiler_params=_params(),
+        # as above, and two float32 [K, N]: the accumulator and the
+        # tile's product on its way into it
+        compiler_params=_params(2 * (tm * K + tm * N + K * N)
+                                * jnp.dtype(dtype).itemsize + 8 * K * N),
         interpret=interpret,
     )
 

@@ -34,7 +34,12 @@ worst routing — ``tokens x min(k, count)`` rows plus a tile of slack a
 group — and the products' cost follows the tiles that are real.  The
 dispatch and the combine are gathers in both directions (an assignment
 knows its row and a row its assignment), each with the other as its
-backward pass; nothing scatters.
+backward pass; nothing scatters.  Each of the four is ONE pass over the
+rows it writes: a row that holds no assignment repeats token 0 (no
+mask follows the gather: its gradient is zero, so nothing reads it),
+and a token's ``k`` rows are gathered ``[k, T, d]`` — laid ``[T, k, d]``
+the chip pads ``k`` to a tile's 8 or 16 sublanes, a copy of two to
+three times the real bytes at ``k = 6``.
 
 With ``axis_name`` the layer is expert parallel across that mesh axis:
 a rank routes ITS OWN tokens once, sends each to the ranks that hold
@@ -48,8 +53,10 @@ zero and lays out no row.  On one rank there is no exchange and nothing
 stands in for it.
 
 Counters (``observability.metrics`` registry, beside ``flash.tiles_*``):
-gauges ``moe.rows_buffer`` and ``moe.experts_held`` are set when a layer
-is traced; counters ``moe.assignments_held`` and ``moe.rows_computed``
+gauges ``moe.rows_buffer``, ``moe.experts_held`` and ``moe.gathered_mb``
+(label ``op`` = ``dispatch``, ``combine``, ``dispatch_bwd``,
+``combine_bwd``: the bytes that gather writes a call) are set when a
+layer is traced; counters ``moe.assignments_held`` and ``moe.rows_computed``
 grow by a train step's metrics of those names, step by step
 (``training/step.py``): the first is what the router chose on held
 experts, the second the rows of the buffer that hold a real
@@ -79,6 +86,7 @@ class Plan(NamedTuple):
     dest: jax.Array        # [T, k] row of the assignment (0 if not held)
     held: jax.Array        # [T, k] bool: its expert is held here
     src: jax.Array         # [R] assignment (t * k + j) of the row
+    tok: jax.Array         # [R] token t of the row (0 if not valid)
     valid: jax.Array       # [R] bool: the row is a real assignment
     tile_group: jax.Array  # [R // tile] expert (local) of each row tile
     n_active: jax.Array    # [] tiles that hold real rows
@@ -159,18 +167,46 @@ def plan(idx, first, count: int, tile: int = ROW_TILE) -> Plan:
     # not choose and sort again in the backward pass
     return Plan(*(checkpoint_name(a, PLAN) for a in (
         dest.reshape(T, k).astype(jnp.int32), held.reshape(T, k),
-        src.astype(jnp.int32), valid, tile_group.astype(jnp.int32),
+        src.astype(jnp.int32), (src // k).astype(jnp.int32), valid,
+        tile_group.astype(jnp.int32),
         tile_end[-1].astype(jnp.int32), n)))
 
 
 # ------------------------------------------------ dispatch and combine
 
 
+def _gather(op: str, source, index):
+    """``source[index]``: the one way rows move.  What it writes a call
+    is the gauge ``moe.gathered_mb{op}``, set as the layer is traced."""
+    out = source[index]
+    get_registry().gauge("moe.gathered_mb", op=op).set(
+        out.size * out.dtype.itemsize / 1e6)
+    return out
+
+
+def _sum_held(op: str, rows, p: Plan, weights=None):
+    """``[T, d]`` float32: each token's held rows (by ``weights [T, k]``
+    where given) summed in the order ``j = 0 ... k - 1``, from ONE gather
+    laid ``[k, T, d]``: the fold from ``[k * T, d]`` is free and the sum
+    is ``k`` multiply-adds over ``[T, d]``, each on its own slice (over
+    the whole array the float32 products would be written out first)."""
+    picked, held = _gather(op, rows, p.dest.T), p.held.T
+
+    def term(j):
+        t = jnp.where(held[j][:, None], picked[j], 0).astype(jnp.float32)
+        return t if weights is None else t * weights[:, j][:, None]
+
+    total = term(0)
+    for j in range(1, held.shape[0]):
+        total = total + term(j)
+    return total
+
+
 @jax.custom_vjp
 def dispatch(x, p: Plan):
-    """``rows [R, d]``: each real row its token, the others zero."""
-    k = p.dest.shape[1]
-    return jnp.where(p.valid[:, None], x[p.src // k], 0).astype(x.dtype)
+    """``rows [R, d]``: each real row its token (the others token 0:
+    ``_combine_bwd`` sends them a zero gradient)."""
+    return _gather("dispatch", x, p.tok)
 
 
 def _dispatch_fwd(x, p):
@@ -178,9 +214,7 @@ def _dispatch_fwd(x, p):
 
 
 def _dispatch_bwd(p, drows):
-    picked = jnp.where(p.held[..., None], drows[p.dest], 0)
-    return jnp.sum(picked.astype(jnp.float32), axis=1).astype(
-        drows.dtype), None
+    return _sum_held("dispatch_bwd", drows, p).astype(drows.dtype), None
 
 
 dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
@@ -189,9 +223,7 @@ dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
 @jax.custom_vjp
 def combine(rows, weights, p: Plan):
     """``y [T, d]``: each token's held rows summed by their weights."""
-    picked = jnp.where(p.held[..., None], rows[p.dest], 0)
-    return jnp.einsum("tkd,tk->td", picked.astype(jnp.float32),
-                      weights).astype(rows.dtype)
+    return _sum_held("combine", rows, p, weights).astype(rows.dtype)
 
 
 def _combine_fwd(rows, weights, p):
@@ -200,8 +232,10 @@ def _combine_fwd(rows, weights, p):
 
 def _combine_bwd(res, dy):
     rows, weights, p = res
-    k = p.dest.shape[1]
-    g = dy[p.src // k].astype(jnp.float32)               # [R, d]
+    # in dy's dtype until the products that read it, and exactly zero on
+    # the rows that hold no assignment: whatever those rows of the
+    # buffer hold meets a zero in every weight gradient
+    g = _gather("combine_bwd", dy, p.tok)                 # [R, d]
     w_row = jnp.where(p.valid, weights.reshape(-1)[p.src], 0.0)
     drows = (w_row[:, None] * g).astype(rows.dtype)
     dw_row = jnp.sum(jnp.where(p.valid[:, None],

@@ -379,10 +379,175 @@ def test_layer_counters_are_in_the_registry():
     x = jax.random.normal(jax.random.PRNGKey(18), (40, D))
     _, (n, served) = jax.jit(
         lambda x: program_layer(x, w, held=(4, 8), tile=8))(x)
-    gauges = get_registry().snapshot()["gauges"]
+    reg = get_registry()
+    gauges = reg.snapshot()["gauges"]
+    rows = moe.buffer_rows(40, K, 8, 8)
     assert gauges["moe.experts_held"] == 8
-    assert gauges["moe.rows_buffer"] == moe.buffer_rows(40, K, 8, 8)
+    assert gauges["moe.rows_buffer"] == rows
     assert 0 < int(n) == int(served) < 40 * K
+    # what each of the four gathers writes a call, from its shapes and
+    # dtypes as traced (nothing runs): the buffer's rows for the two
+    # that fill it, k rows a token for the two that read it back
+    jax.eval_shape(jax.grad(lambda x: program_layer(
+        x.astype(jnp.bfloat16), w, held=(4, 8), tile=8)[0].sum().astype(
+            jnp.float32)), x)
+    for op, n_rows in (("dispatch", rows), ("combine", 40 * K),
+                       ("dispatch_bwd", 40 * K), ("combine_bwd", rows)):
+        assert reg.get("moe.gathered_mb", op=op).value == pytest.approx(
+            n_rows * D * 2 / 1e6), op
+
+
+# ------------------------ the four gathers, by layout, routing and form
+
+FORMS = {   # (k, held count, act, scoring): the three cells' layers
+    "relu-gated-k6-of-16": (6, 16, "relu", "softmax_topk"),
+    "silu-gated-k8-of-16": (8, 16, "silu", "sigmoid"),
+    "relu2-ungated-k6-of-8": (6, 8, "relu2", "sigmoid"),
+}
+ALL, FIRST, TOKENS = 32, 8, 40      # experts routed over; first held
+
+
+def form_weights(count, act):
+    k = jax.random.split(jax.random.PRNGKey(20), 4)
+    e = {"up": 0.3 * jax.random.normal(k[1], (count, D, F)),
+         "down": 0.3 * jax.random.normal(k[2], (count, F, D))}
+    if act not in moe.UNGATED:
+        e["gate"] = 0.3 * jax.random.normal(k[3], (count, D, F))
+    return {"kernel": jax.random.normal(k[0], (D, ALL)), "experts": e}
+
+
+def routing_case(routing, count):
+    """``(x, router bias, router kernel's row 0)`` that steer the choice:
+    the second held expert never chosen; or the first eight tokens on
+    experts held elsewhere only; or every token on the third held
+    expert."""
+    x = jax.random.normal(jax.random.PRNGKey(21), (TOKENS, D))
+    bias, row0 = jnp.zeros((ALL,)), None
+    if routing == "empty_expert":
+        bias = bias.at[FIRST + 1].set(-1e3)
+    elif routing == "one_expert":
+        bias = bias.at[FIRST + 2].set(1e3)
+    else:
+        x = x.at[:8, 0].set(20.0)
+        here = (jnp.arange(ALL) >= FIRST) & (jnp.arange(ALL) < FIRST + count)
+        row0 = jnp.where(here, -3.0, 3.0)
+    return x, bias, row0
+
+
+def per_token_reference(x, w, bias, k, count, act, scoring):
+    """Every held expert over every token, no buffer: a token's result
+    is the sum over its chosen held experts, each by its weight."""
+    idx, weights = moe.route(x, w["kernel"], bias, k, 2.5, scoring)
+    e, y = w["experts"], 0.0
+    for i in range(count):
+        h = x @ e["up"][i]
+        h = (moe.UNGATED[act](h) if "gate" not in e
+             else moe.GATES[act](x @ e["gate"][i]) * h)
+        coef = jnp.sum(jnp.where(idx == FIRST + i, weights, 0.0), axis=-1)
+        y = y + coef[:, None] * (h @ e["down"][i])
+    return y
+
+
+@pytest.mark.parametrize("routing", ["empty_expert", "unheld_tokens",
+                                     "one_expert"])
+@pytest.mark.parametrize("form", list(FORMS))
+def test_the_layer_matches_the_per_token_sum_at_every_layout(form, routing):
+    """Forward value and every gradient (tokens, router kernel, gate /
+    up / down) at the three cells' ``(k, held, form)``, each under a
+    routing that leaves a group empty, tokens without a held expert,
+    or one expert with every token."""
+    k, count, act, scoring = FORMS[form]
+    w = form_weights(count, act)
+    x, bias, row0 = routing_case(routing, count)
+    if row0 is not None:
+        w["kernel"] = w["kernel"].at[0].set(row0)
+
+    def program(w, x):
+        e = w["experts"]
+        return moe.expert_layer(
+            x, w["kernel"], bias, e.get("gate"), e["up"], e["down"],
+            top_k=k, scale=2.5, held=(FIRST, count), tile=8,
+            scoring=scoring, act=act)[0]
+
+    def reference(w, x):
+        return per_token_reference(x, w, bias, k, count, act, scoring)
+
+    idx = moe.route(x, w["kernel"], bias, k, 2.5, scoring)[0]
+    p = moe.plan(idx, FIRST, count, 8)
+    assert {"empty_expert": int(p.count[1]) == 0,
+            "unheld_tokens": not np.any(p.held[:8]) and np.any(p.held),
+            "one_expert": int(p.count[2]) == TOKENS}[routing]
+    with jax.default_matmul_precision("highest"):
+        got, want = both(program, reference, w, x)
+    compare(got, want)
+
+
+def test_weight_gradients_ignore_the_rows_that_hold_no_assignment():
+    """The buffer's rows with ``valid == False`` are never masked after
+    the gather: whatever lies there (a large finite value here) meets a
+    zero gradient, so no expert's weight gradient moves."""
+    w = layer_weights(22)
+    x = jax.random.normal(jax.random.PRNGKey(23), (TOKENS, D))
+    idx, weights = moe.route(x, w["router"]["kernel"], w["router"]["bias"],
+                             K, 2.5)
+    p = moe.plan(idx, 4, 8, 8)
+    assert 0 < int(p.valid.sum()) < p.valid.size
+
+    def loss(e, fill):
+        rows = moe.dispatch(x, p)
+        if fill is not None:
+            rows = jnp.where(p.valid[:, None], rows, fill)
+        out = moe.experts_ffn(rows, e["gate"], e["up"], e["down"], p)
+        return jnp.sum(jnp.sin(moe.combine(out, weights, p)))
+
+    held = {n: a[4:12] for n, a in w["experts"].items()}
+    with jax.default_matmul_precision("highest"):
+        as_built, filled = (jax.jit(jax.grad(lambda e: loss(e, fill)))(held)
+                            for fill in (None, 1e3))
+    for n in held:
+        assert np.any(as_built[n])
+        np.testing.assert_allclose(filled[n], as_built[n], rtol=1e-6,
+                                   atol=1e-6)
+
+
+def _eqns(jaxpr):
+    """Every equation of a jaxpr, those of its sub-jaxprs included."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for v in eqn.params.values():
+            for sub in (v if isinstance(v, (tuple, list)) else (v,)):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _eqns(sub)
+
+
+def test_no_token_major_fold_and_no_split_by_tokens():
+    """Abstract shapes, nothing runs.  At ``k = 6`` no intermediate of
+    ``value_and_grad`` of a layer is ``[T, k, d]`` (the chip pads ``k``
+    to a tile's sublanes there), and the layer lowers as many
+    ``pallas_call``s at 16 384 tokens as at 512: no shape makes it run
+    in token chunks, which lengthens the step program and its set-up."""
+    k, count, f = 6, 16, 32
+    calls = {}
+    for T in (512, 16384):
+        sds = lambda *shape: jax.ShapeDtypeStruct(  # noqa: E731
+            shape, jnp.bfloat16)
+
+        def loss(x, kernel, gate, up, down):
+            return moe.expert_layer(
+                x, kernel, None, gate, up, down, top_k=k, scale=1.0,
+                scoring="softmax_topk", act="relu")[0].astype(
+                    jnp.float32).sum()
+
+        jaxpr = jax.make_jaxpr(jax.value_and_grad(
+            loss, argnums=(0, 1, 2, 3, 4)))(
+            sds(T, D), sds(D, count), sds(count, D, f), sds(count, D, f),
+            sds(count, f, D))
+        eqns = list(_eqns(jaxpr.jaxpr))
+        shapes = {v.aval.shape for e in eqns for v in e.outvars}
+        assert (k, T, D) in shapes and (T, k, D) not in shapes
+        calls[T] = sum(e.primitive.name == "pallas_call" for e in eqns)
+    assert calls[512] == calls[16384] == 9     # 5 products + 4 backward
 
 
 # ------------------------------------------- the whole model and the step
