@@ -21,7 +21,9 @@ docs/timeline.md) plus TRACE-level queue logging.  Here:
     ``jax.profiler.TraceAnnotation`` span on the host thread's line of
     the same trace and the same clock.  It times host code (a dispatch,
     a wait); it cannot name a region *inside* a jitted step — that is
-    what the scopes are for.
+    what the scopes are for.  ``SPAN_*`` is the one table of its names:
+    the serving engine's tick thread and ``submit()`` say what they are
+    doing with them (docs/timeline.md "Reading a tick").
 
 Timestamps are **wall-clock anchored**: a fixed ``time.time() -
 perf_counter()`` epoch captured at construction maps monotonic
@@ -411,13 +413,61 @@ def bucket_scope(stage: str, i: int) -> str:
     return f"{SCOPE_PUSH_PULL}/{stage}/b{i:03d}"
 
 
-@contextmanager
-def annotate(name: str):
+# The serve programs (serving/engine.py) run their forward under
+# SCOPE_MODEL like the train step (Flax's module paths nest inside) and
+# name the two things they run outside the model:
+SCOPE_SERVE_SELECT = "bps.serve/select"  # _select_token: pick + key split
+SCOPE_SERVE_ACCEPT = "bps.serve/accept"  # _verify_accept: speculative tail
+
+
+# ---------------------------------------------------------------------------
+# Host spans of the serving engine (``annotate`` below): the tick thread
+# (``byteps-serve-engine``) and ``submit()`` on its caller's thread.  A
+# child's name extends its parent's, and it lies inside it in time, with
+# two exceptions: ``idle_wait`` lies between two ticks, and a speculative
+# tick's ``bps.tick/verify`` opens inside ``bps.tick/decode`` once the
+# proposer found something to verify.
+# benchmark/harness/host_spans.py reads them back.
+# ---------------------------------------------------------------------------
+
+SPAN_TICK = "bps.tick"                       # a tick that had work
+SPAN_TICK_ADMIT = SPAN_TICK + "/admit"       # scheduler.admit; a request's
+#                                              slot, prefix and block grants
+SPAN_TICK_PREFILL = SPAN_TICK + "/prefill"   # one chunk (req, bucket, start)
+SPAN_TICK_DECODE = SPAN_TICK + "/decode"     # the batched decode pass
+SPAN_TICK_VERIFY = SPAN_TICK + "/verify"     # the widened speculative pass
+SPAN_TICK_ACCOUNT = SPAN_TICK + "/account"   # observe_tick, block_stats, gauges
+SPAN_TICK_IDLE_WAIT = SPAN_TICK + "/idle_wait"   # _run, nothing to do
+SPAN_SUBMIT = "bps.submit"                   # submit(), from its first line
+SPAN_SUBMIT_LOCK_WAIT = SPAN_SUBMIT + "/lock_wait"   # taking the lock
+SPAN_SUBMIT_ENQUEUE = SPAN_SUBMIT + "/enqueue"       # inside it (req)
+
+PREFILL_STAGES = ("build", "launch", "readback")
+PASS_STAGES = ("blocks", "build", "launch", "readback", "emit")
+# the values of ``serve.tick_seconds``' ``phase`` label: where a tick's
+# host seconds went (a decode and a verify pass share PASS_STAGES)
+TICK_PHASES = tuple(f"prefill_{s}" for s in PREFILL_STAGES) + (
+    "admit",) + PASS_STAGES + ("account",)
+
+
+# ``STAGE_SPANS[parent][stage]`` = ``<parent>/<stage>``: a prefill chunk's
+# ``build`` / ``launch`` / ``readback``, a decode or verify pass's five
+STAGE_SPANS = {
+    parent: {s: f"{parent}/{s}" for s in stages}
+    for parent, stages in ((SPAN_TICK_PREFILL, PREFILL_STAGES),
+                           (SPAN_TICK_DECODE, PASS_STAGES),
+                           (SPAN_TICK_VERIFY, PASS_STAGES))}
+
+
+def annotate(name: str, **args):
     """Host-side span in a ``jax.profiler`` trace
-    (``jax.profiler.TraceAnnotation``): on the calling thread's line, on
-    the device ops' clock.  The device-side names are the ``SCOPE_*``
-    table above."""
+    (``jax.profiler.TraceAnnotation``, to be entered with ``with``): on
+    the calling thread's line, on the device ops' clock.  ``args`` become
+    the span's arguments (a request id, a bucket); what is only known at
+    its end is added through ``set_metadata(**more)`` of the span that
+    ``with ... as span`` binds.  With no profiler session running a span
+    is a flag test.  The device-side names are the ``SCOPE_*`` table
+    above."""
     import jax.profiler
 
-    with jax.profiler.TraceAnnotation(name):
-        yield
+    return jax.profiler.TraceAnnotation(name, **args)

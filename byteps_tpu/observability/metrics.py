@@ -87,7 +87,9 @@ class Counter(_Metric):
     keeping the counter value track; ``mirror=False`` drops Tracer
     mirroring entirely — registry-only metrics for per-frame hot paths
     whose trace-level detail already comes from spans (the wire
-    engine's counters; docs/observability.md "Overhead")."""
+    engine's counters; docs/observability.md "Overhead").  ``inc`` adds
+    whatever it is given: a cumulative-seconds counter
+    (``serve.tick_seconds``) adds floats."""
 
     __slots__ = ("_value", "_instants", "_mirror")
 

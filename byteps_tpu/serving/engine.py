@@ -99,6 +99,9 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..common import logging as bps_log
+from ..common import tracing
+from ..common.tracing import (SCOPE_MODEL, SCOPE_SERVE_ACCEPT,
+                              SCOPE_SERVE_SELECT)
 from ..inference import sample_logits
 from ..models.transformer import Transformer
 from . import metrics as sm
@@ -208,7 +211,11 @@ class Request:
     # ops the same logical operation issued
     trace_id: str = ""
     _t_pc: float = dataclasses.field(default=0.0, repr=False)
+    # t_submit is taken on submit()'s first line, t_enqueued under the
+    # engine lock: TTFT runs from the first (it holds the wait for the
+    # lock), the queue wait from the second
     t_submit: float = 0.0
+    t_enqueued: float = 0.0
     t_admit: float = 0.0
     t_first: float = 0.0
     t_last: float = 0.0
@@ -218,6 +225,13 @@ class Request:
         default_factory=threading.Event)
 
     def __iter__(self):
+        for tok, _ in self.stamped():
+            yield tok
+
+    def stamped(self):
+        """The stream as ``(token, t_emit)`` pairs: ``t_emit`` is
+        ``_emit``'s ``time.monotonic()`` stamp, from which the frontend
+        measures the hand-off to the wire (``serve.emit_to_wire_s``)."""
         while True:
             item = self._out.get()
             if item is _END:
@@ -692,6 +706,9 @@ class ServingEngine:
         self._prefilling: Dict[int, Request] = {}
         self._tick_chunk_debt = 0   # take_credits() debits to return
         self._tick_prefill = 0      # padded prefill tokens this tick
+        # this tick's host seconds by phase (_phase), flushed onto
+        # serve.tick_seconds at the tick's end
+        self._phase_s = dict.fromkeys(tracing.TICK_PHASES, 0.0)
         self._tok = jnp.zeros((n_slots,), jnp.int32)
         self._keys = jnp.zeros((n_slots, 2), jnp.uint32)
         self._outstanding = 0
@@ -758,12 +775,20 @@ class ServingEngine:
         logits, returning ``(token, carried_key)``.  Sampled mode
         replays generate()'s exact per-step key chain: carry split[0],
         sample with split[1]; greedy carries the key untouched."""
-        if self.greedy:
-            return jnp.argmax(logits_last[0], axis=-1).astype(jnp.int32), key
-        nk, sub = jax.random.split(key)
-        tok = sample_logits(logits_last, sub, self.temperature,
-                            self.top_k, self.top_p)[0].astype(jnp.int32)
-        return tok, nk
+        with jax.named_scope(SCOPE_SERVE_SELECT):
+            if self.greedy:
+                return (jnp.argmax(logits_last[0], axis=-1)
+                        .astype(jnp.int32), key)
+            nk, sub = jax.random.split(key)
+            tok = sample_logits(logits_last, sub, self.temperature,
+                                self.top_k, self.top_p)[0].astype(jnp.int32)
+            return tok, nk
+
+    def _forward(self, variables, *args, method, **kw):
+        """The model's forward of every serve program, under the scope
+        the train step's carries (Flax's module paths nest inside)."""
+        with jax.named_scope(SCOPE_MODEL):
+            return self.model.apply(variables, *args, method=method, **kw)
 
     @staticmethod
     def _slot_row(caches, slot):
@@ -781,13 +806,13 @@ class ServingEngine:
             caches, new_row)
 
     def _make_decode_fn(self):
-        model, greedy = self.model, self.greedy
+        greedy = self.greedy
         pad_id = self.pad_id
         select = self._select_token
 
         def one(variables, row, tok, pos, key):
             rowb = jax.tree_util.tree_map(lambda c: c[None], row)
-            logits, new = model.apply(
+            logits, new = self._forward(
                 variables, tok[None, None], rowb, pos,
                 method=Transformer.decode)
             nxt, nk = select(logits[:, -1], key)
@@ -834,7 +859,7 @@ class ServingEngine:
         fn = self._paged_decode_fns.get(key)
         if fn is not None:
             return fn
-        model, greedy = self.model, self.greedy
+        greedy = self.greedy
         pad_id = self.pad_id
         select = self._select_token
         tp = self.tp
@@ -843,7 +868,7 @@ class ServingEngine:
             def decode_fn(variables, pcaches, tok, pos, active, keys,
                           tables, wblk, woff):
                 self.decode_traces += 1  # trace-time only
-                logits, new_pc = model.apply(
+                logits, new_pc = self._forward(
                     variables, tok[:, None], pcaches, tables, pos,
                     wblk, woff, True,
                     method=Transformer.decode_paged_fused)
@@ -858,7 +883,7 @@ class ServingEngine:
                 return new_pc, nxt, keys2
         else:
             def one(variables, pcaches, table, tok, pos, key):
-                logits, new_rows = model.apply(
+                logits, new_rows = self._forward(
                     variables, tok[None, None], pcaches, table, pos,
                     hw_blocks=hw, tp=tp,
                     method=Transformer.decode_paged)
@@ -920,28 +945,29 @@ class ServingEngine:
         per-request chain stays generate()'s (seeded parity by replay).
         Running on device keeps ``_tok``/``_keys`` resident: the host
         reads back only the small (tmat, counts) arrays to emit."""
-        d = tmat.shape[1] - 1
-        ok = ((props == tmat[:, :-1])
-              & (jnp.arange(d)[None, :] < prop_len[:, None]))
-        lead = jnp.sum(jnp.cumprod(ok.astype(jnp.int32), axis=1), axis=1)
-        m = jnp.minimum(1 + lead, jnp.maximum(budget, 1))
-        if self.eos_id is not None:
-            is_eos = tmat == self.eos_id
-            m = jnp.where(jnp.any(is_eos, axis=1),
-                          jnp.minimum(m, jnp.argmax(is_eos, axis=1) + 1),
-                          m)
-        idx = (m - 1)[:, None]
-        nxt = jnp.take_along_axis(tmat, idx, axis=1)[:, 0]
-        nxt = jnp.where(active, nxt, tok)
-        if self.greedy:
-            nkeys = keys
-        else:
-            nkeys = jnp.take_along_axis(kchain, idx[:, :, None],
-                                        axis=1)[:, 0]
-            nkeys = jnp.where(active[:, None], nkeys, keys)
-        m = jnp.where(active, m, 0)
-        accepted = jnp.where(active, lead, 0)
-        return nxt, nkeys, tmat, m, accepted
+        with jax.named_scope(SCOPE_SERVE_ACCEPT):
+            d = tmat.shape[1] - 1
+            ok = ((props == tmat[:, :-1])
+                  & (jnp.arange(d)[None, :] < prop_len[:, None]))
+            lead = jnp.sum(jnp.cumprod(ok.astype(jnp.int32), axis=1), axis=1)
+            m = jnp.minimum(1 + lead, jnp.maximum(budget, 1))
+            if self.eos_id is not None:
+                is_eos = tmat == self.eos_id
+                m = jnp.where(jnp.any(is_eos, axis=1),
+                              jnp.minimum(m, jnp.argmax(is_eos, axis=1) + 1),
+                              m)
+            idx = (m - 1)[:, None]
+            nxt = jnp.take_along_axis(tmat, idx, axis=1)[:, 0]
+            nxt = jnp.where(active, nxt, tok)
+            if self.greedy:
+                nkeys = keys
+            else:
+                nkeys = jnp.take_along_axis(kchain, idx[:, :, None],
+                                            axis=1)[:, 0]
+                nkeys = jnp.where(active[:, None], nkeys, keys)
+            m = jnp.where(active, m, 0)
+            accepted = jnp.where(active, lead, 0)
+            return nxt, nkeys, tmat, m, accepted
 
     def _verify_fn(self, tq: int):
         """Jitted speculative verify for one depth bucket (``tq`` =
@@ -957,12 +983,11 @@ class ServingEngine:
         fn = self._verify_fns.get(tq)
         if fn is not None:
             return fn
-        model = self.model
         select = self._select_token
 
         def one(variables, row, toks, pos, key):
             rowb = jax.tree_util.tree_map(lambda c: c[None], row)
-            logits, new = model.apply(
+            logits, new = self._forward(
                 variables, toks[None, :], rowb, pos,
                 method=Transformer.verify_tokens)
             ts, ks, k = [], [], key
@@ -1007,7 +1032,6 @@ class ServingEngine:
         fn = self._verify_fns.get(key)
         if fn is not None:
             return fn
-        model = self.model
         select = self._select_token
         tp = self.tp
 
@@ -1026,7 +1050,7 @@ class ServingEngine:
                           woff):
                 self.verify_traces += 1  # trace-time only
                 toks = jnp.concatenate([tok[:, None], props], axis=1)
-                logits, new_pc = model.apply(
+                logits, new_pc = self._forward(
                     variables, toks, pcaches, tables, pos, wblk, woff,
                     method=Transformer.verify_tokens_paged_fused)
                 tmat, kchain = jax.vmap(chain)(logits, keys)
@@ -1035,7 +1059,7 @@ class ServingEngine:
                     budget)
         else:
             def one(variables, pcaches, table, toks, pos, key):
-                logits, new_rows = model.apply(
+                logits, new_rows = self._forward(
                     variables, toks[None, :], pcaches, table, pos,
                     hw_blocks=hw, tp=tp,
                     method=Transformer.verify_tokens_paged)
@@ -1089,7 +1113,7 @@ class ServingEngine:
         fn = self._chunk_fns.get(bucket)
         if fn is not None:
             return fn
-        model, select = self.model, self._select_token
+        select = self._select_token
         blk = self.pool.block
         mb = self.pool.max_blocks
         null = self.pool.null_block
@@ -1099,7 +1123,7 @@ class ServingEngine:
         def chunk_fn(variables, pcaches, tokens, table, start, last_idx,
                      key):
             self.chunk_traces += 1  # trace-time only
-            logits, new_rows = model.apply(
+            logits, new_rows = self._forward(
                 variables, tokens, pcaches, table, start, last_idx,
                 tp=tp, method=Transformer.prefill_chunk_paged)
             tok0, nk = select(logits[:, -1], key)
@@ -1261,11 +1285,11 @@ class ServingEngine:
         fn = self._prefill_fns.get(bucket)
         if fn is not None:
             return fn
-        model, select = self.model, self._select_token
+        select = self._select_token
 
         def prefill_fn(variables, caches, prompt, slot, true_len, key):
             self.prefill_traces += 1  # trace-time only
-            logits, new_row = model.apply(
+            logits, new_row = self._forward(
                 variables, prompt, self._slot_row(caches, slot), true_len,
                 method=_prefill_forward)
             tok0, nk = select(logits[:, -1], key)
@@ -1285,11 +1309,11 @@ class ServingEngine:
         fn = self._chunk_fns.get(bucket)
         if fn is not None:
             return fn
-        model, select = self.model, self._select_token
+        select = self._select_token
 
         def chunk_fn(variables, caches, tokens, slot, start, last_idx, key):
             self.chunk_traces += 1  # trace-time only
-            logits, new_row = model.apply(
+            logits, new_row = self._forward(
                 variables, tokens, self._slot_row(caches, slot), start,
                 last_idx, method=Transformer.prefill_chunk)
             tok0, nk = select(logits[:, -1], key)
@@ -1402,19 +1426,38 @@ class ServingEngine:
         them; ``kv_blocks`` (disagg decode replicas) carries staged,
         already-written block ids whose adoption replaces the prefill
         pass entirely (docs/serving.md "Disaggregated tiers")."""
-        if epoch is not None:
-            with self.epoch_fence(epoch):
-                return self._submit(prompt, max_new_tokens, seed=seed,
-                                    priority=priority,
-                                    resume_tokens=resume_tokens,
-                                    keep_kv=keep_kv, kv_blocks=kv_blocks)
-        return self._submit(prompt, max_new_tokens, seed=seed,
-                            priority=priority, resume_tokens=resume_tokens,
-                            keep_kv=keep_kv, kv_blocks=kv_blocks)
+        # stamped before anything can wait: TTFT holds the wait for the
+        # engine lock below (the tick thread holds it for a whole tick)
+        t_submit = time.monotonic()
+        fence = (contextlib.nullcontext() if epoch is None
+                 else self.epoch_fence(epoch))
+        with tracing.annotate(tracing.SPAN_SUBMIT), fence:
+            return self._submit(prompt, max_new_tokens, seed=seed,
+                                priority=priority,
+                                resume_tokens=resume_tokens,
+                                keep_kv=keep_kv, kv_blocks=kv_blocks,
+                                t_submit=t_submit)
+
+    @contextlib.contextmanager
+    def _lock_waited(self):
+        """The engine lock as a submit takes it: the wait under
+        ``bps.submit/lock_wait`` and on ``serve.submit_lock_wait_s``.
+        Callers write ``with self._lock_waited(), self._lock:`` — the
+        second take is re-entrant and free, and it is where the lock
+        analyzer (scripts/lint.py) reads the guarded scope from."""
+        t0 = time.perf_counter()
+        with tracing.annotate(tracing.SPAN_SUBMIT_LOCK_WAIT):
+            self._lock.acquire()
+        try:
+            self.metrics.observe("submit_lock_wait",
+                                 time.perf_counter() - t0)
+            yield
+        finally:
+            self._lock.release()
 
     def _submit(self, prompt, max_new_tokens: int, *, seed: int,
                 priority: int, resume_tokens, keep_kv: bool = False,
-                kv_blocks=None) -> Request:
+                kv_blocks=None, t_submit: float) -> Request:
         prompt = np.asarray(prompt, np.int32).reshape(-1)
         T = int(prompt.shape[0])
         if T < 1:
@@ -1435,12 +1478,12 @@ class ServingEngine:
             # emit tokens a never-interrupted run never produces).
             # Answer an already-finished request — no slot, no prefill,
             # safe even on configs that refuse recompute-based resume.
-            with self._lock:
+            with self._lock_waited(), self._lock:
                 self._req_seq += 1
                 req = Request(id=self._req_seq, prompt=prompt,
                               max_new_tokens=max_new_tokens, seed=seed,
-                              priority=priority,
-                              t_submit=time.monotonic())
+                              priority=priority, t_submit=t_submit,
+                              t_enqueued=time.monotonic())
                 req.tokens = resumed
                 req.state = RequestState.DONE
                 req._out.put(_END)
@@ -1483,47 +1526,51 @@ class ServingEngine:
         # thread can see the request: a fast request could otherwise
         # finish (decrementing) first, and a concurrent drain() would
         # see a transiently-zero counter with work still in flight.
-        with self._lock:
-            if self._engine_error is not None:
-                raise RuntimeError(
-                    f"serving engine is dead (tick failed with "
-                    f"{self._engine_error!r}); restart it") \
-                    from self._engine_error
-            self._req_seq += 1
-            req = Request(id=self._req_seq, prompt=prompt,
-                          max_new_tokens=max_new_tokens, seed=seed,
-                          priority=priority, t_submit=time.monotonic())
-            if resumed:
-                # pre-seed the emitted tokens and park the resume state
-                # exactly as _preempt would have: _admit then prefills
-                # prompt + tokens[:-1] and the final chunk restores the
-                # parked next-input token and carried key instead of
-                # emitting a fresh "first" token
-                req.tokens = resumed
-                req._resumed_n = len(resumed)
-                req._resume_tok = resumed[-1]
-                if not self.greedy:
-                    req._resume_key = _resume_key_chain(seed, len(resumed))
-            req._keep_kv = bool(keep_kv and self.paged)
-            if kv_blocks is not None and self.paged:
-                req._kv_blocks = [int(b) for b in kv_blocks]
-                kv_blocks = None  # ownership moved to the request
-            if self._trace_rpc:
-                # join the caller's active trace (a submit inside a
-                # traced client op) or mint a fresh id for this request
-                from ..observability.trace import (current_trace_id,
-                                                   mint_trace_id)
+        with self._lock_waited(), self._lock:
+            with tracing.annotate(tracing.SPAN_SUBMIT_ENQUEUE,
+                                  req=self._req_seq + 1):
+                if self._engine_error is not None:
+                    raise RuntimeError(
+                        f"serving engine is dead (tick failed with "
+                        f"{self._engine_error!r}); restart it") \
+                        from self._engine_error
+                self._req_seq += 1
+                req = Request(id=self._req_seq, prompt=prompt,
+                              max_new_tokens=max_new_tokens, seed=seed,
+                              priority=priority, t_submit=t_submit,
+                              t_enqueued=time.monotonic())
+                if resumed:
+                    # pre-seed the emitted tokens and park the resume state
+                    # exactly as _preempt would have: _admit then prefills
+                    # prompt + tokens[:-1] and the final chunk restores the
+                    # parked next-input token and carried key instead of
+                    # emitting a fresh "first" token
+                    req.tokens = resumed
+                    req._resumed_n = len(resumed)
+                    req._resume_tok = resumed[-1]
+                    if not self.greedy:
+                        req._resume_key = _resume_key_chain(seed, len(resumed))
+                req._keep_kv = bool(keep_kv and self.paged)
+                if kv_blocks is not None and self.paged:
+                    req._kv_blocks = [int(b) for b in kv_blocks]
+                    kv_blocks = None  # ownership moved to the request
+                if self._trace_rpc:
+                    # join the caller's active trace (a submit inside a
+                    # traced client op) or mint a fresh id for this request
+                    from ..observability.trace import (current_trace_id,
+                                                       mint_trace_id)
 
-                req.trace_id = (current_trace_id() or mint_trace_id()).hex()
-                req._t_pc = time.perf_counter()
-            self._outstanding += 1
-            try:
-                req._task = self.scheduler.submit(req, bucket)
-            except Exception:
-                self._outstanding -= 1
-                self._drain_cv.notify_all()  # same lock; wake waiters
-                self.metrics.bump(sm.REJECTED)
-                raise
+                    req.trace_id = (current_trace_id()
+                                    or mint_trace_id()).hex()
+                    req._t_pc = time.perf_counter()
+                self._outstanding += 1
+                try:
+                    req._task = self.scheduler.submit(req, bucket)
+                except Exception:
+                    self._outstanding -= 1
+                    self._drain_cv.notify_all()  # same lock; wake waiters
+                    self.metrics.bump(sm.REJECTED)
+                    raise
         self.metrics.bump(sm.SUBMITTED)
         with self._wake:
             self._wake.notify_all()
@@ -1564,6 +1611,32 @@ class ServingEngine:
             return self._step_locked()
 
     def _step_locked(self) -> Dict[str, int]:
+        # with nothing in flight and nothing queued (the 50 ms background
+        # poll) there is no tick to run: no span, no gauges, no counters
+        # — a traced long-lived server would otherwise append to the
+        # Tracer's in-memory list forever.  Nothing can arrive meanwhile:
+        # submit() enqueues under this lock
+        if not (self.pool.active_count or self.scheduler.depth):
+            return {"admitted": 0, "emitted": 0, "active": 0, "queued": 0,
+                    "prefill_tokens": 0}
+        with tracing.annotate(tracing.SPAN_TICK,
+                              active=self.pool.active_count,
+                              queued=self.scheduler.depth) as span:
+            return self._tick_locked(span)
+
+    @contextlib.contextmanager
+    def _phase_locked(self, span: str, phase: str, **args):
+        """One phase of a tick, named twice from one pair of stamps: a
+        host span on the device trace's clock, and its seconds on
+        ``serve.tick_seconds{phase}`` at the tick's end."""
+        t0 = time.perf_counter()
+        try:
+            with tracing.annotate(span, **args):
+                yield
+        finally:
+            self._phase_s[phase] += time.perf_counter() - t0
+
+    def _tick_locked(self, span) -> Dict[str, int]:
         emitted = 0
         admitted = 0
         granted: List = []
@@ -1588,7 +1661,8 @@ class ServingEngine:
             # FIFO)
             free = self.pool.free_count
             if free:
-                granted = self.scheduler.admit(free)
+                with self._phase_locked(tracing.SPAN_TICK_ADMIT, "admit"):
+                    granted = self.scheduler.admit(free)
                 for task in granted:
                     req = task.request
                     if req.cancelled:
@@ -1648,12 +1722,7 @@ class ServingEngine:
             if self._tick_chunk_debt:
                 self.scheduler.return_credits(self._tick_chunk_debt)
                 self._tick_chunk_debt = 0
-        # idle ticks (background poll with nothing in flight) emit no
-        # gauges — a traced long-lived server would otherwise append
-        # two counter events per 50ms poll to the Tracer's in-memory
-        # list forever
-        if granted or emitted or self.pool.active_count \
-                or self.scheduler.depth:
+        with self._phase_locked(tracing.SPAN_TICK_ACCOUNT, "account"):
             self.metrics.observe_tick(self.pool.occupancy(),
                                       self.scheduler.depth, emitted)
             # live credit level (post-return = the budget the next
@@ -1664,6 +1733,9 @@ class ServingEngine:
                 self.metrics.gauge(sm.KV_BLOCKS_FREE, bs["free"])
                 self.metrics.gauge(sm.KV_BLOCKS_USED, bs["used"])
                 self.metrics.gauge(sm.KV_BLOCKS_SHARED, bs["shared"])
+        span.set_metadata(admitted=admitted, emitted=emitted)
+        self.metrics.observe_tick_phases(self._phase_s)
+        self._phase_s = dict.fromkeys(tracing.TICK_PHASES, 0.0)
         # "admitted" counts requests actually assigned a slot this tick
         # — NOT cancelled grants or held (resubmitted) tasks
         return {"admitted": admitted, "emitted": emitted,
@@ -1672,118 +1744,92 @@ class ServingEngine:
                 "prefill_tokens": self._tick_prefill}
 
     def _admit(self, req: Request) -> int:
-        # the sequence this admission must prefill: the prompt, or —
-        # when resuming a preempted request — prompt + emitted tokens
-        # minus the last (its K/V is unwritten; it is the next decode
-        # input, parked in _resume_tok)
-        k = len(req.tokens)
-        seq = (req.prompt if k == 0 else
-               np.concatenate([req.prompt,
-                               np.asarray(req.tokens[:-1], np.int32)]))
-        req._seq = seq
-        T = int(seq.shape[0])
-        slot = self.pool.assign(req.id, T)
-        assert slot is not None, "admit() granted beyond free slots"
-        req.slot = slot
-        if not req.t_admit:  # keep the first admission's queue-wait
-            req.t_admit = time.monotonic()
-            self.metrics.bump(sm.ADMITTED)
-        self._slot_req[slot] = req
-        if req._kv_blocks is not None:
-            # disagg adoption: a shipped prefill's staged blocks replace
-            # the prefill pass.  The table adopts them (ownership
-            # transfer — the stager's refs become the table's), the
-            # cursor is already at T from assign(), and the parked
-            # resume pair seeds the next decode input exactly like the
-            # chunked-resume path below — bit-exact by the position-wise
-            # determinism argument (docs/serving.md "Disaggregated
-            # tiers").  Any geometry surprise refuses adoption and falls
-            # through to normal (re-)prefill — never a wrong answer.
-            ids, req._kv_blocks = req._kv_blocks, None
-            if (self.paged and req._resume_tok is not None
-                    and len(ids) == -(-T // self.pool.block)
-                    and len(ids) <= self.pool.tables[slot].max_blocks
-                    and not self.pool.tables[slot].blocks):
-                self.pool.adopt_blocks(slot, ids)
-                req.state = RequestState.ACTIVE
-                self._tok = self._tok.at[slot].set(req._resume_tok)
-                if not self.greedy and req._resume_key is not None:
-                    self._keys = self._keys.at[slot].set(
-                        jnp.asarray(req._resume_key))
-                req._resume_tok = None
-                req._resume_key = None
-                return 0
-            bps_log.warning(
-                "disagg: refusing adoption of %d staged block(s) for "
-                "request %d (want %d for T=%d) — re-prefilling",
-                len(ids), req.id, -(-T // self.pool.block)
-                if self.paged else -1, T)
-            for b in ids:
-                self.pool.alloc.decref(int(b))
-        p0 = 0
-        if self.prefix is not None:
-            req._prefix_digs = self.prefix.digests_for(
-                seq, salt=self._prefix_salt)
-            m = self.prefix.match(seq, salt=self._prefix_salt,
-                                  digests=req._prefix_digs)
-            if m is not None:
-                entry, p0 = m
-                # pin across the attach/copy, then resume prefill at
-                # the boundary — the shared (or copied) bytes ARE the
-                # K/V whole prefill would recompute, so parity is by
-                # construction
-                self.prefix.acquire(entry)
-                try:
-                    if self.paged:
-                        # zero-copy prefix hit: the slot's table adopts
-                        # the entry's blocks (refcount bumps, no device
-                        # work — the acceptance criterion the compile
-                        # counters pin)
-                        self.pool.share_prefix(
-                            slot, entry.buffer[:p0 // self.pool.block])
-                    else:
-                        self.pool.caches = self._prefix_copy_fn()(
-                            self.pool.caches, entry.buffer, slot)
-                finally:
-                    self.prefix.release(entry)
-                self.metrics.bump(sm.PREFIX_HITS)
-                self.metrics.bump(sm.PREFIX_HIT_TOKENS, p0)
-            else:
-                self.metrics.bump(sm.PREFIX_MISSES)
+        with self._phase_locked(tracing.SPAN_TICK_ADMIT, "admit", req=req.id):
+            # the sequence this admission must prefill: the prompt, or —
+            # when resuming a preempted request — prompt + emitted tokens
+            # minus the last (its K/V is unwritten; it is the next decode
+            # input, parked in _resume_tok)
+            k = len(req.tokens)
+            seq = (req.prompt if k == 0 else
+                   np.concatenate([req.prompt,
+                                   np.asarray(req.tokens[:-1], np.int32)]))
+            req._seq = seq
+            T = int(seq.shape[0])
+            slot = self.pool.assign(req.id, T)
+            assert slot is not None, "admit() granted beyond free slots"
+            req.slot = slot
+            if not req.t_admit:  # keep the first admission's queue-wait
+                req.t_admit = time.monotonic()
+                self.metrics.bump(sm.ADMITTED)
+            self._slot_req[slot] = req
+            if req._kv_blocks is not None:
+                # disagg adoption: a shipped prefill's staged blocks replace
+                # the prefill pass.  The table adopts them (ownership
+                # transfer — the stager's refs become the table's), the
+                # cursor is already at T from assign(), and the parked
+                # resume pair seeds the next decode input exactly like the
+                # chunked-resume path below — bit-exact by the position-wise
+                # determinism argument (docs/serving.md "Disaggregated
+                # tiers").  Any geometry surprise refuses adoption and falls
+                # through to normal (re-)prefill — never a wrong answer.
+                ids, req._kv_blocks = req._kv_blocks, None
+                if (self.paged and req._resume_tok is not None
+                        and len(ids) == -(-T // self.pool.block)
+                        and len(ids) <= self.pool.tables[slot].max_blocks
+                        and not self.pool.tables[slot].blocks):
+                    self.pool.adopt_blocks(slot, ids)
+                    req.state = RequestState.ACTIVE
+                    self._tok = self._tok.at[slot].set(req._resume_tok)
+                    if not self.greedy and req._resume_key is not None:
+                        self._keys = self._keys.at[slot].set(
+                            jnp.asarray(req._resume_key))
+                    req._resume_tok = None
+                    req._resume_key = None
+                    return 0
+                bps_log.warning(
+                    "disagg: refusing adoption of %d staged block(s) for "
+                    "request %d (want %d for T=%d) — re-prefilling",
+                    len(ids), req.id, -(-T // self.pool.block)
+                    if self.paged else -1, T)
+                for b in ids:
+                    self.pool.alloc.decref(int(b))
+            p0 = 0
+            if self.prefix is not None:
+                req._prefix_digs = self.prefix.digests_for(
+                    seq, salt=self._prefix_salt)
+                m = self.prefix.match(seq, salt=self._prefix_salt,
+                                      digests=req._prefix_digs)
+                if m is not None:
+                    entry, p0 = m
+                    # pin across the attach/copy, then resume prefill at
+                    # the boundary — the shared (or copied) bytes ARE the
+                    # K/V whole prefill would recompute, so parity is by
+                    # construction
+                    self.prefix.acquire(entry)
+                    try:
+                        if self.paged:
+                            # zero-copy prefix hit: the slot's table adopts
+                            # the entry's blocks (refcount bumps, no device
+                            # work — the acceptance criterion the compile
+                            # counters pin)
+                            self.pool.share_prefix(
+                                slot, entry.buffer[:p0 // self.pool.block])
+                        else:
+                            self.pool.caches = self._prefix_copy_fn()(
+                                self.pool.caches, entry.buffer, slot)
+                    finally:
+                        self.prefix.release(entry)
+                    self.metrics.bump(sm.PREFIX_HITS)
+                    self.metrics.bump(sm.PREFIX_HIT_TOKENS, p0)
+                else:
+                    self.metrics.bump(sm.PREFIX_MISSES)
         if p0 == 0 and not self.chunk and not self.paged:
             # whole-prompt prefill (the pre-chunking path, bit-identical)
             req.state = RequestState.ACTIVE
             bucket = _next_bucket(T, self.min_prefill_bucket, self.max_seq)
-            padded = np.full((1, bucket), self.pad_id, np.int32)
-            padded[0, :T] = seq
-            key = (jnp.zeros((2,), jnp.uint32) if self.greedy
-                   else jax.random.PRNGKey(req.seed))
-            fn = self._prefill_fn(bucket)
-            caches, tok0, nk = fn(self.variables, self.pool.caches,
-                                  jnp.asarray(padded), slot, T, key)
-            self.pool.caches = caches
-            self.metrics.bump(sm.PREFILL_TOKENS, bucket)
-            self._tick_prefill += bucket
-            if req._resume_tok is not None:
-                # resuming a request another engine emitted tokens for
-                # (router failover): the prefill's sampled token and key
-                # split are discarded — the parked next-input token and
-                # the recomputed carried key continue the original
-                # chain, same discipline as the chunked resume path
-                self._tok = self._tok.at[slot].set(req._resume_tok)
-                if not self.greedy and req._resume_key is not None:
-                    self._keys = self._keys.at[slot].set(
-                        jnp.asarray(req._resume_key))
-                req._resume_tok = None
-                req._resume_key = None
-                self._maybe_insert_prefix(req)
-                return 0
-            self._tok = self._tok.at[slot].set(tok0)
-            if not self.greedy:
-                self._keys = self._keys.at[slot].set(nk)
-            self._maybe_insert_prefix(req)
-            self._emit(req, int(tok0))
-            return 1
+            with tracing.annotate(tracing.SPAN_TICK_PREFILL, req=req.id,
+                                  bucket=bucket, start=0):
+                return self._prefill_whole(req, seq, bucket)
         # chunked (or prefix-resumed, or paged) prefill: the request
         # parks in PREFILLING with the slot held; the admission grant
         # pre-paid its first chunk, later chunks debit the shared
@@ -1794,6 +1840,49 @@ class ServingEngine:
         self._prefilling[slot] = req
         return self._advance_prefill(req)
 
+    def _prefill_whole(self, req: Request, seq, bucket: int) -> int:
+        """The whole prompt in one program (dense engine, no chunking,
+        no prefix hit): build, launch, read the first token back."""
+        T = int(seq.shape[0])
+        slot = req.slot
+        stage = tracing.STAGE_SPANS[tracing.SPAN_TICK_PREFILL]
+        with self._phase_locked(stage["build"], "prefill_build"):
+            padded = np.full((1, bucket), self.pad_id, np.int32)
+            padded[0, :T] = seq
+            key = (jnp.zeros((2,), jnp.uint32) if self.greedy
+                   else jax.random.PRNGKey(req.seed))
+            fn = self._prefill_fn(bucket)
+            padded = jnp.asarray(padded)
+        with self._phase_locked(stage["launch"], "prefill_launch"):
+            caches, tok0, nk = fn(self.variables, self.pool.caches,
+                                  padded, slot, T, key)
+            del padded          # as in _decode_pass: not at the return
+        self.pool.caches = caches
+        self.metrics.bump(sm.PREFILL_TOKENS, bucket)
+        self._tick_prefill += bucket
+        if req._resume_tok is not None:
+            # resuming a request another engine emitted tokens for
+            # (router failover): the prefill's sampled token and key
+            # split are discarded — the parked next-input token and
+            # the recomputed carried key continue the original
+            # chain, same discipline as the chunked resume path
+            self._tok = self._tok.at[slot].set(req._resume_tok)
+            if not self.greedy and req._resume_key is not None:
+                self._keys = self._keys.at[slot].set(
+                    jnp.asarray(req._resume_key))
+            req._resume_tok = None
+            req._resume_key = None
+            self._maybe_insert_prefix(req)
+            return 0
+        self._tok = self._tok.at[slot].set(tok0)
+        if not self.greedy:
+            self._keys = self._keys.at[slot].set(nk)
+        self._maybe_insert_prefix(req)
+        with self._phase_locked(stage["readback"], "prefill_readback"):
+            tok = int(tok0)
+        self._emit(req, tok)
+        return 1
+
     def _advance_prefill(self, req: Request) -> int:
         """Run as many prefill chunks for ``req`` as the tick's credits
         allow.  Returns 1 when the final chunk completed (first token
@@ -1803,7 +1892,6 @@ class ServingEngine:
         request mid-pass; the slot is gone then)."""
         seq = req._seq if req._seq is not None else req.prompt
         T = int(seq.shape[0])
-        slot = req.slot
         S = self.max_seq
         while True:
             p0 = req.prefill_pos
@@ -1840,6 +1928,23 @@ class ServingEngine:
             # already in the row rewrites identical bytes (position-wise
             # determinism, docs/serving.md), so the overlap is bit-exact
             start = min(p0, S - bucket)
+            with tracing.annotate(tracing.SPAN_TICK_PREFILL, req=req.id,
+                                  bucket=bucket, start=start):
+                out = self._prefill_chunk(req, seq, p0, csize, bucket,
+                                          start)
+            if out is not None:
+                return out
+
+    def _prefill_chunk(self, req: Request, seq, p0: int, csize: int,
+                       bucket: int, start: int) -> Optional[int]:
+        """One paid-for chunk ``[start, start + bucket)`` of ``req``'s
+        prefill: build, launch and, on the final chunk, the first
+        token's readback.  ``None`` = a middle chunk (the caller loops
+        on), else ``_advance_prefill``'s answer."""
+        T = int(seq.shape[0])
+        slot = req.slot
+        stage = tracing.STAGE_SPANS[tracing.SPAN_TICK_PREFILL]
+        with self._phase_locked(stage["build"], "prefill_build"):
             if self.paged:
                 # lazy block grant for the chunk's REAL tokens only
                 # (min(..., T)): the padded bucket tail's writes route
@@ -1869,45 +1974,48 @@ class ServingEngine:
                    else jax.random.PRNGKey(req.seed))
             if self.paged:
                 fn = self._paged_chunk_fn(bucket)
-                caches, tok0, nk = fn(self.variables, self.pool.caches,
-                                      jnp.asarray(toks),
-                                      self.pool.table_row(slot), start,
-                                      last_idx, key)
+                where = self.pool.table_row(slot)
             else:
                 fn = self._chunk_fn(bucket)
-                caches, tok0, nk = fn(self.variables, self.pool.caches,
-                                      jnp.asarray(toks), slot, start,
-                                      last_idx, key)
-            self.pool.caches = caches
-            req.prefill_pos = p0 + csize
-            self.metrics.bump(sm.PREFILL_TOKENS, bucket)
-            self.metrics.bump(sm.PREFILL_CHUNKS)
-            self._tick_prefill += bucket
-            if final:
-                del self._prefilling[slot]
-                req.state = RequestState.ACTIVE
-                if req._resume_tok is not None:
-                    # resuming a preempted request: the K/V for every
-                    # already-emitted token is rebuilt; the final
-                    # chunk's sampled token AND its key split are
-                    # discarded, and the parked next-input token plus
-                    # the carried key are restored — the per-request
-                    # key chain continues exactly once-per-step, so
-                    # seeded streams stay bit-exact across preemption
-                    self._tok = self._tok.at[slot].set(req._resume_tok)
-                    if not self.greedy and req._resume_key is not None:
-                        self._keys = self._keys.at[slot].set(
-                            jnp.asarray(req._resume_key))
-                    req._resume_tok = None
-                    req._resume_key = None
-                    self._maybe_insert_prefix(req)
-                    return 0  # nothing emitted; decode resumes next
-                self._tok = self._tok.at[slot].set(tok0)
-                if not self.greedy:
-                    self._keys = self._keys.at[slot].set(nk)
-                self._maybe_insert_prefix(req)
-                self._emit(req, int(tok0))
-                return 1
+                where = slot
+            toks = jnp.asarray(toks)
+        with self._phase_locked(stage["launch"], "prefill_launch"):
+            caches, tok0, nk = fn(self.variables, self.pool.caches, toks,
+                                  where, start, last_idx, key)
+            del toks, where     # as in _decode_pass: not at the return
+        self.pool.caches = caches
+        req.prefill_pos = p0 + csize
+        self.metrics.bump(sm.PREFILL_TOKENS, bucket)
+        self.metrics.bump(sm.PREFILL_CHUNKS)
+        self._tick_prefill += bucket
+        if not final:
+            return None
+        del self._prefilling[slot]
+        req.state = RequestState.ACTIVE
+        if req._resume_tok is not None:
+            # resuming a preempted request: the K/V for every
+            # already-emitted token is rebuilt; the final chunk's
+            # sampled token AND its key split are discarded, and the
+            # parked next-input token plus the carried key are restored
+            # — the per-request key chain continues exactly
+            # once-per-step, so seeded streams stay bit-exact across
+            # preemption
+            self._tok = self._tok.at[slot].set(req._resume_tok)
+            if not self.greedy and req._resume_key is not None:
+                self._keys = self._keys.at[slot].set(
+                    jnp.asarray(req._resume_key))
+            req._resume_tok = None
+            req._resume_key = None
+            self._maybe_insert_prefix(req)
+            return 0  # nothing emitted; decode resumes next
+        self._tok = self._tok.at[slot].set(tok0)
+        if not self.greedy:
+            self._keys = self._keys.at[slot].set(nk)
+        self._maybe_insert_prefix(req)
+        with self._phase_locked(stage["readback"], "prefill_readback"):
+            tok = int(tok0)
+        self._emit(req, tok)
+        return 1
 
     def _with_block_pressure(self, req: Request, fn) -> bool:
         """Run ``fn()`` (a block allocation on behalf of ``req``); on
@@ -2042,22 +2150,28 @@ class ServingEngine:
         return _next_bucket(-(-need // blk), 1, self.pool.max_blocks)
 
     def _decode_tick(self, active: List[int]) -> int:
+        with tracing.annotate(tracing.SPAN_TICK_DECODE,
+                              slots=len(active)):
+            return self._decode_pass(active)
+
+    def _decode_pass(self, active: List[int]) -> int:
         n = self.pool.n_slots
+        stage = tracing.STAGE_SPANS[tracing.SPAN_TICK_DECODE]
         if self.paged:
             # lazy block grant at the boundary crossing: a slot whose
             # cursor enters an uncovered block gets one here — under
             # pressure this is where prefix eviction / preemption fires
-            for slot in list(active):
-                req = self._slot_req[slot]
-                if req is None:
-                    continue  # a victim of an earlier preemption
-                if not self._with_block_pressure(
+            with self._phase_locked(stage["blocks"], "blocks"):
+                for slot in list(active):
+                    req = self._slot_req[slot]
+                    if req is None:
+                        continue  # a victim of an earlier preemption
+                    self._with_block_pressure(
                         req, lambda s=slot: self.pool.ensure_blocks(
-                            s, self.pool.pos[s] + 1)):
-                    continue
-            active = [s for s in active
-                      if self._slot_req[s] is not None
-                      and s not in self._prefilling]
+                            s, self.pool.pos[s] + 1))
+                active = [s for s in active
+                          if self._slot_req[s] is not None
+                          and s not in self._prefilling]
             if not active:
                 return 0
         if self.spec is not None:
@@ -2067,63 +2181,67 @@ class ServingEngine:
                 if out is not None:
                     return out
         self.metrics.bump(sm.DECODE_TICKS)
-        pos = np.zeros((n,), np.int32)
-        mask = np.zeros((n,), bool)
-        for slot in active:
-            pos[slot] = self.pool.pos[slot]
-            mask[slot] = True
-        if self.paged:
-            # scatter targets: each active slot writes its cursor's
-            # (block, offset); masked slots (free or PREFILLING) write
-            # the null block, so their garbage can never land in a
-            # shared prefix block or a mid-prefill row
-            wblk = np.full((n,), self.pool.null_block, np.int32)
-            woff = np.zeros((n,), np.int32)
+        with self._phase_locked(stage["build"], "build"):
+            pos = np.zeros((n,), np.int32)
+            mask = np.zeros((n,), bool)
             for slot in active:
-                wblk[slot], woff[slot] = self.pool.write_target(slot)
-            if self.paged_kernel:
-                # fused kernel: one program, write targets per (slot,
-                # query) — tq = 1 here — and NO gather anywhere
-                fn = self._paged_decode_fn(None)
-                caches, nxt, keys = fn(
-                    self.variables, self.pool.caches, self._tok,
-                    jnp.asarray(pos), jnp.asarray(mask), self._keys,
-                    self.pool.tables_device(),
-                    jnp.asarray(wblk[:, None]),
-                    jnp.asarray(woff[:, None]))
-            else:
-                # pos-capped gather: stream each slot's high-water
-                # bucket, not the full null-padded table width
-                hw = self._gather_hw(1)
-                self.metrics.bump(sm.GATHERED_BLOCKS, n * hw)
-                fn = self._paged_decode_fn(hw)
-                caches, nxt, keys = fn(
-                    self.variables, self.pool.caches, self._tok,
-                    jnp.asarray(pos), jnp.asarray(mask), self._keys,
-                    self.pool.tables_device(), jnp.asarray(wblk),
-                    jnp.asarray(woff))
-        else:
-            # PREFILLING slots ride the decode step masked-off like
-            # freed slots do, but their garbage K/V write must NOT land
-            # at pos 0 (it would corrupt the copied prefix / already-
-            # written chunks): aim it at the slot's post-prefill
-            # cursor, which the request's own first real decode
-            # overwrites before the causal mask can ever admit it
-            for slot in self._prefilling:
                 pos[slot] = self.pool.pos[slot]
-            caches, nxt, keys = self._decode_step(
-                self.variables, self.pool.caches, self._tok,
-                jnp.asarray(pos), jnp.asarray(mask), self._keys)
+                mask[slot] = True
+            if self.paged:
+                # scatter targets: each active slot writes its cursor's
+                # (block, offset); masked slots (free or PREFILLING)
+                # write the null block, so their garbage can never land
+                # in a shared prefix block or a mid-prefill row
+                wblk = np.full((n,), self.pool.null_block, np.int32)
+                woff = np.zeros((n,), np.int32)
+                for slot in active:
+                    wblk[slot], woff[slot] = self.pool.write_target(slot)
+                if self.paged_kernel:
+                    # fused kernel: one program, write targets per
+                    # (slot, query) — tq = 1 here — and NO gather
+                    # anywhere
+                    fn = self._paged_decode_fn(None)
+                    wblk, woff = wblk[:, None], woff[:, None]
+                else:
+                    # pos-capped gather: stream each slot's high-water
+                    # bucket, not the full null-padded table width
+                    hw = self._gather_hw(1)
+                    self.metrics.bump(sm.GATHERED_BLOCKS, n * hw)
+                    fn = self._paged_decode_fn(hw)
+                args = (jnp.asarray(pos), jnp.asarray(mask), self._keys,
+                        self.pool.tables_device(), jnp.asarray(wblk),
+                        jnp.asarray(woff))
+            else:
+                # PREFILLING slots ride the decode step masked-off like
+                # freed slots do, but their garbage K/V write must NOT
+                # land at pos 0 (it would corrupt the copied prefix /
+                # already-written chunks): aim it at the slot's
+                # post-prefill cursor, which the request's own first
+                # real decode overwrites before the causal mask can
+                # ever admit it
+                for slot in self._prefilling:
+                    pos[slot] = self.pool.pos[slot]
+                fn = self._decode_step
+                args = (jnp.asarray(pos), jnp.asarray(mask), self._keys)
+        with self._phase_locked(stage["launch"], "launch"):
+            caches, nxt, keys = fn(self.variables, self.pool.caches,
+                                   self._tok, *args)
+            # the tick's input arrays go now, while the device runs: kept
+            # to the pass's return their frees cost 0.5 ms a tick AFTER
+            # the readback, with the device idle (PERF.md §6, PR 36)
+            del args
         self.pool.caches = caches
         self._tok = nxt
         self._keys = keys
-        nxt_host = np.asarray(nxt)
+        with self._phase_locked(stage["readback"], "readback"):
+            nxt_host = np.asarray(nxt)
         emitted = 0
-        for slot in active:
-            req = self._slot_req[slot]
-            self.pool.advance(slot)
-            self._emit(req, int(nxt_host[slot]))
-            emitted += 1
+        with self._phase_locked(stage["emit"], "emit"):
+            for slot in active:
+                req = self._slot_req[slot]
+                self.pool.advance(slot)
+                self._emit(req, int(nxt_host[slot]))
+                emitted += 1
         return emitted
 
     def _collect_proposals(self, active: List[int]) -> Dict[int, List[int]]:
@@ -2203,7 +2321,16 @@ class ServingEngine:
             d //= 2
         if d > cap:
             return None
+        with tracing.annotate(tracing.SPAN_TICK_VERIFY,
+                              proposals=len(props)):
+            return self._verify_pass(active, props, d)
+
+    def _verify_pass(self, active: List[int],
+                     props: Dict[int, List[int]], d: int) -> int:
+        n = self.pool.n_slots
+        S = self.max_seq
         tq = d + 1
+        stage = tracing.STAGE_SPANS[tracing.SPAN_TICK_VERIFY]
         pmat = np.full((n, d), self.pad_id, np.int32)
         plen = np.zeros((n,), np.int32)
         posv = np.zeros((n,), np.int32)
@@ -2220,75 +2347,83 @@ class ServingEngine:
                 pmat[slot, :m] = p[:m]
                 plen[slot] = m
         if self.paged:
-            blk = self.pool.block
-            null = self.pool.null_block
-            wblk = np.full((n, tq), null, np.int32)
-            woff = np.zeros((n, tq), np.int32)
-            for slot in active:
-                # span grant, best-effort: speculation must never evict
-                # prefix entries or preempt live requests just to hold
-                # guess-width — on exhaustion acceptance simply caps at
-                # the granted coverage (>= pos + 1, ensured above)
-                want = int(posv[slot]) + 1 + int(plen[slot])
-                try:
-                    self.pool.ensure_blocks(slot, min(want, S))
-                except BlocksExhaustedError:
-                    pass
-                table = self.pool.tables[slot].blocks
-                cov = len(table) * blk - int(posv[slot])
-                # a proposal whose acceptance would advance the cursor
-                # onto an ungranted (null-aimed, unwritten) position is
-                # clipped BEFORE the verify, so the in-program accept
-                # can never outrun the granted coverage
-                plen[slot] = min(int(plen[slot]), cov - 1)
-                for j in range(min(tq, cov)):
-                    p_ = int(posv[slot]) + j
-                    wblk[slot, j] = table[p_ // blk]
-                    woff[slot, j] = p_ % blk
-            if self.paged_kernel:
-                fn = self._paged_verify_fn(tq, None)
+            with self._phase_locked(stage["blocks"], "blocks"):
+                for slot in active:
+                    # span grant, best-effort: speculation must never
+                    # evict prefix entries or preempt live requests
+                    # just to hold guess-width — on exhaustion
+                    # acceptance simply caps at the granted coverage
+                    # (>= pos + 1, ensured by the decode pass's grant)
+                    want = int(posv[slot]) + 1 + int(plen[slot])
+                    try:
+                        self.pool.ensure_blocks(slot, min(want, S))
+                    except BlocksExhaustedError:
+                        pass
+        with self._phase_locked(stage["build"], "build"):
+            if self.paged:
+                blk = self.pool.block
+                null = self.pool.null_block
+                wblk = np.full((n, tq), null, np.int32)
+                woff = np.zeros((n, tq), np.int32)
+                for slot in active:
+                    table = self.pool.tables[slot].blocks
+                    cov = len(table) * blk - int(posv[slot])
+                    # a proposal whose acceptance would advance the
+                    # cursor onto an ungranted (null-aimed, unwritten)
+                    # position is clipped BEFORE the verify, so the
+                    # in-program accept can never outrun the granted
+                    # coverage
+                    plen[slot] = min(int(plen[slot]), cov - 1)
+                    for j in range(min(tq, cov)):
+                        p_ = int(posv[slot]) + j
+                        wblk[slot, j] = table[p_ // blk]
+                        woff[slot, j] = p_ % blk
+                if self.paged_kernel:
+                    fn = self._paged_verify_fn(tq, None)
+                else:
+                    hw = self._gather_hw(tq)
+                    self.metrics.bump(sm.GATHERED_BLOCKS, n * hw)
+                    fn = self._paged_verify_fn(tq, hw)
+                where = (self.pool.tables_device(), jnp.asarray(wblk),
+                         jnp.asarray(woff))
             else:
-                hw = self._gather_hw(tq)
-                self.metrics.bump(sm.GATHERED_BLOCKS, n * hw)
-                fn = self._paged_verify_fn(tq, hw)
-            out = fn(self.variables, self.pool.caches,
-                     jnp.asarray(pmat), jnp.asarray(plen),
-                     jnp.asarray(posv), jnp.asarray(mask), self._tok,
-                     self._keys, jnp.asarray(budget),
-                     self.pool.tables_device(), jnp.asarray(wblk),
-                     jnp.asarray(woff))
-        else:
-            # PREFILLING slots' masked garbage span aims at their
-            # cursor, same discipline as the one-token step (the span
-            # fits by the row cap above)
-            for slot in self._prefilling:
-                posv[slot] = self.pool.pos[slot]
-            fn = self._verify_fn(tq)
-            out = fn(self.variables, self.pool.caches,
-                     jnp.asarray(pmat), jnp.asarray(plen),
-                     jnp.asarray(posv), jnp.asarray(mask), self._tok,
-                     self._keys, jnp.asarray(budget))
+                # PREFILLING slots' masked garbage span aims at their
+                # cursor, same discipline as the one-token step (the
+                # span fits by the row cap above)
+                for slot in self._prefilling:
+                    posv[slot] = self.pool.pos[slot]
+                fn = self._verify_fn(tq)
+                where = ()
+            args = (jnp.asarray(pmat), jnp.asarray(plen),
+                    jnp.asarray(posv), jnp.asarray(mask), self._tok,
+                    self._keys, jnp.asarray(budget)) + where
+        with self._phase_locked(stage["launch"], "launch"):
+            out = fn(self.variables, self.pool.caches, *args)
+            del args, where     # as in _decode_pass: not at the return
         caches, self._tok, self._keys, tmat, m_emit, lead = out
         self.pool.caches = caches
         # ONE host transfer for everything the emit loop needs — three
         # separate np.asarray calls would block three times
-        tmat_h, me_h, lead_h = jax.device_get((tmat, m_emit, lead))
+        with self._phase_locked(stage["readback"], "readback"):
+            tmat_h, me_h, lead_h = jax.device_get((tmat, m_emit, lead))
         emitted = 0
         accepted = 0
-        for slot in active:
-            req = self._slot_req[slot]
-            if req is None:
-                continue
-            n_emit = int(me_h[slot])
-            accepted += int(lead_h[slot])
-            # cursor advances over EXACTLY the emitted tokens' inputs:
-            # accepted-but-truncated tokens (budget/EOS) advance
-            # nothing and are counted nowhere — the next-input token
-            # and key chain were already picked to match on device
-            self.pool.advance(slot, n_emit)
-            for tk in tmat_h[slot, :n_emit]:
-                self._emit(req, int(tk))
-                emitted += 1
+        with self._phase_locked(stage["emit"], "emit"):
+            for slot in active:
+                req = self._slot_req[slot]
+                if req is None:
+                    continue
+                n_emit = int(me_h[slot])
+                accepted += int(lead_h[slot])
+                # cursor advances over EXACTLY the emitted tokens'
+                # inputs: accepted-but-truncated tokens (budget/EOS)
+                # advance nothing and are counted nowhere — the
+                # next-input token and key chain were already picked to
+                # match on device
+                self.pool.advance(slot, n_emit)
+                for tk in tmat_h[slot, :n_emit]:
+                    self._emit(req, int(tk))
+                    emitted += 1
         self.metrics.bump(sm.DECODE_TICKS)
         self.metrics.bump(sm.SPEC_VERIFY_TICKS)
         self.metrics.bump(sm.SPEC_PROPOSED, int(plen.sum()))
@@ -2302,7 +2437,7 @@ class ServingEngine:
             req.t_first = now  # resumed request pre-seeds req.tokens)
         req.t_last = now
         req.tokens.append(tok)
-        req._out.put(tok)
+        req._out.put((tok, now))
         done = (len(req.tokens) >= req.max_new_tokens
                 or (self.eos_id is not None and tok == self.eos_id))
         if done:
@@ -2351,7 +2486,7 @@ class ServingEngine:
             n = len(req.tokens) - req._resumed_n
             tpot = ((req.t_last - req.t_first) / (n - 1) if n > 1 else None)
             self.metrics.observe_request(
-                queue_wait_s=req.t_admit - req.t_submit,
+                queue_wait_s=req.t_admit - req.t_enqueued,
                 ttft_s=req.t_first - req.t_submit, tpot_s=tpot, tokens=n)
         elif state is RequestState.FAILED:
             self.metrics.bump(sm.FAILED)
@@ -2391,7 +2526,10 @@ class ServingEngine:
                 return
             with self._wake:
                 if self._idle() and not self._stop_flag:
-                    self._wake.wait(timeout=0.05)
+                    # named, so that a device-idle gap with nothing to
+                    # do and one with the host busy read differently
+                    with tracing.annotate(tracing.SPAN_TICK_IDLE_WAIT):
+                        self._wake.wait(timeout=0.05)
 
     def _fail_all(self, exc: BaseException) -> None:
         with self._lock:

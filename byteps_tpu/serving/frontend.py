@@ -281,8 +281,12 @@ class _ServeHandler(socketserver.BaseRequestHandler):
         must stop serving this connection; the request is eagerly
         cancelled so its slot (and paged KV blocks) free this tick."""
         try:
-            for tok in req:
+            for tok, t_emit in req.stamped():
                 sock.sendall(_encode(0, "t", np.asarray([tok], np.int32)))
+                # the hand-off a client's token gap contains: the tick
+                # thread's _emit to this thread's frame on the wire
+                engine.metrics.observe("emit_to_wire",
+                                       time.monotonic() - t_emit)
             sock.sendall(_encode(0, "end",
                                  np.asarray(req.tokens, np.int32)))
             return True

@@ -20,6 +20,7 @@ import threading
 from typing import Dict, Optional
 
 from ..common import logging as bps_log
+from ..common.tracing import TICK_PHASES
 from ..observability.metrics import MetricsRegistry, get_registry
 
 # canonical counter names
@@ -91,6 +92,19 @@ PREFILL_CREDITS = "serve.prefill_credits"
 KV_BLOCKS_SHIPPED = "serve.kv_blocks_shipped"
 KV_BLOCKS_SHIPPED_BYTES = "serve.kv_blocks_shipped_bytes"
 SHIP_LATENCY_S = "serve.ship_latency_s"
+# the tick from inside (docs/timeline.md "Reading a tick"): cumulative
+# host seconds of the tick thread by phase (label ``phase``, one of
+# common/tracing.py:TICK_PHASES) and the ticks that had work, so that a
+# window's share is ``after - before`` of two STATS replies; the wait
+# for the engine lock at the top of submit() (what TTFT holds and the
+# queue wait does not) and the hand-off from _emit to the connection
+# thread's sendall returning.  Registry-only (``mirror=False``): ten
+# adds a tick on the Chrome timeline would be the flood _step_locked's
+# idle-tick comment warns of, and the host spans carry the detail.
+TICK_SECONDS = "serve.tick_seconds"
+TICKS_WORKED = "serve.ticks_worked"
+SUBMIT_LOCK_WAIT_S = "serve.submit_lock_wait_s"
+EMIT_TO_WIRE_S = "serve.emit_to_wire_s"
 
 
 class ServeMetrics:
@@ -102,7 +116,9 @@ class ServeMetrics:
     so scrapes see the serving engine live."""
 
     _HIST = {"queue_wait": QUEUE_WAIT_S, "ttft": TTFT_S, "tpot": TPOT_S,
-             "ship": SHIP_LATENCY_S}
+             "ship": SHIP_LATENCY_S,
+             "submit_lock_wait": SUBMIT_LOCK_WAIT_S,
+             "emit_to_wire": EMIT_TO_WIRE_S}
 
     def __init__(self, tracer=None,
                  registry: Optional[MetricsRegistry] = None):
@@ -112,6 +128,10 @@ class ServeMetrics:
         # exactly this instance's series even on a shared registry
         self._names: Dict[str, None] = {}
         self._lock = threading.Lock()
+        # the tick's counters, looked up once: eleven get-or-creates a
+        # tick were 0.08 ms of a 19.5 ms tick on the chip's host
+        # (PERF.md §6, PR 36); reset_serve_metrics() empties it
+        self._tick_counters: Dict[str, object] = {}
 
     @property
     def registry(self) -> MetricsRegistry:
@@ -158,6 +178,25 @@ class ServeMetrics:
             self.gauge(TPOT_MS, tpot_s * 1e3)
         self.bump(COMPLETED, tokens=tokens)
 
+    def observe(self, label: str, seconds: float) -> None:
+        """One sample of a ``_HIST`` histogram outside a request's
+        completion: ``submit_lock_wait``, ``emit_to_wire``."""
+        self._hist(label).observe(seconds)
+
+    def observe_tick_phases(self, seconds: Dict[str, float]) -> None:
+        """One working tick's host seconds by phase, onto the cumulative
+        ``serve.tick_seconds{phase}`` counters, and the tick itself."""
+        counters = self._tick_counters
+        if not counters:
+            for phase in seconds:
+                counters[phase] = self._registry.counter(
+                    TICK_SECONDS, track="serve", mirror=False, phase=phase)
+            counters[TICKS_WORKED] = self._registry.counter(
+                TICKS_WORKED, track="serve", mirror=False)
+        for phase, s in seconds.items():
+            counters[phase].inc(s)
+        counters[TICKS_WORKED].inc()
+
     # ------------------------------------------------------------ reporting
 
     def get(self, name: str) -> int:
@@ -172,7 +211,12 @@ class ServeMetrics:
     def summary(self) -> Dict[str, object]:
         """Counters plus latency percentiles (seconds)."""
         out: Dict[str, object] = dict(self.snapshot())
-        for label in ("queue_wait", "ttft", "tpot", "ship"):
+        out[TICKS_WORKED] = self.get(TICKS_WORKED)
+        out[TICK_SECONDS] = {
+            m.labels["phase"]: m.value
+            for m in (self._registry.get(TICK_SECONDS, phase=p)
+                      for p in TICK_PHASES) if m is not None}
+        for label in self._HIST:
             h = self._hist(label)
             out[f"{label}_p50_s"] = h.percentile(50)
             out[f"{label}_p99_s"] = h.percentile(99)
@@ -203,5 +247,6 @@ def reset_serve_metrics() -> None:
         inst, _metrics = _metrics, None
     if inst is not None:
         inst.registry.remove_prefix("serve.")
+        inst._tick_counters.clear()  # or they would count on, unseen
         for n in inst.snapshot():  # free-form names outside serve.*
             inst.registry.remove(n)
