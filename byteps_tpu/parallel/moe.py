@@ -24,9 +24,15 @@ result that its own experts give:
             and the tokens gathered into it.  The assignments of experts
             held elsewhere fall in a tail group that is never laid out.
   experts   grouped matrix products over the real tiles,
-            ``ops/grouped_matmul.py``: three for a gated expert (gate,
-            up, down; the gate's activation is SiLU or ReLU), two for a
-            non-gated one (``down(relu(up x)^2)``: no gate leaf).
+            ``ops/grouped_matmul.py``, two kernels forward whatever the
+            form: the first projection with its activation
+            (``grouped_matmul_act``: gate and up of a gated expert share
+            one pass over the row tile, the gate's SiLU or ReLU times up
+            on the float32 tile; a non-gated expert is the same kernel
+            with one matrix, ``relu(up x)^2``: no gate leaf), then down.
+            Backward the first projection is one kernel too (``dx``
+            summed over gate and up in it), beside a weight gradient a
+            matrix.  XLA runs nothing over the buffer between them.
   combine   each token sums its held assignments' rows by their weights.
 
 **No capacity factor and no dropped assignment.**  The buffer holds the
@@ -52,10 +58,23 @@ is dropped here either; a slot whose token did not choose the rank is
 zero and lays out no row.  On one rank there is no exchange and nothing
 stands in for it.
 
+**A layer made of kernels alone leaves XLA nothing to prefetch
+behind.**  A ``[rows, d]`` gather is five times faster when its ``[T,
+d]`` source lies in VMEM (``ops/grouped_matmul.py:_VMEM_LIMIT``), and
+XLA copies a source there only while an op of its OWN runs — never
+behind a Mosaic call.  So ``experts_ffn`` casts ``down`` after the first
+projection (``_in_order``) and ``_combine_bwd`` gathers after the
+recomputed forward: the cast is the op that hides the copy of ``dy``
+between a layer's two gathers, whose sources do not fit VMEM together.
+``moe.route_dispatch_ms_per_step`` rising by ~4 ms a layer is the sign
+that this was lost.
+
 Counters (``observability.metrics`` registry, beside ``flash.tiles_*``):
-gauges ``moe.rows_buffer``, ``moe.experts_held`` and ``moe.gathered_mb``
+gauges ``moe.rows_buffer``, ``moe.experts_held``, ``moe.gathered_mb``
 (label ``op`` = ``dispatch``, ``combine``, ``dispatch_bwd``,
-``combine_bwd``: the bytes that gather writes a call) are set when a
+``combine_bwd``: the bytes that gather writes a call) and
+``moe.expert_calls`` (label ``pass`` = ``fwd``, ``bwd``: the
+``pallas_call``s ``experts_ffn`` traces a pass) are set when a
 layer is traced; counters ``moe.assignments_held`` and ``moe.rows_computed``
 grow by a train step's metrics of those names, step by step
 (``training/step.py``): the first is what the router chose on held
@@ -65,7 +84,6 @@ assignment — equal unless something was dropped.
 
 from __future__ import annotations
 
-import functools
 from typing import NamedTuple, Optional, Tuple
 
 import jax
@@ -74,7 +92,7 @@ from jax import lax
 from jax.ad_checkpoint import checkpoint_name
 
 from ..observability.metrics import get_registry
-from ..ops.grouped_matmul import grouped_matmul
+from ..ops.grouped_matmul import grouped_matmul, grouped_matmul_act
 
 ROW_TILE = 256      # rows of a tile of the buffer: one expert each
 PLAN = "moe_plan"   # checkpoint name of the top-k choice and its layout
@@ -235,7 +253,11 @@ def _combine_bwd(res, dy):
     # in dy's dtype until the products that read it, and exactly zero on
     # the rows that hold no assignment: whatever those rows of the
     # buffer hold meets a zero in every weight gradient
-    g = _gather("combine_bwd", dy, p.tok)                 # [R, d]
+    # the gather waits for the recomputed forward (``rows`` is its last
+    # kernel's result), so that XLA can bring ``dy`` into VMEM after the
+    # dispatch gather's source has left it: module docstring
+    tok, rows = lax.optimization_barrier((p.tok, rows))
+    g = _gather("combine_bwd", dy, tok)                   # [R, d]
     w_row = jnp.where(p.valid, weights.reshape(-1)[p.src], 0.0)
     drows = (w_row[:, None] * g).astype(rows.dtype)
     dw_row = jnp.sum(jnp.where(p.valid[:, None],
@@ -250,21 +272,41 @@ combine.defvjp(_combine_fwd, _combine_bwd)
 # ------------------------------------------------------------ the layer
 
 
+@jax.custom_vjp
+def _in_order(first, then):
+    """Both, ``then`` not read before ``first`` is there (forward only)."""
+    return lax.optimization_barrier((first, then))
+
+
+_in_order.defvjp(lambda *both: (lax.optimization_barrier(both), None),
+                 lambda _, d: d)
+
+
 def experts_ffn(rows, gate, up, down, p: Plan, interpret=None,
                 act: str = "silu"):
     """The held experts' feed-forward over the row buffer: ``up
     [count, d, f]``, ``down [count, f, d]``.  Gated (``gate [count, d,
     f]``): ``act`` of the gate (``GATES``: ``silu`` SwiGLU, ``relu``
-    ReGLU) times up, then down — three products.  Non-gated (``gate``
-    None): ``act`` of up (``UNGATED``: ``relu2``), then down — two."""
-    mm = functools.partial(grouped_matmul, tile_group=p.tile_group,
-                           n_active=p.n_active, interpret=interpret)
+    ReGLU) times up, then down.  Non-gated (``gate`` None): ``act`` of
+    up (``UNGATED``: ``relu2``), then down.  Either way two kernels
+    forward (``grouped_matmul_act``, ``grouped_matmul``); backward one
+    for the first projection, a weight gradient a matrix and down's
+    ``dx``: the gauge ``moe.expert_calls``."""
+    where = dict(tile_group=p.tile_group, n_active=p.n_active,
+                 interpret=interpret)
     if gate is None:
-        h = UNGATED[act](mm(rows, up.astype(rows.dtype)))
+        ws, fn = (up,), UNGATED[act]
     else:
-        h = GATES[act](mm(rows, gate.astype(rows.dtype))) * mm(
-            rows, up.astype(rows.dtype))
-    return mm(h, down.astype(rows.dtype))
+        ws, fn = (gate, up), GATES[act]
+    for pass_, calls in (("fwd", 2), ("bwd", 3 + len(ws))):
+        get_registry().gauge("moe.expert_calls", **{"pass": pass_}).set(
+            calls)
+    h = grouped_matmul_act(rows, tuple(w.astype(rows.dtype) for w in ws),
+                           act=fn, **where)
+    # down's cast after the first projection: the one XLA op between a
+    # layer's two gathers (module docstring)
+    h, down = _in_order(h, down)
+    return grouped_matmul(h, down.astype(rows.dtype), **where)
 
 
 def served(idx, first, count: int, p: Plan):

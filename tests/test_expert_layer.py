@@ -5,6 +5,7 @@ on seeded weights at a small size on the CPU.
 """
 
 import dataclasses
+import functools
 import os
 import sys
 
@@ -22,7 +23,7 @@ from byteps_tpu.models.transformer import (LatentAttention, Transformer,
                                            TransformerConfig, apply_rope)
 from byteps_tpu.observability.metrics import get_registry
 from byteps_tpu.ops.flash_attention import flash_attention
-from byteps_tpu.ops.grouped_matmul import grouped_matmul
+from byteps_tpu.ops.grouped_matmul import grouped_matmul, grouped_matmul_act
 from byteps_tpu.parallel import moe
 from byteps_tpu.parallel.collectives import shard_map
 from byteps_tpu.parallel.ring_attention import local_attention
@@ -374,6 +375,94 @@ def test_grouped_matmul_skips_inactive_tiles_and_matches_per_group():
     np.testing.assert_allclose(dw, rw, rtol=1e-5, atol=1e-5)
 
 
+FIRST_FORMS = {"silu": 2, "relu": 2, "relu2": 1}     # matrices a form
+
+
+@pytest.mark.parametrize("act", list(FIRST_FORMS))
+def test_first_projection_pair_matches_per_group(act):
+    """``grouped_matmul_act`` and its backward kernel against the
+    per-group ``jnp`` products: ``h``, ``dx``, every ``dw`` and the
+    stored ``dg`` / ``du`` on the active tiles — with inactive tiles, a
+    group that holds no assignment (one tile, as the layout gives it)
+    and a large finite value in every row that holds none."""
+    gm = sys.modules["byteps_tpu.ops.grouped_matmul"]
+    G, tm, Kd, N = 4, 8, 16, 24
+    tile_group = jnp.array([0, 0, 1, 2, 3, 3, 3, 3], jnp.int32)
+    active = 6                       # tiles 6 and 7 hold no real row
+    real = np.array([8, 8, 5, 0, 8, 3, 0, 0])     # rows a tile that do
+    valid = jnp.asarray((np.arange(tm)[None, :] < real[:, None]).reshape(-1))
+    k = jax.random.split(jax.random.PRNGKey(24), 4)
+    x = jnp.where(valid[:, None], jax.random.normal(k[0], (8 * tm, Kd)), 1e3)
+    ws = tuple(0.3 * jax.random.normal(kk, (G, Kd, N))
+               for kk in jax.random.split(k[1], FIRST_FORMS[act]))
+    dh = jnp.where(valid[:, None], jax.random.normal(k[2], (8 * tm, N)), 0)
+    fn = moe.UNGATED[act] if len(ws) == 1 else moe.GATES[act]
+    live = slice(0, active * tm)
+
+    def epilogue(*pre):
+        return fn(pre[0]) * pre[1] if len(pre) == 2 else fn(pre[0])
+
+    def want(x, ws):
+        pre = [jnp.concatenate([x[i * tm:(i + 1) * tm] @ w[tile_group[i]]
+                                for i in range(active)]) for w in ws]
+        return epilogue(*pre), pre
+
+    with jax.default_matmul_precision("highest"):
+        h, vjp = jax.vjp(lambda x, ws: grouped_matmul_act(
+            x, ws, tile_group, jnp.int32(active), fn), x, ws)
+        dx, dws = vjp(dh)
+        (rh, pre), rvjp = jax.vjp(want, x, ws)
+        rdx, rdws = rvjp((dh[live], [jnp.zeros_like(a) for a in pre]))
+        # the stored products and their gradients, as the kernels write
+        # them for the weight-gradient products
+        got_h, *got_pre = gm._act_fwd_pass(
+            x, ws, tile_group, jnp.int32(active), fn, True, None)
+        *dpre, dx2 = gm._act_bwd_pass(
+            dh, got_pre, ws, tile_group, jnp.int32(active), fn, None)
+        rdpre = jax.vjp(epilogue, *pre)[1](dh[live])
+    close = functools.partial(np.testing.assert_allclose, rtol=1e-5,
+                              atol=1e-3)       # beside rows of 1e3
+    close(h[live], rh)
+    close(got_h[live], rh)
+    close(dx[live], rdx[live])
+    close(dx2[live], rdx[live])
+    assert len(dws) == len(ws) == len(dpre)
+    for got, ref_ in zip(list(dws) + got_pre + dpre,
+                         list(rdws) + pre + list(rdpre)):
+        got = got if got.ndim == 3 else got[live]
+        close(got, ref_)
+    for dw in dws:                   # the group that holds no assignment
+        assert np.any(dw[0]) and not np.any(dw[2])
+    for d in dpre:                   # exactly zero where dh is
+        assert not np.any(np.asarray(d[live])[~np.asarray(valid[live])])
+
+
+def test_the_activation_is_looked_up_when_the_layer_is_traced(monkeypatch):
+    """One place holds ``act``: a stand-in planted in ``moe.UNGATED``
+    after a first call changes what the next computes, forward and
+    backward (the call builders are keyed by the function, not by its
+    name)."""
+    k = jax.random.split(jax.random.PRNGKey(25), 3)
+    x = jax.random.normal(k[0], (TOKENS, D))
+    up = 0.3 * jax.random.normal(k[1], (4, D, F))
+    down = 0.3 * jax.random.normal(k[2], (4, F, D))
+    idx = jnp.tile(jnp.arange(4), (TOKENS, 1))[:, :2]
+    p = moe.plan(idx, 0, 4, 8)
+
+    def run():
+        return jax.value_and_grad(lambda up: jnp.sum(jnp.sin(moe.experts_ffn(
+            moe.dispatch(x, p), None, up, down, p, act="relu2")[
+                p.dest.reshape(-1)])))(up)
+
+    first = run()
+    monkeypatch.setitem(moe.UNGATED, "relu2", jax.nn.relu)
+    planted = run()
+    assert abs(float(first[0]) - float(planted[0])) > 1e-3
+    assert not np.allclose(first[1], planted[1], atol=1e-3)
+    monkeypatch.setitem(moe.UNGATED, "relu2", moe.relu2)
+    np.testing.assert_allclose(run()[1], first[1], rtol=1e-6)
+
+
 def test_layer_counters_are_in_the_registry():
     w = layer_weights(17)
     x = jax.random.normal(jax.random.PRNGKey(18), (40, D))
@@ -521,12 +610,21 @@ def _eqns(jaxpr):
                     yield from _eqns(sub)
 
 
+def _calls(eqns):
+    return sum(e.primitive.name == "pallas_call" for e in eqns)
+
+
 def test_no_token_major_fold_and_no_split_by_tokens():
     """Abstract shapes, nothing runs.  At ``k = 6`` no intermediate of
     ``value_and_grad`` of a layer is ``[T, k, d]`` (the chip pads ``k``
     to a tile's sublanes there), and the layer lowers as many
     ``pallas_call``s at 16 384 tokens as at 512: no shape makes it run
-    in token chunks, which lengthens the step program and its set-up."""
+    in token chunks, which lengthens the step program and its set-up.
+    And nothing but kernels stands between the first projection's
+    kernels: no equation outside a ``pallas_call`` writes an ``[R, f]``
+    array (the activation and its derivative ride in the kernels), and
+    no ``add_any`` an ``[R, d]`` one (``dx`` is summed over gate and up
+    in the backward kernel)."""
     k, count, f = 6, 16, 32
     calls = {}
     for T in (512, 16384):
@@ -546,8 +644,44 @@ def test_no_token_major_fold_and_no_split_by_tokens():
         eqns = list(_eqns(jaxpr.jaxpr))
         shapes = {v.aval.shape for e in eqns for v in e.outvars}
         assert (k, T, D) in shapes and (T, k, D) not in shapes
-        calls[T] = sum(e.primitive.name == "pallas_call" for e in eqns)
-    assert calls[512] == calls[16384] == 9     # 5 products + 4 backward
+        calls[T] = _calls(eqns)
+        R = moe.buffer_rows(T, k, count, moe.ROW_TILE)
+        wrote = lambda shape: sorted({  # noqa: E731
+            e.primitive.name for e in eqns
+            if any(v.aval.shape == shape for v in e.outvars)})
+        # (a barrier orders its operands and moves nothing)
+        assert set(wrote((R, f))) - {"optimization_barrier"} == {
+            "pallas_call"}
+        assert "add_any" not in wrote((R, D)) and "pallas_call" in wrote(
+            (R, D))
+    assert calls[512] == calls[16384] == 7     # 2 forward + 5 backward
+
+
+@pytest.mark.parametrize("form", list(FORMS))
+def test_expert_calls_gauge_is_what_a_layer_traces(form):
+    """``moe.expert_calls{pass}``: the ``pallas_call``s of one layer,
+    forward and backward, as its jaxprs count them (nothing runs)."""
+    k, count, act, scoring = FORMS[form]
+    w = form_weights(count, act)
+    e = w["experts"]
+
+    def layer(x, e):
+        return moe.expert_layer(
+            x, w["kernel"], None, e.get("gate"), e["up"], e["down"],
+            top_k=k, scale=2.5, held=(FIRST, count), tile=8,
+            scoring=scoring, act=act)[0].sum()
+
+    x = jnp.zeros((TOKENS, D))
+    reg = get_registry()
+    for pass_ in ("fwd", "bwd"):
+        reg.gauge("moe.expert_calls", **{"pass": pass_}).set(0)
+    fwd = _calls(_eqns(jax.make_jaxpr(layer)(x, e).jaxpr))
+    both_ = _calls(_eqns(jax.make_jaxpr(jax.value_and_grad(
+        layer, argnums=(0, 1)))(x, e).jaxpr))
+    got = {pass_: reg.get("moe.expert_calls", **{"pass": pass_}).value
+           for pass_ in ("fwd", "bwd")}
+    assert got == {"fwd": fwd, "bwd": both_ - fwd}
+    assert got == {"fwd": 2, "bwd": 4 if "gate" not in e else 5}
 
 
 # ------------------------------------------- the whole model and the step
