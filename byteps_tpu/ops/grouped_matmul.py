@@ -1,4 +1,5 @@
-"""Pallas TPU grouped matrix product over rows sorted by group.
+"""Pallas TPU grouped matrix product over rows sorted by group, and the
+gathers that fill and empty its buffer when their source lies in HBM.
 
 The expert layer (``parallel/moe.py``) lays the rows routed to its
 experts out group after group in one static buffer, every group starting
@@ -39,6 +40,15 @@ assignment (their ``dy`` is zero).
 
 No capacity, no drop: the buffer is sized for the worst routing and the
 cost follows the tiles that are real.
+
+  * ``row_gather(source [T, d], tok [R], n_active, tile) -> [R, d]`` and
+    ``sum_gather(rows [R, d], dest, held [T, k], n_active, weights) ->
+    [T, d]`` — the expert layer's gathers where XLA cannot keep the
+    source in VMEM: each wanted row moves by ONE DMA (the rows of the
+    active tiles; a token's held rows, summed in float32 in VMEM), the
+    others not at all.  The comment above ``dma_rows`` says how a row
+    is made to move alone; ``parallel/moe.py:from_hbm`` picks them by
+    shape.
 """
 
 from __future__ import annotations
@@ -63,14 +73,55 @@ from ._pallas_utils import resolve_interpret
 # whole ``[T, d]`` source in VMEM only if both fit the chip's 128 MiB —
 # 84 MB at 16 384 x 2560 bf16 beside 64 MiB do not, and the gather then
 # reads HBM row by row: 4.7 ms where 0.8 (v5e, one layer, PR 35).
-_VMEM_FLOOR, _VMEM_LIMIT = 16 * 1024 * 1024, 64 * 1024 * 1024
+_VMEM_FLOOR, VMEM_LIMIT = 16 * 1024 * 1024, 64 * 1024 * 1024
+
+
+def _limit(counted: int) -> int:
+    return min(VMEM_LIMIT, max(_VMEM_FLOOR, counted + counted // 4))
 
 
 def _params(counted: int):
-    return pltpu.CompilerParams(
-        dimension_semantics=("arbitrary",),
-        vmem_limit_bytes=min(_VMEM_LIMIT, max(_VMEM_FLOOR,
-                                              counted + counted // 4)))
+    return pltpu.CompilerParams(dimension_semantics=("arbitrary",),
+                                vmem_limit_bytes=_limit(counted))
+
+
+# VMEM each call's blocks count to: every block double-buffered, and the
+# float32 values the body holds beside them
+def _gmm_bytes(K, N, tm, dtype):
+    # the float32 product before its cast
+    return 2 * (tm * K + K * N + tm * N) * jnp.dtype(dtype).itemsize \
+        + 4 * tm * N
+
+
+def _dw_bytes(K, N, tm, dtype):
+    # the accumulator and the tile's product on its way into it
+    return 2 * (tm * K + tm * N + K * N) * jnp.dtype(dtype).itemsize \
+        + 8 * K * N
+
+
+def _act_bytes(K, N, tm, dtype, n_w, n_out):
+    # the products and h
+    return 2 * (tm * K + n_w * K * N + n_out * tm * N) \
+        * jnp.dtype(dtype).itemsize + 4 * (n_w + 1) * tm * N
+
+
+def _act_bwd_bytes(K, N, tm, dtype, n_w):
+    # dh, the stored products and their gradients, and dx with the
+    # product on its way into it
+    return 2 * (tm * K + n_w * K * N + (1 + 2 * n_w) * tm * N) \
+        * jnp.dtype(dtype).itemsize + 4 * (1 + 2 * n_w) * tm * N \
+        + 8 * tm * K
+
+
+def scoped_vmem(d: int, f: int, tm: int, dtype, n_w: int) -> int:
+    """The most scoped VMEM any call of an expert layer asks for — the
+    first projection (``n_w`` matrices ``[d, f]``) each way, down each
+    way and the weight gradients — at row tile ``tm``."""
+    return max(_limit(b) for b in (
+        _act_bytes(d, f, tm, dtype, n_w, 1 + n_w),
+        _act_bwd_bytes(d, f, tm, dtype, n_w), _dw_bytes(d, f, tm, dtype),
+        _gmm_bytes(f, d, tm, dtype), _gmm_bytes(d, f, tm, dtype),
+        _dw_bytes(f, d, tm, dtype)))
 
 
 def _row(i, tile_group, n_active):
@@ -109,10 +160,7 @@ def _gmm_call(R, K, N, tm, dtype, transpose_w, interpret):
                                    _group)],
             out_specs=pl.BlockSpec((tm, N), _row)),
         out_shape=jax.ShapeDtypeStruct((R, N), dtype),
-        # both operands' blocks and the result's, double-buffered, and
-        # the float32 product before its cast
-        compiler_params=_params(2 * (tm * K + K * N + tm * N)
-                                * jnp.dtype(dtype).itemsize + 4 * tm * N),
+        compiler_params=_params(_gmm_bytes(K, N, tm, dtype)),
         interpret=interpret,
     )
 
@@ -156,10 +204,7 @@ def _dw_call(R, K, N, G, tm, dtype, interpret):
             out_specs=pl.BlockSpec((1, K, N), _group),
             scratch_shapes=[pltpu.VMEM((K, N), jnp.float32)]),
         out_shape=jax.ShapeDtypeStruct((G, K, N), dtype),
-        # as above, and two float32 [K, N]: the accumulator and the
-        # tile's product on its way into it
-        compiler_params=_params(2 * (tm * K + tm * N + K * N)
-                                * jnp.dtype(dtype).itemsize + 8 * K * N),
+        compiler_params=_params(_dw_bytes(K, N, tm, dtype)),
         interpret=interpret,
     )
 
@@ -253,10 +298,7 @@ def _act_call(R, K, N, tm, dtype, act, n_w, residuals, interpret):
             + [pl.BlockSpec((1, K, N), _group)] * n_w,
             out_specs=[pl.BlockSpec((tm, N), _row)] * n_out),
         out_shape=[jax.ShapeDtypeStruct((R, N), dtype)] * n_out,
-        # every block double-buffered; in float32 the products and h
-        compiler_params=_params(
-            2 * (tm * K + n_w * K * N + n_out * tm * N)
-            * jnp.dtype(dtype).itemsize + 4 * (n_w + 1) * tm * N),
+        compiler_params=_params(_act_bytes(K, N, tm, dtype, n_w, n_out)),
         interpret=interpret,
     )
 
@@ -295,13 +337,7 @@ def _act_bwd_call(R, K, N, tm, dtype, act, n_w, interpret):
             out_specs=[tile] * n_w + [pl.BlockSpec((tm, K), _row)]),
         out_shape=[jax.ShapeDtypeStruct((R, N), dtype)] * n_w
         + [jax.ShapeDtypeStruct((R, K), dtype)],
-        # every block double-buffered; in float32 dh, the stored
-        # products and their gradients, and dx with the product on its
-        # way into it
-        compiler_params=_params(
-            2 * (tm * K + n_w * K * N + (1 + 2 * n_w) * tm * N)
-            * jnp.dtype(dtype).itemsize
-            + 4 * (1 + 2 * n_w) * tm * N + 8 * tm * K),
+        compiler_params=_params(_act_bwd_bytes(K, N, tm, dtype, n_w)),
         interpret=interpret,
     )
 
@@ -354,3 +390,257 @@ def _act_bwd(act, interpret, res, dh):
 
 
 grouped_matmul_act.defvjp(_act_fwd, _act_bwd)
+
+
+# ---------------------------------------------- rows moved from HBM by DMA
+#
+# Mosaic slices an HBM array for a DMA at whole tiles of its layout
+# only, and XLA lays an ``[N, d]`` array in tiles of eight 32-bit
+# sublane rows (sixteen bf16 rows): one row cannot move alone.  A row of
+# ``[N, p, d / p]`` can (its tile is the row).  So each gather runs in two
+# phases over one grid.  Phase one walks the source's tiles that can
+# hold a wanted row (every tile of ``[T, d]``; the active tiles of the
+# buffer) through VMEM and writes them to an HBM copy laid ``[N, p, d /
+# p]`` — row ``n`` whole sublane rows, ``p = 4 / itemsize`` (a 16-bit
+# row travels as its two halves packed together).  Phase two moves each
+# wanted row of that copy by ONE DMA.  Every loop over rows, tokens and
+# ``k`` is a rolled ``pl.loop`` on indices in SMEM, so a kernel lowers
+# to the same few kilobytes whatever its tile or ``k``.
+
+
+def dma_rows(d: int, dtype) -> int:
+    """Lanes a row of ``[N, d]`` is cut into to move alone (``d / p``),
+    or 0 where it cannot: another width than 16 or 32 bits, or a part
+    that is not a whole number of 128-lane tiles."""
+    size = jnp.dtype(dtype).itemsize
+    if size not in (2, 4) or d % (128 * (4 // size)):
+        return 0
+    return d // (4 // size)
+
+
+def _parts(buf, at):
+    """``(columns, [n, d / p])`` a part: the ``[n, p, d / p]`` rows of
+    ``buf`` (``at``: leading indices) back in the row's columns."""
+    per, h = buf.shape[-2:]
+    return [(slice(a * h, (a + 1) * h), buf[(*at, slice(None), a)])
+            for a in range(per)]
+
+
+def _relay(tile_ref, lay, copy_ref, first, sem):
+    """Phase one, a tile: ``tile_ref [tm, d]`` laid ``[tm, p, d / p]`` in
+    VMEM (``lay``), then written to rows ``first ...`` of the HBM copy."""
+    per, h = lay.shape[1:]
+    for a in range(per):
+        lay[:, a, :] = tile_ref[:, a * h:(a + 1) * h]
+    dma = pltpu.make_async_copy(
+        lay, copy_ref.at[pl.ds(first, lay.shape[0])], sem)
+    dma.start()
+    dma.wait()
+
+
+def _scratch(N, d, tm, dtype):
+    """The HBM copy (an output nobody reads) and the VMEM it passes."""
+    per = 4 // jnp.dtype(dtype).itemsize
+    return (jax.ShapeDtypeStruct((N, per, d // per), dtype),
+            pltpu.VMEM((tm, per, d // per), dtype))
+
+
+def _row_gather_kernel(n_active_ref, tok_ref, src_ref, o_ref, copy_ref,
+                       lay, buf, sem):
+    i, tm = pl.program_id(0), buf.shape[0]
+    src_tiles = copy_ref.shape[0] // tm
+    j = i - src_tiles
+
+    @pl.when(i < src_tiles)
+    def _():
+        _relay(src_ref, lay, copy_ref, i * tm, sem)
+
+    @pl.when((j >= 0) & (j < n_active_ref[0]))
+    def _():
+        @pl.loop(0, tm)
+        def _(r):
+            pltpu.make_async_copy(copy_ref.at[tok_ref[0, r]], buf.at[r],
+                                  sem).start()
+
+        # a DMA semaphore counts bytes: one wait for the whole tile
+        pltpu.make_async_copy(buf, buf, sem).wait()
+        for cols, part in _parts(buf, ()):
+            o_ref[:, cols] = part
+
+
+@functools.lru_cache(maxsize=64)
+def _row_gather_call(T, R, d, tm, dtype, interpret):
+    src_tiles = T // tm
+
+    def src(i, n_active):
+        return (jnp.minimum(i, src_tiles - 1), 0)
+
+    def row(i, n_active):      # phase one holds tile 0, writing nothing
+        return (jnp.clip(i - src_tiles, 0, n_active[0] - 1), 0)
+
+    copy, lay = _scratch(T, d, tm, dtype)
+    return pl.pallas_call(
+        _row_gather_kernel,
+        name="row_gather",
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(src_tiles + R // tm,),
+            # the tile's row indices in SMEM a tile at a time: all of
+            # them would not fit its 1 MiB at every size
+            in_specs=[pl.BlockSpec((None, 1, tm),
+                                   lambda i, n: (*row(i, n), 0),
+                                   memory_space=pltpu.SMEM),
+                      pl.BlockSpec((tm, d), src)],
+            out_specs=[pl.BlockSpec((tm, d), row),
+                       pl.BlockSpec(memory_space=pl.ANY)],
+            scratch_shapes=[lay, lay, pltpu.SemaphoreType.DMA(())]),
+        out_shape=[jax.ShapeDtypeStruct((R, d), dtype), copy],
+        # the source's tile and the result's, double-buffered, the two
+        # laid tiles, and what Mosaic holds of a tile on its way between
+        # the layouts (17.5 MB in all at 256 x 2560 bf16: the v5e
+        # compiler's count)
+        compiler_params=_params(14 * tm * d * jnp.dtype(dtype).itemsize),
+        interpret=interpret,
+    )
+
+
+def row_gather(source, tok, n_active, tile: int, interpret=None):
+    """``[R, d]``: row ``r`` is ``source[tok[r]]`` (``tok [R]``) on the
+    first ``n_active`` row tiles of ``tile`` rows, each row ONE DMA from
+    the source's copy in HBM; the rows of the other tiles are left
+    unwritten.  ``source [T, d]`` needs ``dma_rows``, ``T`` a multiple
+    of ``tile``."""
+    interpret = resolve_interpret(interpret, "row_gather")
+    (T, d), R = source.shape, tok.shape[0]
+    return _row_gather_call(T, R, d, tile, source.dtype, interpret)(
+        jnp.reshape(n_active, (1,)).astype(jnp.int32),
+        tok.reshape(R // tile, 1, tile).astype(jnp.int32), source)[0]
+
+
+def _sum_gather_kernel(n_active_ref, row_ref, n_ref, tot_ref, rows_ref,
+                       m_ref, *refs, weighted):
+    w_ref = refs[0] if weighted else None
+    o_ref, copy_ref, lay, buf, acc, sem = refs[-6:]
+    i, tm = pl.program_id(0), lay.shape[0]
+    k, tt = buf.shape[0], buf.shape[1]
+    j = i - copy_ref.shape[0] // tm
+
+    @pl.when(i < n_active_ref[0])
+    def _():
+        _relay(rows_ref, lay, copy_ref, i * tm, sem)
+
+    @pl.when(j >= 0)
+    def _():
+        # token t's held rows, in the order j = 0 ... k - 1, to slots
+        # 0 ... n_t - 1: no loop step for a row not held
+        @pl.loop(0, tt)
+        def _(t):
+            @pl.loop(0, n_ref[0, t])
+            def _(q):
+                pltpu.make_async_copy(copy_ref.at[row_ref[0, t * k + q]],
+                                      buf.at[q, t], sem).start()
+
+        # a DMA semaphore counts bytes: one wait per eight rows moved
+        eight, one = buf.at[0, pl.ds(0, 8)], buf.at[0, pl.ds(0, 1)]
+        total = tot_ref[0, 0]
+
+        @pl.loop(0, total // 8)
+        def _(_):
+            pltpu.make_async_copy(eight, eight, sem).wait()
+
+        @pl.loop(0, total % 8)
+        def _(_):
+            pltpu.make_async_copy(one, one, sem).wait()
+
+        # in float32, slot by slot; a slot past n_t adds nothing (it
+        # holds what an earlier tile left there: a select keeps it out)
+        acc[...] = jnp.zeros(acc.shape, jnp.float32)
+        lanes = lax.broadcasted_iota(jnp.int32, (tt, k), 1)
+
+        @pl.loop(0, k)
+        def _(q):
+            pick = lanes == q
+            held = jnp.max(jnp.where(pick, m_ref[...], 0), axis=1,
+                           keepdims=True) > 0
+            if weighted:
+                w = jnp.sum(jnp.where(pick, w_ref[...], 0.0), axis=1,
+                            keepdims=True)
+            for cols, part in _parts(buf, (q,)):
+                term = jnp.where(held, part.astype(jnp.float32), 0.0)
+                acc[:, cols] += term * w if weighted else term
+
+        o_ref[...] = acc[...].astype(o_ref.dtype)
+
+
+@functools.lru_cache(maxsize=64)
+def _sum_gather_call(T, R, d, k, tm, dtype, weighted, interpret):
+    row_tiles, tt = R // tm, tm
+
+    def rows(i, n_active):     # phase two holds the last, moving nothing
+        return (jnp.minimum(i, n_active[0] - 1), 0)
+
+    def tok(i, n_active):      # phase one holds tile 0, writing nothing
+        return (jnp.maximum(i - row_tiles, 0), 0)
+
+    def smem(shape):
+        return pl.BlockSpec((None, 1, shape), lambda i, n: (*tok(i, n), 0),
+                            memory_space=pltpu.SMEM)
+
+    copy, lay = _scratch(R, d, tm, dtype)
+    per = 4 // jnp.dtype(dtype).itemsize
+    return pl.pallas_call(
+        functools.partial(_sum_gather_kernel, weighted=weighted),
+        name="sum_gather",
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(row_tiles + T // tt,),
+            # the token tile's held rows, their counts and its total in
+            # SMEM a tile at a time (as in row_gather)
+            in_specs=[smem(tt * k), smem(tt), smem(1),
+                      pl.BlockSpec((tm, d), rows)]
+            + [pl.BlockSpec((tt, k), tok)] * (1 + weighted),
+            out_specs=[pl.BlockSpec((tt, d), tok),
+                       pl.BlockSpec(memory_space=pl.ANY)],
+            scratch_shapes=[lay,
+                            pltpu.VMEM((k, tt, per, d // per), dtype),
+                            pltpu.VMEM((tt, d), jnp.float32),
+                            pltpu.SemaphoreType.DMA(())]),
+        out_shape=[jax.ShapeDtypeStruct((T, d), dtype), copy],
+        # the buffer's tile double-buffered and laid (with row_gather's
+        # allowance for its way between the layouts), k rows a token,
+        # the result's block double-buffered, the float32 sum and a term
+        # on its way into it, and the [tt, k] blocks (a lane tile wide)
+        compiler_params=_params(
+            (7 * tm + (k + 2) * tt) * d * jnp.dtype(dtype).itemsize
+            + 8 * tt * d + 2 * (1 + weighted) * tt * 128 * 4),
+        interpret=interpret,
+    )
+
+
+def sum_gather(rows, dest, held, n_active, weights=None, tile: int = 256,
+               interpret=None):
+    """``[T, d]`` in ``rows``' dtype: token ``t``'s held rows ``rows[dest[t,
+    j]]`` (``held [T, k]``), times ``weights [T, k]`` where given, summed
+    in float32 in the order ``j = 0 ... k - 1`` and cast once — each row
+    ONE DMA from the copy of the first ``n_active`` row tiles of the
+    buffer (``rows [R, d]``, tiles of ``tile`` rows: every held row lies
+    there) in HBM, a row not held neither moved nor read.  Needs
+    ``dma_rows``; ``T`` a multiple of ``tile``."""
+    interpret = resolve_interpret(interpret, "sum_gather")
+    (T, k), (R, d) = dest.shape, rows.shape
+    tiles = T // tile
+    # token t's held rows first, in their order: [T, j, q]
+    into = ((jnp.cumsum(held, axis=1) - 1)[:, :, None] == jnp.arange(k)) \
+        & held[:, :, None]
+    n = jnp.sum(held, axis=1, dtype=jnp.int32)
+
+    def packed(a):
+        return jnp.sum(jnp.where(into, a[:, :, None], 0), axis=1)
+
+    m = (jnp.arange(k) < n[:, None]).astype(jnp.int32)
+    extra = () if weights is None else (packed(weights.astype(jnp.float32)),)
+    return _sum_gather_call(T, R, d, k, tile, rows.dtype, weights is not None,
+                            interpret)(
+        jnp.reshape(n_active, (1,)).astype(jnp.int32),
+        packed(dest).astype(jnp.int32).reshape(tiles, 1, tile * k),
+        n.reshape(tiles, 1, tile),
+        jnp.sum(n.reshape(tiles, tile), axis=1).reshape(tiles, 1, 1),
+        rows, m, *extra)[0]

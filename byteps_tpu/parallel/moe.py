@@ -40,12 +40,29 @@ worst routing — ``tokens x min(k, count)`` rows plus a tile of slack a
 group — and the products' cost follows the tiles that are real.  The
 dispatch and the combine are gathers in both directions (an assignment
 knows its row and a row its assignment), each with the other as its
-backward pass; nothing scatters.  Each of the four is ONE pass over the
-rows it writes: a row that holds no assignment repeats token 0 (no
-mask follows the gather: its gradient is zero, so nothing reads it),
-and a token's ``k`` rows are gathered ``[k, T, d]`` — laid ``[T, k, d]``
-the chip pads ``k`` to a tile's 8 or 16 sublanes, a copy of two to
-three times the real bytes at ``k = 6``.
+backward pass; nothing scatters.  Each of the four is ONE call over the
+rows it writes: ``dispatch`` and ``combine_bwd`` write ``[R, d]`` from a
+``[T, d]`` source by ``Plan.tok`` (a row that holds no assignment
+repeats token 0: no mask follows the gather, its gradient is zero, so
+nothing reads it); ``combine`` and ``dispatch_bwd`` write ``[T, d]``,
+each token's held rows of the ``[R, d]`` buffer summed in float32 in
+the order ``j = 0 ... k - 1``.
+
+**The shape rule picks how the rows move** (``from_hbm``).  Where the
+source fits the VMEM that the layer's kernels leave (128 MiB less the
+most scoped VMEM one of them asks for, ``ops/grouped_matmul.py:
+scoped_vmem``), XLA gathers and reads it there (0.8 ms a ``[T, d]``
+gather at 16 384 x 2560 on the v5e); the sum side lays a token's ``k`` rows
+``[k, T, d]`` (laid ``[T, k, d]`` the chip pads ``k`` to 8 or 16
+sublanes).  Where it does not — the row buffer always, a ``[T, d]``
+source of 134 MB at LFM2-8B-A1B's 4 x 8192 tokens — XLA would read HBM
+row by row (4.5 ms a gather there), and the gather is a kernel
+(``ops/grouped_matmul.py:row_gather`` / ``sum_gather``): the source's
+tiles that can hold a wanted row pass through VMEM once, and each
+wanted row then moves by ONE DMA — the active tiles' rows, a token's
+held rows, no more.  A width whose half-row is not whole lanes (d 2688)
+stays with XLA.  The gauge ``moe.gather_kernel{op}`` says which way a
+traced layer took.
 
 With ``axis_name`` the layer is expert parallel across that mesh axis:
 a rank routes ITS OWN tokens once, sends each to the ranks that hold
@@ -59,20 +76,25 @@ zero and lays out no row.  On one rank there is no exchange and nothing
 stands in for it.
 
 **A layer made of kernels alone leaves XLA nothing to prefetch
-behind.**  A ``[rows, d]`` gather is five times faster when its ``[T,
-d]`` source lies in VMEM (``ops/grouped_matmul.py:_VMEM_LIMIT``), and
-XLA copies a source there only while an op of its OWN runs — never
+behind.**  An XLA ``[rows, d]`` gather is five times faster when its
+``[T, d]`` source lies in VMEM (``ops/grouped_matmul.py:VMEM_LIMIT``),
+and XLA copies a source there only while an op of its OWN runs — never
 behind a Mosaic call.  So ``experts_ffn`` casts ``down`` after the first
 projection (``_in_order``) and ``_combine_bwd`` gathers after the
 recomputed forward: the cast is the op that hides the copy of ``dy``
 between a layer's two gathers, whose sources do not fit VMEM together.
+The sum side's kernel (where the rule picks it) takes no such op away:
+the compiled steps of the JoyAI, SmallThinker and Nemotron-H cells
+keep 15 / 11 / 12 ``[T, d]`` sources in VMEM with it as without
+(compile-only v5e).
 ``moe.route_dispatch_ms_per_step`` rising by ~4 ms a layer is the sign
 that this was lost.
 
 Counters (``observability.metrics`` registry, beside ``flash.tiles_*``):
 gauges ``moe.rows_buffer``, ``moe.experts_held``, ``moe.gathered_mb``
 (label ``op`` = ``dispatch``, ``combine``, ``dispatch_bwd``,
-``combine_bwd``: the bytes that gather writes a call) and
+``combine_bwd``: the bytes that gather writes a call),
+``moe.gather_kernel`` (label ``op``: 1 a kernel, 0 XLA) and
 ``moe.expert_calls`` (label ``pass`` = ``fwd``, ``bwd``: the
 ``pallas_call``s ``experts_ffn`` traces a pass) are set when a
 layer is traced; counters ``moe.assignments_held`` and ``moe.rows_computed``
@@ -84,6 +106,7 @@ assignment — equal unless something was dropped.
 
 from __future__ import annotations
 
+import functools
 from typing import NamedTuple, Optional, Tuple
 
 import jax
@@ -92,10 +115,13 @@ from jax import lax
 from jax.ad_checkpoint import checkpoint_name
 
 from ..observability.metrics import get_registry
-from ..ops.grouped_matmul import grouped_matmul, grouped_matmul_act
+from ..ops.grouped_matmul import (VMEM_LIMIT, dma_rows, grouped_matmul,
+                                  grouped_matmul_act, row_gather,
+                                  scoped_vmem, sum_gather)
 
 ROW_TILE = 256      # rows of a tile of the buffer: one expert each
 PLAN = "moe_plan"   # checkpoint name of the top-k choice and its layout
+VMEM_BYTES = 128 * 1024 * 1024   # the chip's VMEM (v5e)
 
 
 class Plan(NamedTuple):
@@ -193,22 +219,62 @@ def plan(idx, first, count: int, tile: int = ROW_TILE) -> Plan:
 # ------------------------------------------------ dispatch and combine
 
 
-def _gather(op: str, source, index):
-    """``source[index]``: the one way rows move.  What it writes a call
-    is the gauge ``moe.gathered_mb{op}``, set as the layer is traced."""
-    out = source[index]
-    get_registry().gauge("moe.gathered_mb", op=op).set(
+# what XLA may keep in VMEM beside a call that reserves the most scoped
+# VMEM a call can: the free bytes a gather's source is held to when no
+# expert layer names its own calls (``_held_part`` does)
+FREE = VMEM_BYTES - VMEM_LIMIT
+
+
+def from_hbm(source, free: int, tokens: int, tile: int) -> bool:
+    """The shape rule: a gather from ``source [N, d]`` moves its rows by
+    DMA (``ops/grouped_matmul.py:row_gather`` / ``sum_gather``) when the
+    source is larger than the ``free`` bytes of VMEM beside the layer's
+    kernels and a row can move alone (``dma_rows``); else XLA gathers,
+    reading VMEM when its source fits there.  The kernels walk the
+    ``tokens`` in the buffer's row ``tile``: a multiple of it."""
+    return (source.size * source.dtype.itemsize > free
+            and dma_rows(source.shape[1], source.dtype) > 0
+            and tokens % tile == 0)
+
+
+def _counted(op: str, out, kernel: bool):
+    """What a gather writes a call and how: the gauges
+    ``moe.gathered_mb{op}`` and ``moe.gather_kernel{op}``, set as the
+    layer is traced."""
+    reg = get_registry()
+    reg.gauge("moe.gathered_mb", op=op).set(
         out.size * out.dtype.itemsize / 1e6)
+    reg.gauge("moe.gather_kernel", op=op).set(int(kernel))
     return out
 
 
-def _sum_held(op: str, rows, p: Plan, weights=None):
-    """``[T, d]`` float32: each token's held rows (by ``weights [T, k]``
-    where given) summed in the order ``j = 0 ... k - 1``, from ONE gather
-    laid ``[k, T, d]``: the fold from ``[k * T, d]`` is free and the sum
-    is ``k`` multiply-adds over ``[T, d]``, each on its own slice (over
-    the whole array the float32 products would be written out first)."""
-    picked, held = _gather(op, rows, p.dest.T), p.held.T
+def _tile(p: Plan) -> int:
+    return p.tok.shape[0] // p.tile_group.shape[0]
+
+
+def _gather(op: str, source, tok, p: Plan, free: int, interpret):
+    """``source[tok]`` ``[R, d]``, ``tok`` a row's token: from HBM one
+    DMA a row of the active tiles (the others left unwritten: nothing
+    reads them), or XLA's gather of every row."""
+    kernel = from_hbm(source, free, source.shape[0], _tile(p))
+    out = (row_gather(source, tok, p.n_active, _tile(p), interpret)
+           if kernel else source[tok])
+    return _counted(op, out, kernel)
+
+
+def _sum_held(op: str, rows, p: Plan, free: int, interpret, weights=None):
+    """``[T, d]`` in ``rows``' dtype: each token's held rows (by ``weights
+    [T, k]`` where given) summed in float32 in the order ``j = 0 ... k -
+    1``, cast once.  From HBM the rows move one DMA each and meet in VMEM
+    (``sum_gather``); through XLA ONE gather lays them ``[k, T, d]``: the
+    fold from ``[k * T, d]`` is free and the sum is ``k`` multiply-adds
+    over ``[T, d]``, each on its own slice (over the whole array the
+    float32 products would be written out first)."""
+    if from_hbm(rows, free, p.dest.shape[0], _tile(p)):
+        return _counted(op, sum_gather(rows, p.dest, p.held, p.n_active,
+                                       weights, _tile(p), interpret), True)
+    picked = _counted(op, rows[p.dest.T], False)
+    held = p.held.T
 
     def term(j):
         t = jnp.where(held[j][:, None], picked[j], 0).astype(jnp.float32)
@@ -217,38 +283,39 @@ def _sum_held(op: str, rows, p: Plan, weights=None):
     total = term(0)
     for j in range(1, held.shape[0]):
         total = total + term(j)
-    return total
+    return total.astype(rows.dtype)
 
 
-@jax.custom_vjp
-def dispatch(x, p: Plan):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3))
+def dispatch(x, p: Plan, free: int = FREE, interpret=None):
     """``rows [R, d]``: each real row its token (the others token 0:
-    ``_combine_bwd`` sends them a zero gradient)."""
-    return _gather("dispatch", x, p.tok)
+    ``_combine_bwd`` sends them a zero gradient).  ``free``: the shape
+    rule's VMEM (``from_hbm``)."""
+    return _gather("dispatch", x, p.tok, p, free, interpret)
 
 
-def _dispatch_fwd(x, p):
-    return dispatch(x, p), p
+def _dispatch_fwd(x, p, free, interpret):
+    return dispatch(x, p, free, interpret), p
 
 
-def _dispatch_bwd(p, drows):
-    return _sum_held("dispatch_bwd", drows, p).astype(drows.dtype), None
+def _dispatch_bwd(free, interpret, p, drows):
+    return _sum_held("dispatch_bwd", drows, p, free, interpret), None
 
 
 dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
 
 
-@jax.custom_vjp
-def combine(rows, weights, p: Plan):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def combine(rows, weights, p: Plan, free: int = FREE, interpret=None):
     """``y [T, d]``: each token's held rows summed by their weights."""
-    return _sum_held("combine", rows, p, weights).astype(rows.dtype)
+    return _sum_held("combine", rows, p, free, interpret, weights)
 
 
-def _combine_fwd(rows, weights, p):
-    return combine(rows, weights, p), (rows, weights, p)
+def _combine_fwd(rows, weights, p, free, interpret):
+    return combine(rows, weights, p, free, interpret), (rows, weights, p)
 
 
-def _combine_bwd(res, dy):
+def _combine_bwd(free, interpret, res, dy):
     rows, weights, p = res
     # in dy's dtype until the products that read it, and exactly zero on
     # the rows that hold no assignment: whatever those rows of the
@@ -257,7 +324,7 @@ def _combine_bwd(res, dy):
     # kernel's result), so that XLA can bring ``dy`` into VMEM after the
     # dispatch gather's source has left it: module docstring
     tok, rows = lax.optimization_barrier((p.tok, rows))
-    g = _gather("combine_bwd", dy, tok)                   # [R, d]
+    g = _gather("combine_bwd", dy, tok, p, free, interpret)   # [R, d]
     w_row = jnp.where(p.valid, weights.reshape(-1)[p.src], 0.0)
     drows = (w_row[:, None] * g).astype(rows.dtype)
     dw_row = jnp.sum(jnp.where(p.valid[:, None],
@@ -328,16 +395,20 @@ def _held_part(x, idx, weights, first, gate, up, down, tile, interpret,
     """The held experts' part for tokens ``x [T, d]`` already routed
     (``idx, weights [T, k]``), and its two counts."""
     count = up.shape[0]
+    # the VMEM XLA may hold a gather's source in beside this layer's
+    # kernels: the shape rule's (``from_hbm``)
+    free = VMEM_BYTES - scoped_vmem(x.shape[1], up.shape[2], tile, x.dtype,
+                                    1 if gate is None else 2)
     with jax.named_scope("dispatch"):
         p = plan(idx, first, count, tile)
-        rows = dispatch(x, p)
+        rows = dispatch(x, p, free, interpret)
     reg = get_registry()
     reg.gauge("moe.rows_buffer").set(rows.shape[0])
     reg.gauge("moe.experts_held").set(count)
     with jax.named_scope("experts"):
         out = experts_ffn(rows, gate, up, down, p, interpret, act)
     with jax.named_scope("combine"):
-        y = combine(out, weights, p)
+        y = combine(out, weights, p, free, interpret)
     return y, served(idx, first, count, p)
 
 

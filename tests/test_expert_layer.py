@@ -7,6 +7,7 @@ on seeded weights at a small size on the CPU.
 import dataclasses
 import functools
 import os
+import re
 import sys
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
@@ -23,7 +24,8 @@ from byteps_tpu.models.transformer import (LatentAttention, Transformer,
                                            TransformerConfig, apply_rope)
 from byteps_tpu.observability.metrics import get_registry
 from byteps_tpu.ops.flash_attention import flash_attention
-from byteps_tpu.ops.grouped_matmul import grouped_matmul, grouped_matmul_act
+from byteps_tpu.ops.grouped_matmul import (grouped_matmul, grouped_matmul_act,
+                                          row_gather, sum_gather)
 from byteps_tpu.parallel import moe
 from byteps_tpu.parallel.collectives import shard_map
 from byteps_tpu.parallel.ring_attention import local_attention
@@ -682,6 +684,222 @@ def test_expert_calls_gauge_is_what_a_layer_traces(form):
            for pass_ in ("fwd", "bwd")}
     assert got == {"fwd": fwd, "bwd": both_ - fwd}
     assert got == {"fwd": 2, "bwd": 4 if "gate" not in e else 5}
+
+
+# ------------------------------------- the gathers from HBM, by DMA
+
+GD = 256            # a width whose rows move alone in bf16 and float32
+
+
+def dma_case(k, dtype, seed=30):
+    """``(x, weights, plan, experts)`` at ``GD``: tokens partly held
+    (FIRST .. FIRST + 8 of ALL), one held group empty, tiles past
+    ``n_active``."""
+    count, tile = 8, 8
+    ks = jax.random.split(jax.random.PRNGKey(seed), 5)
+    x = jax.random.normal(ks[0], (64, GD)).astype(dtype)
+    bias = jnp.zeros((ALL,)).at[FIRST + 1].set(-1e3)
+    idx, weights = moe.route(x, jax.random.normal(ks[1], (GD, ALL)), bias,
+                             k, 2.5)
+    p = moe.plan(idx, FIRST, count, tile)
+    assert 0 < int(p.held.sum()) < p.held.size and int(p.count[1]) == 0
+    assert int(p.n_active) < p.tile_group.shape[0]
+    e = {n: (0.3 * jax.random.normal(kk, shape)).astype(dtype)
+         for n, kk, shape in (("gate", ks[2], (count, GD, F)),
+                              ("up", ks[3], (count, GD, F)),
+                              ("down", ks[4], (count, F, GD)))}
+    return x, weights, p, e
+
+
+def assert_same(got, want, dtype, scaled=False):
+    got, want = (np.asarray(jnp.asarray(a, jnp.float32)) for a in (got, want))
+    # the same float32 sums in the same order; the CPU's XLA fuses some
+    # multiply-adds on one side and not the other, so a bf16 result may
+    # round one place apart and a float32 one differ in its last bits
+    if dtype == jnp.bfloat16 and scaled:
+        # ... and through the bf16 products of the experts that place
+        # moves by a few of the largest value's
+        np.testing.assert_allclose(got, want, rtol=0,
+                                   atol=2 ** -6 * np.max(np.abs(want)))
+    elif dtype == jnp.bfloat16:
+        np.testing.assert_allclose(got, want, rtol=2 ** -7, atol=0)
+    else:
+        np.testing.assert_allclose(got, want, rtol=1e-5,
+                                   atol=1e-5 * np.max(np.abs(want)))
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+@pytest.mark.parametrize("k", [4, 6, 8])
+def test_the_dma_gathers_give_what_xla_gives(k, dtype):
+    """``row_gather`` is ``source[tok]`` on every row of an active tile
+    (the rows past ``n_active`` are left unwritten — the interpreter
+    leaves NaN there); ``sum_gather`` is the XLA path's held sum, with
+    weights and without; and ``dispatch`` / ``combine`` around the
+    experts give the XLA path's values and gradients, so no reader
+    touches an unwritten row."""
+    x, weights, p, e = dma_case(k, dtype)
+    tile = moe._tile(p)
+    live = int(p.n_active) * tile
+    rows = row_gather(x, p.tok, p.n_active, tile)
+    assert np.all(np.isfinite(np.asarray(rows[:live], np.float32)))
+    assert_same(rows[:live], x[p.tok][:live], dtype)
+    for w in (None, weights):
+        got = sum_gather(rows, p.dest, p.held, p.n_active, w, tile)
+        assert_same(got, moe._sum_held("op", rows, p, 1 << 60, None, w),
+                    dtype)
+
+    def loss(x, e, weights, free):
+        rows = moe.dispatch(x, p, free)
+        out = moe.experts_ffn(rows, e["gate"], e["up"], e["down"], p)
+        y = moe.combine(out, weights, p, free)
+        return jnp.sum(jnp.sin(y.astype(jnp.float32))), y
+
+    runs = [jax.jit(jax.value_and_grad(
+        functools.partial(loss, free=free), argnums=(0, 1, 2),
+        has_aux=True))(x, e, weights) for free in (0, 1 << 60)]
+    dma, xla = (jax.tree_util.tree_leaves(r) for r in runs)
+    for got, want in zip(dma, xla):
+        assert np.all(np.isfinite(np.asarray(got, np.float32)))
+        assert_same(got, want, dtype, scaled=True)
+
+
+def _kernel_calls(text):
+    """``[(kernel name, length of its Mosaic payload)]`` of a lowered
+    module, call by call."""
+    calls = []
+    for chunk in text.split("stablehlo.custom_call @tpu_custom_call")[1:]:
+        body = re.search(r"\\22body\\22: \\22([A-Za-z0-9+/=]+)\\22", chunk)
+        name = re.search(r'kernel_name = "(\w+)"', chunk)
+        calls.append((name.group(1), len(body.group(1))))
+    return calls
+
+
+# an expert layer at LFM2-8B-A1B's per-layer shapes (4 x 8192 tokens,
+# d 2048, 8 of 32 experts held, k 4): the shape rule moves all four
+# gathers by DMA
+LFM2 = dict(T=32768, d=2048, f=1792, k=4, count=8, experts=32)
+
+
+def _lowered_step(monkeypatch, free_all: bool) -> str:
+    """Two checkpointed expert layers, forward and backward, lowered for
+    the chip here (nothing compiles; ``free_all``: as if VMEM held every
+    source, so XLA gathers)."""
+    if free_all:
+        monkeypatch.setattr(moe, "VMEM_BYTES", 1 << 60)
+    c = LFM2
+    sds = lambda *s, dt=jnp.bfloat16: jax.ShapeDtypeStruct(s, dt)  # noqa: E731
+
+    def layer(x, r, g, u, dn):
+        return moe.expert_layer(x, r, None, g, u, dn, top_k=c["k"],
+                                scale=1.0, held=(0, c["count"]),
+                                interpret=False)[0]
+
+    def step(x, ws):
+        for w in ws:
+            x = x + jax.checkpoint(layer)(x, *w)
+        return x.astype(jnp.float32).sum()
+
+    ws = [(sds(c["d"], c["experts"], dt=jnp.float32),
+           sds(c["count"], c["d"], c["f"]), sds(c["count"], c["d"], c["f"]),
+           sds(c["count"], c["f"], c["d"]))] * 2
+    try:
+        return jax.jit(jax.value_and_grad(step, argnums=(0, 1))).trace(
+            sds(c["T"], c["d"]), ws).lower(
+                lowering_platforms=("tpu",)).as_text()
+    finally:
+        monkeypatch.undo()
+
+
+def test_the_dma_gathers_cost_no_set_up(monkeypatch):
+    """Set-up is a requirement of the gather kernels (lowered for the
+    chip on the CPU; nothing compiles or runs).  The lowered step is the
+    same text after every cache is cleared — the persistent compile
+    cache's key is stable from process to process; a kernel's Mosaic
+    payload does not grow when its tile's rows or ``k`` double — its
+    loops are rolled; and the step holds the parent layout's kernels
+    plus one kernel call for each XLA gather the shape rule handed
+    over, no more."""
+    gm = sys.modules["byteps_tpu.ops.grouped_matmul"]
+    texts = []
+    for _ in range(2):      # one call site: a payload carries its stack
+        jax.clear_caches()
+        for f in (gm._row_gather_call, gm._sum_gather_call, gm._gmm_call,
+                  gm._act_call, gm._act_bwd_call, gm._dw_call):
+            f.cache_clear()
+        texts.append(_lowered_step(monkeypatch, False))
+    first = texts[0]
+    assert texts[1] == first
+
+    sds = lambda *s, dt=jnp.bfloat16: jax.ShapeDtypeStruct(s, dt)  # noqa: E731
+    T, d, R = 4096, 2048, 16384
+
+    def payload(fn, *args):
+        (name, size), = _kernel_calls(jax.jit(fn).trace(*args).lower(
+            lowering_platforms=("tpu",)).as_text())
+        return size
+
+    rows = [payload(lambda x, t, n, tile=tile: row_gather(
+        x, t, n, tile, interpret=False), sds(T, d), sds(R, dt=jnp.int32),
+        sds(dt=jnp.int32)) for tile in (256, 512)]
+    sums = [payload(lambda r, dest, held, n, w, tile=tile: sum_gather(
+        r, dest, held, n, w, tile, interpret=False), sds(R, d),
+        sds(T, k, dt=jnp.int32), sds(T, k, dt=jnp.bool_), sds(dt=jnp.int32),
+        sds(T, k, dt=jnp.float32)) for k, tile in ((4, 256), (8, 256),
+                                                   (4, 512))]
+    assert rows[1] <= rows[0] * 1.01 and max(sums) <= sums[0] * 1.01
+
+    xla = _lowered_step(monkeypatch, True)
+    kernels, before = ([n for n, _ in _kernel_calls(t)] for t in (first, xla))
+    moved = [n for n in kernels if n in ("row_gather", "sum_gather")]
+    wide = rf"stablehlo\.gather.*-> tensor<[0-9x]*x{LFM2['d']}xbf16>"
+    handed = len(re.findall(wide, xla)) - len(re.findall(wide, first))
+    assert sorted(set(kernels) - set(moved)) == sorted(set(before))
+    assert len(kernels) == len(before) + len(moved) and len(moved) == handed
+    # two layers: forward, recomputed (the dispatch alone) and backward
+    assert handed == 2 * 5
+
+
+SHAPE_RULE = {   # (T, d, f, k, held, experts, gated): a layer of each cell
+    "lfm2": ((32768, 2048, 1792, 4, 8, 32, True), (1, 1, 1, 1)),
+    "smallthinker": ((16384, 2560, 768, 6, 16, 64, True), (0, 1, 1, 0)),
+    "joyai": ((8192, 2048, 768, 8, 16, 256, True), (0, 1, 1, 0)),
+    # half a row of 2688 is not a whole number of lanes
+    "nemotron": ((8192, 2688, 1856, 6, 8, 128, False), (0, 0, 0, 0)),
+}
+OPS = ("dispatch", "combine", "dispatch_bwd", "combine_bwd")
+
+
+@pytest.mark.parametrize("cell", list(SHAPE_RULE))
+def test_the_shape_rule_picks_the_dma_gathers_by_shape(cell):
+    """``moe.gather_kernel{op}`` as a layer of each cell is traced
+    (abstract shapes, nothing runs): the ``[T, d]`` sources that fit the
+    VMEM beside the layer's kernels stay with XLA, the row buffer and a
+    ``[T, d]`` source larger than that move by DMA."""
+    (T, d, f, k, count, experts, gated), want = SHAPE_RULE[cell]
+    sds = lambda *s: jax.ShapeDtypeStruct(s, jnp.bfloat16)  # noqa: E731
+
+    def loss(x, r, up, down, gate):
+        return moe.expert_layer(
+            x, r, None, gate, up, down, top_k=k, scale=1.0,
+            held=(0, count), act="silu" if gated else "relu2")[0].astype(
+                jnp.float32).sum()
+
+    reg = get_registry()
+    for op in OPS:
+        reg.gauge("moe.gather_kernel", op=op).set(-1)
+    jax.eval_shape(jax.grad(loss, argnums=(0, 2, 3)), sds(T, d),
+                   sds(d, experts), sds(count, d, f), sds(count, f, d),
+                   sds(count, d, f) if gated else None)
+    assert tuple(reg.get("moe.gather_kernel", op=op).value
+                 for op in OPS) == want
+    # the bytes each writes a call keep their meaning
+    R = moe.buffer_rows(T, k, count, moe.ROW_TILE)
+    assert reg.get("moe.gathered_mb", op="dispatch").value == pytest.approx(
+        R * d * 2 / 1e6)
+    sum_rows = T if want[1] else k * T
+    assert reg.get("moe.gathered_mb", op="combine").value == pytest.approx(
+        sum_rows * d * 2 / 1e6)
 
 
 # ------------------------------------------- the whole model and the step
