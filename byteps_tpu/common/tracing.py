@@ -399,6 +399,10 @@ SCOPE_UNPACK = SCOPE_PUSH_PULL + "/unpack"   # buckets sliced back into leaves
 SCOPE_OPTIMIZER = "bps.optimizer"        # inner update + parameter write
 #                                          (on a share: its slice of the params)
 SCOPE_STEP_METRICS = "bps.step_metrics"  # loss / model-state psums
+# one readout of a looped model (``loop_steps`` > 1), inside SCOPE_HEAD:
+# the final norm, the exit gate, the head's cross-entropy, the exit
+# distribution and its entropy
+SCOPE_LOOP_READOUT = "loop_readout"
 
 BUCKET_STAGES = ("pack", "reduce")
 

@@ -16,8 +16,9 @@ the scaling-book recipe (mesh + annotated shardings + XLA collectives):
   attention (``lax.ppermute`` K/V rotation) or Ulysses (``all_to_all``)
   inside a ``shard_map`` island — see parallel/ring_attention.py.
 
-Everything is static-shaped; the only loop is over layers (unrolled at
-trace time — layer count is small and static).
+Everything is static-shaped; the loop over layers is unrolled at trace
+time (layer count is small and static).  A looped model's passes over
+the shared stack (``loop_steps``) are one ``nn.scan``.
 """
 
 from __future__ import annotations
@@ -176,6 +177,18 @@ class TransformerConfig:
     # ``lm_loss_fn`` adds ``mtp_loss_weight`` times its cross-entropy
     mtp_layers: int = 0
     mtp_loss_weight: float = 0.3
+    # a norm on each sublayer's OUTPUT as well as its input (``Block``:
+    # ``h = x + N2(attn(N1(x)))``, ``h + N4(ffn(N3(h)))``), each with a
+    # scale of its own
+    sandwich_norm: bool = False
+    # a looped model (Ouro's LoopLM): the whole stack of blocks runs
+    # ``loop_steps`` times a token with one set of weights, the final
+    # norm after every pass and its output fed to the next; an exit gate
+    # reads each pass's state, and ``lm_loss_fn`` takes the exit
+    # distribution's expected loss less a weight times its entropy
+    # (``training/step.py:EXIT_ENTROPY_WEIGHT``).  Training path only:
+    # no cache holds a pass's K/V.
+    loop_steps: int = 1
     # recompute each block in the backward pass instead of keeping its
     # activations (``nn.remat``)
     remat: bool = False
@@ -1076,28 +1089,33 @@ class Block(nn.Module):
 
     @nn.compact
     def __call__(self, x, key_mask=None, cache=None, pos=None):
-        attention = (LatentAttention if self.cfg.attn_kind == "mla"
+        cfg = self.cfg
+        attention = (LatentAttention if cfg.attn_kind == "mla"
                      else partial(Attention, layer=self.layer))
-        y = self.cfg.make_norm("ln1")(x)
+        # with sandwich norms each sublayer's output is normalised too
+        out_norm = ((lambda name, y: cfg.make_norm(name)(y))
+                    if cfg.sandwich_norm else (lambda name, y: y))
+        y = cfg.make_norm("ln1")(x)
         # the router may score the attention input: kept for the expert
         # layer below, which still reads the feed-forward input
-        router_x = y if self.cfg.moe_router_pre_attn else None
+        router_x = y if cfg.moe_router_pre_attn else None
         if cache is not None:
             if key_mask is not None:
                 raise ValueError(
                     "KV-cache decode does not support key_mask (pad K/V "
                     "would enter the cache as real context)")
-            attn_out, new_cache = attention(self.cfg, name="attn")(
+            attn_out, new_cache = attention(cfg, name="attn")(
                 y, cache=cache, pos=pos)
-            x = x + attn_out
         else:
             new_cache = None
-            x = x + attention(self.cfg, name="attn")(y, key_mask=key_mask)
-        y = self.cfg.make_norm("ln2")(x)
+            attn_out = attention(cfg, name="attn")(y, key_mask=key_mask)
+        x = x + out_norm("ln1_out", attn_out)
+        y = cfg.make_norm("ln2")(x)
         if self.experts:
-            x = x + ExpertLayer(self.cfg, name="moe")(y, router_x)
+            ffn_out = ExpertLayer(cfg, name="moe")(y, router_x)
         else:
-            x = x + MLP(self.cfg, name="mlp")(y)
+            ffn_out = MLP(cfg, name="mlp")(y)
+        x = x + out_norm("ln2_out", ffn_out)
         return (x, new_cache) if cache is not None else x
 
 
@@ -1276,6 +1294,38 @@ class MTPModule(nn.Module):
         return cfg.make_norm("norm")(x)
 
 
+def exit_log_probs(exit_logits):
+    """The exit distribution of a looped model, in logs, over axis 0 (the
+    ``R`` passes): with ``lam_t = sigmoid(exit_logits[t])`` pass ``t <
+    R`` takes ``lam_t * prod_{j<t} (1 - lam_j)`` and the last pass the
+    remaining mass ``prod_{j<R} (1 - lam_j)``.  Float32, in log space
+    (``log_sigmoid``), so that a pass of vanishing mass keeps a finite
+    log for the entropy."""
+    logits = exit_logits.astype(jnp.float32)
+    log_stay = jnp.cumsum(jax.nn.log_sigmoid(-logits), axis=0)
+    # log prod_{j<t} (1 - lam_j): 0 for the first pass
+    log_reach = jnp.concatenate(
+        [jnp.zeros_like(log_stay[:1]), log_stay[:-1]], axis=0)
+    return jnp.concatenate(
+        [jax.nn.log_sigmoid(logits[:-1]) + log_reach[:-1],
+         log_reach[-1:]], axis=0)
+
+
+def loop_pass(model, x, _):
+    """One pass of a looped model (``Transformer.hidden``'s scan body):
+    the shared stack of blocks, then the readout's part that feeds the
+    next pass — the final norm — and the exit gate on its output.
+    Returns ``(next pass's input, (normed state, exit logit))``."""
+    from ..common.tracing import SCOPE_HEAD, SCOPE_LOOP_READOUT
+
+    for block in model.blocks:
+        x = block(x)
+    with jax.named_scope(SCOPE_HEAD), jax.named_scope(SCOPE_LOOP_READOUT):
+        s = model.ln_f(x)
+        gate = model.exit_gate(s)[..., 0]
+    return s, (s, gate)
+
+
 class Transformer(nn.Module):
     """Causal LM.  Input ``tokens [B, T]`` -> logits ``[B, T, vocab]``.
 
@@ -1320,6 +1370,17 @@ class Transformer(nn.Module):
                 for i in range(cfg.num_layers)
             ]
         self.ln_f = cfg.make_norm("ln_f")
+        if cfg.loop_steps > 1:
+            if (cfg.layer_kinds is not None or cfg.mtp_layers
+                    or cfg.moe_experts):
+                raise ValueError(
+                    "a looped model is built of Blocks without experts "
+                    "and without a multi-token-prediction module")
+            # float32: the exit distribution is a product of R gates
+            self.exit_gate = QuantDense(1, dtype=jnp.float32, use_bias=True,
+                                        name="exit_gate")
+        elif cfg.loop_steps < 1:
+            raise ValueError(f"loop_steps={cfg.loop_steps}: at least 1")
         if cfg.mtp_layers > 1:
             raise ValueError("one multi-token-prediction depth is built; "
                              f"mtp_layers={cfg.mtp_layers}")
@@ -1337,11 +1398,18 @@ class Transformer(nn.Module):
 
     def hidden(self, tokens):
         """Everything up to (and including) the final norm:
-        ``[B, T] -> [B, T, d_model]``."""
+        ``[B, T] -> [B, T, d_model]``.  A looped model (``loop_steps`` R
+        > 1) returns ``(states [R, B, T, d_model], exit_logits [R, B,
+        T])``: every pass's normed state and its exit gate's logit, the
+        passes one ``nn.scan`` over the shared stack whose parameters
+        are broadcast — the lowered program does not grow with R, and
+        the shared weights' gradients sum over the passes."""
         from ..observability.metrics import get_registry
 
         cfg = self.cfg
         reg = get_registry()        # set when the model is traced
+        reg.gauge("loop.steps").set(cfg.loop_steps)
+        reg.gauge("model.layers_held").set(cfg.num_layers)
         attn_layers = range(cfg.num_layers)
         if cfg.layer_kinds is not None:
             kinds = [cfg.layer_kind(i) for i in attn_layers]
@@ -1355,6 +1423,11 @@ class Transformer(nn.Module):
         x = self.embed(tokens)
         if cfg.pos_emb == "learned":
             x = x + self.pos(jnp.arange(tokens.shape[1])[None, :])
+        if cfg.loop_steps > 1:
+            passes = nn.scan(
+                loop_pass, variable_broadcast="params",
+                split_rngs={"params": False}, length=cfg.loop_steps)
+            return passes(self, x, None)[1]
         for block in self.blocks:
             x = block(x)
         return self.ln_f(x)
@@ -1384,9 +1457,20 @@ class Transformer(nn.Module):
         return h, self.mtp(h, jnp.roll(self.embed(tokens), -1, axis=1))
 
     def __call__(self, tokens):
+        """Logits; of every pass (``[R, B, T, vocab]``) for a looped
+        model."""
         if self.cfg.mtp_layers and self.is_initializing():
             return self.logits(self.hidden_mtp(tokens)[0])
+        if self.cfg.loop_steps > 1:
+            return self.logits(self.hidden(tokens)[0])
         return self.logits(self.hidden(tokens))
+
+    def _refuse_a_loop(self):
+        if self.cfg.loop_steps > 1:
+            raise NotImplementedError(
+                f"a looped model (loop_steps={self.cfg.loop_steps}) is "
+                "built for training: no cache holds each pass's K/V, and "
+                "decoding by the exit gate is not built")
 
     def decode(self, tokens, caches, pos, last_only=False, last_idx=None):
         """One autoregressive step over ``tokens [B, tq]`` at absolute
@@ -1406,6 +1490,7 @@ class Transformer(nn.Module):
         *dynamic* position — a right-padded chunk's true last prompt
         token instead of the literal last row (see ``prefill_chunk``).
         """
+        self._refuse_a_loop()
         x = self.embed(tokens)
         if self.cfg.pos_emb == "learned":
             idx = (pos[:, None] + jnp.arange(tokens.shape[1])[None, :]
@@ -1487,6 +1572,7 @@ class Transformer(nn.Module):
         :func:`gather_paged_rows`; the caller slices the written span
         and re-splits it per shard at scatter time.
         """
+        self._refuse_a_loop()
         rows = gather_paged_rows(pcaches, table, hw_blocks=hw_blocks,
                                  tp=tp)
         if tp > 1:
@@ -1500,6 +1586,7 @@ class Transformer(nn.Module):
         through its block table, run the position-offset chunk, return
         the written rows for the caller's scatter-back (see
         :meth:`decode_paged`)."""
+        self._refuse_a_loop()
         rows = gather_paged_rows(pcaches, table, tp=tp)
         if tp > 1:
             rows = _regroup_tp_rows(self.cfg, rows)
@@ -1519,6 +1606,7 @@ class Transformer(nn.Module):
 
         Returns ``(logits [N, tq, vocab], new_pcaches)`` — the pool
         comes back updated; there is nothing to scatter."""
+        self._refuse_a_loop()
         views = tuple(dict(c, table=tables, wblk=wblk, woff=woff)
                       for c in pcaches)
         logits, new = self.decode(tokens, views, pos,
@@ -1570,6 +1658,7 @@ class Transformer(nn.Module):
         the caller's per-position scatter-back (see
         :meth:`decode_paged`; ``hw_blocks`` caps the gather at the
         high-water block, which must cover ``pos + k + 1``)."""
+        self._refuse_a_loop()
         rows = gather_paged_rows(pcaches, table, hw_blocks=hw_blocks,
                                  tp=tp)
         if tp > 1:

@@ -40,7 +40,8 @@ from ..common import logging as bps_log
 from ..common import partition
 from ..common.config import get_config
 from ..observability.metrics import get_registry
-from ..common.tracing import (SCOPE_HEAD, SCOPE_MODEL, SCOPE_OPTIMIZER,
+from ..common.tracing import (SCOPE_HEAD, SCOPE_LOOP_READOUT,
+                              SCOPE_MODEL, SCOPE_OPTIMIZER,
                               SCOPE_STEP_METRICS)
 from ..ops.compression import Compression
 from .optimizer import (distributed_links, resolve_compression,
@@ -137,9 +138,11 @@ def make_data_parallel_step(
     ``batch`` is a pytree whose leaves have the global batch on dim 0
     (sharded over ``axes``), and metrics = {"loss": mean loss}.  A
     ``loss_fn`` may return a third element, a dict of counts of its own
-    (``lm_loss_fn``: ``moe_assignments_held``, ``moe_rows_computed``);
-    they join the metrics summed over the workers, and every call of the
-    step adds them to the registry's counters of their names
+    (``lm_loss_fn``: ``moe_assignments_held``, ``moe_rows_computed``)
+    or of means (a looped model's ``loop_ce<t>``, ``loop_exit_p<t>``);
+    they join the metrics, a count summed over the workers and a mean
+    averaged, and every call of the step adds a count to the registry's
+    counter of its name and sets a mean's gauge
     (``flush_step_counts``).
 
     Semantics match the reference benchmark
@@ -259,8 +262,11 @@ def make_data_parallel_step(
             )
             metrics = {"loss": loss}
             for extra in counts:
-                metrics.update({k: jax.lax.psum(v, axes)
-                                for k, v in extra.items()})
+                # a count is summed over the workers, a mean averaged
+                metrics.update({
+                    k: jax.lax.psum(v, axes) / n
+                    if jnp.issubdtype(jnp.result_type(v), jnp.floating)
+                    else jax.lax.psum(v, axes) for k, v in extra.items()})
         return (
             TrainState(new_params, new_opt, new_mstate, state.step + 1),
             metrics,
@@ -485,11 +491,16 @@ def _count_step(counts) -> None:
     """Each count of one step grows the registry counter of its name
     (``moe_assignments_held`` -> ``moe.assignments_held``: the layer's
     prefix becomes the registry's dotted one), ``train.steps_counted``
-    by one."""
+    by one; a mean (``loop_ce1``) sets the gauge of its name to the
+    step's value."""
     reg = get_registry()
     reg.counter("train.steps_counted", instants=False).inc()
     for name, n in counts.items():
-        reg.counter(name.replace("_", ".", 1), instants=False).inc(int(n))
+        dotted = name.replace("_", ".", 1)
+        if jnp.issubdtype(jnp.result_type(n), jnp.floating):
+            reg.gauge(dotted).set(float(n))
+        else:
+            reg.counter(dotted, instants=False).inc(int(n))
 
 
 def flush_step_counts(wait: bool = True) -> None:
@@ -627,6 +638,90 @@ def replicate_state(state, mesh: Mesh):
     )
 
 
+def _head_weight(params, h):
+    """The LM head's ``[d, vocab]`` weight in ``h``'s dtype."""
+    if "lm_head" in params:
+        return params["lm_head"]["kernel"].astype(h.dtype)
+    # tied-embedding models (tie_embeddings=True) have no
+    # lm_head; the head weight is the embedding transposed.
+    # tp-partitioned trees box the leaf in nn.Partitioned.
+    import flax.linen as nn
+
+    emb = params["embed"]["embedding"]
+    if isinstance(emb, nn.meta.AxisMetadata):
+        emb = emb.unbox()
+    return emb.T.astype(h.dtype)
+
+
+def loop_readouts(params, model, tokens, targets, fused_head: bool = True,
+                  block_n: Optional[int] = None,
+                  block_v: Optional[int] = None):
+    """The R readouts of a looped model (``cfg.loop_steps`` > 1) on
+    ``tokens [B, T]`` with next-token ``targets [B, T]`` (out of range:
+    ignored): ``(ce [R, B*T], log_p [R, B*T], valid [B*T])`` — each
+    pass's cross-entropy of its normed state through the one head (zero
+    on an ignored row), the exit distribution in logs
+    (``models/transformer.py:exit_log_probs``) and which rows count.
+    The passes' heads run as one ``lax.map`` over the states: one head
+    program, whatever R.  Everything after the stack lies under
+    ``bps.head/loop_readout``."""
+    from ..models.transformer import exit_log_probs
+
+    states, exit_logits = model.apply({"params": params}, tokens,
+                                      method=model.hidden)
+    R, B, T, d = states.shape
+    flat_t = targets.reshape(-1)
+    vocab = model.cfg.vocab_size
+    valid = (flat_t >= 0) & (flat_t < vocab)
+    with jax.named_scope(SCOPE_HEAD), jax.named_scope(SCOPE_LOOP_READOUT):
+        if fused_head:
+            from ..ops.fused_cross_entropy import fused_linear_cross_entropy
+
+            w = _head_weight(params, states)
+
+            def row_ce(h):
+                return fused_linear_cross_entropy(h.reshape(-1, d), w,
+                                                  flat_t, block_n, block_v)
+        else:
+            def row_ce(h):
+                logits = model.apply({"params": params}, h,
+                                     method=model.logits)
+                return jnp.where(
+                    valid, optax.softmax_cross_entropy_with_integer_labels(
+                        logits.reshape(-1, vocab),
+                        jnp.where(valid, flat_t, 0)), 0.0)
+
+        ce = jax.lax.map(row_ce, states)
+        return ce, exit_log_probs(exit_logits.reshape(R, -1)), valid
+
+
+# the exit distribution's entropy weight in a looped model's loss: the
+# Ouro paper's Stage-I pre-training setting
+EXIT_ENTROPY_WEIGHT = 0.1
+
+
+def exit_weighted_loss(ce, log_p, valid, entropy_weight: float):
+    """``(loss, means)`` of a looped model's readouts (``loop_readouts``):
+    the mean over valid rows of the exit distribution's expected
+    cross-entropy ``sum_t p_t ce_t`` less ``entropy_weight`` times its
+    entropy ``-sum_t p_t log p_t``; ``means`` holds each pass's mean
+    cross-entropy (``loop_ce<t>``) and exit probability
+    (``loop_exit_p<t>``), t from 1."""
+    p = jnp.exp(log_p)
+    per_row = jnp.sum(p * ce, axis=0) + entropy_weight * jnp.sum(
+        p * log_p, axis=0)
+    n = jnp.maximum(jnp.sum(valid), 1).astype(jnp.float32)
+
+    def mean(rows):
+        return jnp.sum(jnp.where(valid, rows, 0.0)) / n
+
+    means = {}
+    for t in range(ce.shape[0]):
+        means[f"loop_ce{t + 1}"] = mean(ce[t])
+        means[f"loop_exit_p{t + 1}"] = mean(p[t])
+    return mean(per_row), means
+
+
 def lm_loss_fn(model, fused_head: bool = False,
                block_n: Optional[int] = None, block_v: Optional[int] = None,
                early_exit: Optional[tuple] = None):
@@ -661,7 +756,12 @@ def lm_loss_fn(model, fused_head: bool = False,
     head; where it has expert layers the closure returns a third
     element, ``{"moe_assignments_held": n, "moe_rows_computed": r}``
     (``parallel/moe.py:served``, summed over its expert layers), which
-    the step reports beside ``loss``.
+    the step reports beside ``loss``.  A looped model
+    (``cfg.loop_steps`` R > 1) reads its R states out through the same
+    head (``loop_readouts``) and returns the exit distribution's
+    expected cross-entropy less ``EXIT_ENTROPY_WEIGHT`` times its
+    entropy (``exit_weighted_loss``), with each pass's mean
+    cross-entropy and exit probability as the third element.
     ``block_n``/``block_v`` are overrides handed to the kernels as they
     are; left ``None`` each of the three kernels reads its own blocks
     from ``(N, d, vocab)`` and the VMEM it may use
@@ -674,19 +774,6 @@ def lm_loss_fn(model, fused_head: bool = False,
     The mean is over *valid* targets — ignored positions contribute
     neither loss nor denominator, in both the fused and plain branches.
     """
-
-    def _head_weight(params, h):
-        if "lm_head" in params:
-            return params["lm_head"]["kernel"].astype(h.dtype)
-        # tied-embedding models (tie_embeddings=True) have no
-        # lm_head; the head weight is the embedding transposed.
-        # tp-partitioned trees box the leaf in nn.Partitioned.
-        import flax.linen as nn
-
-        emb = params["embed"]["embedding"]
-        if isinstance(emb, nn.meta.AxisMetadata):
-            emb = emb.unbox()
-        return emb.T.astype(h.dtype)
 
     def _hiddens(params, m, tokens):
         """``((h, h_mtp | None), n)``: the pre-head states — with a
@@ -769,6 +856,21 @@ def lm_loss_fn(model, fused_head: bool = False,
             m, h, h_mtp, targets), n
 
     ce = _fused_ce if fused_head else _plain_ce
+    cfg = getattr(model, "cfg", None)
+    looped = getattr(cfg, "loop_steps", 1) > 1
+    if looped and early_exit is not None:
+        raise ValueError("a looped model has an exit gate of its own; "
+                         "early_exit truncates a stack run once")
+
+    def _looped(params, m, tokens, targets):
+        readouts = loop_readouts(params, m, tokens, targets, fused_head,
+                                 block_n, block_v)
+        with jax.named_scope(SCOPE_HEAD), jax.named_scope(
+                SCOPE_LOOP_READOUT):
+            return exit_weighted_loss(*readouts, EXIT_ENTROPY_WEIGHT)
+
+    if looped:
+        ce = _looped
 
     def loss_fn(params, model_state, batch):
         tokens = batch["tokens"]
