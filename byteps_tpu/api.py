@@ -650,6 +650,11 @@ def broadcast_parameters(params, root_rank: int = 0):
     if jax.process_count() > 1:
         from jax.experimental import multihost_utils
 
+        from .training.checkpoint import whole_on_every_process
+
+        # a leaf the data-parallel step keeps on dim-0 shares spans the
+        # processes: gather it whole (a collective) before the broadcast
+        params = whole_on_every_process(params)
         params = multihost_utils.broadcast_one_to_all(
             params, is_source=jax.process_index() == root_rank
         )

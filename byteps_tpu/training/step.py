@@ -15,9 +15,12 @@ the collective time (ledger, PR 22).  A collective that is a leaf's own
 scattered and gathered as it lies) follows that leaf's weight-gradient
 product and is hidden inside the backward pass — 12.7 ms exposed (ledger,
 PR 28).  So ``make_data_parallel_step`` reduce-scatters leaf by leaf, runs
-the optimizer on this worker's share and all-gathers the new parameters;
-the flat bucketed ``push_pull_tree`` remains for the replicated path and
-the eager API, and has no benchmark cell at world > 1.
+the optimizer on this worker's share and leaves the new parameters on
+their shares until the next step, whose forward all-gathers them while
+the layers ahead of them compute (ByteScheduler's cross-iteration
+overlap: a layer waits only for its own parameters).  The flat bucketed
+``push_pull_tree`` remains for the replicated path and the eager API,
+and has no benchmark cell at world > 1.
 
 ``make_data_parallel_step`` is the Horovod-benchmark-equivalent step used by
 ``benchmark/`` and the examples; model-parallel (tp/sp) steps compose GSPMD
@@ -44,6 +47,7 @@ from ..common.tracing import (SCOPE_HEAD, SCOPE_LOOP_READOUT,
                               SCOPE_MODEL, SCOPE_OPTIMIZER,
                               SCOPE_STEP_METRICS)
 from ..ops.compression import Compression
+from .checkpoint import whole_on_every_process
 from .optimizer import (distributed_links, resolve_compression,
                         resolve_local_axis, resolve_wire_dtype, scoped_update,
                         trace_share_update)
@@ -159,10 +163,13 @@ def make_data_parallel_step(
     reduce-scattered so that every worker is left with its contiguous
     1/shards of every leaf's averaged gradient along dim 0 (shards = the
     size of the local scatter axis); the optimizer updates that share
-    with its share of the moments, and the new parameter shares are
-    all-gathered: the same bytes on the wire as reducing and gathering
-    the gradient, 1/shards of the update's memory traffic, the moments
-    held once across the axis.  Both collectives follow one plan
+    with its share of the moments, and the new parameter shares stay
+    where they are until the next step, whose forward all-gathers them
+    (the gathers of the last layers run behind the first layers'
+    compute): the same bytes on the wire as reducing and gathering the
+    gradient, 1/shards of the update's memory traffic, the parameters
+    between steps and the moments held once across the axis.  Both
+    collectives follow one plan
     (``partition.plan_share_buckets``): a leaf whose share fills a
     bucket (``partition_bytes / shards``) goes by itself, as it lies —
     the scatter of a leaf along dim 0 leaves its dim-0 share, the gather
@@ -172,21 +179,30 @@ def make_data_parallel_step(
     ``ndim``-based masks keep their meaning); ``state.opt_state`` keeps
     the ``DistributedOptimizer`` chain's treedef and the parameters'
     global shapes, a share's moments placed ``P(scatter_axis)`` on dim
-    0; parameters stay replicated.  Which path runs is read from the
-    input, not from a switch: a leaf whose dim 0 does not divide is
-    reduced and updated whole on every worker, and the whole optimizer
-    stays on the replicated path (gradients all-gathered, state ``P()``)
-    when its ``update`` is not elementwise in every non-scalar operand
+    0; ``state.params`` keeps the global shapes too, a sharded leaf
+    placed ``P(scatter_axis)`` on dim 0 between steps.  Read the
+    parameters whole through ``TrainStep.whole_params(state)``: in a
+    multi-process job no process holds a share's leaf whole, and every
+    process calls it.
+    Which path runs is read from the input, not from a switch: a leaf
+    whose dim 0 does not divide is reduced and updated whole on every
+    worker, and the whole optimizer stays on the replicated path
+    (gradients all-gathered, parameters and state ``P()``) when its
+    ``update`` is not elementwise in every non-scalar operand
     (``optimizer.trace_share_update``: a share's norm is not the
     leaf's), when a wire cast is set (parameters must not cross the wire
     below master precision) or when ``backward_passes_per_step > 1``;
     the gauges ``optimizer.sharded_bytes`` / ``optimizer.replicated_bytes``
-    (label ``reason``) say which, a log line why.  A state that is not
-    where the program wants it — fresh from ``create_train_state``,
-    restored and broadcast from a checkpoint — is put there by the
-    step's first call (a local slice; ``lower`` likewise lowers against
-    the wanted shardings whatever it is handed), so callers keep handing
-    it replicated state; ``checkpoint.save_checkpoint`` gathers it whole.
+    (label ``reason``) say which, a log line why, and
+    ``optimizer.forward_gather_bytes`` (the same value as
+    ``optimizer.sharded_bytes``, both 0 on the replicated path and at
+    world 1) how many parameter bytes the forward gathers.  A state
+    that is not where the program wants it — fresh from
+    ``create_train_state``, restored and broadcast from a checkpoint —
+    is put there by the step's first call (a local slice; ``lower``
+    likewise lowers against the wanted shardings whatever it is
+    handed), so callers keep handing it replicated state;
+    ``checkpoint.save_checkpoint`` gathers it whole.
 
     .. note:: At ``world == 1`` (with ``backward_passes_per_step == 1``)
        the DistributedOptimizer wrapper is dropped — matching the
@@ -216,6 +232,7 @@ def make_data_parallel_step(
         tx = scoped_update(optimizer)
         if comp_tx is not None:
             tx = optax.chain(comp_tx, tx)
+        _note_shares(0)
     else:
         pb = partition_bytes or get_config().partition_bytes
         links = distributed_links(optimizer, compression, axes, True, pb,
@@ -239,11 +256,17 @@ def make_data_parallel_step(
                 loss, *aux = loss_fn(p, state.model_state, batch)
             return loss, aux
 
+        on_shares = lay is not None and lay.sharded
+        # the shares' gathers lie outside the differentiated function:
+        # the gradient is taken of the whole leaves, and reduce-scattered
+        # by the bucket plan, not by the gather's transpose
+        whole = (layouts.gather_shares(lay, state.params) if on_shares
+                 else state.params)
         (loss, (new_mstate, *counts)), grads = jax.value_and_grad(
-            lf, has_aux=True)(state.params)
-        if lay is not None and lay.sharded:
+            lf, has_aux=True)(whole)
+        if on_shares:
             new_params, new_opt = layouts.sharded_update(
-                lay, grads, state.opt_state, state.params)
+                lay, grads, state.opt_state, whole, state.params)
         else:
             # push_pull and the inner update name themselves (``tx``)
             updates, new_opt = tx.update(grads, state.opt_state,
@@ -277,8 +300,8 @@ def make_data_parallel_step(
         # divides, what the optimizer's state holds), so the shard_map is
         # made where they are known: under the trace
         lay = layouts.of(state) if layouts is not None else None
-        state_spec = TrainState(  # params / model state / step replicated
-            P(), lay.opt_specs if lay is not None else P(), P(), P())
+        state_spec = TrainState(  # model state / step replicated
+            *(lay.specs if lay is not None else (P(), P())), P(), P())
         return shard_map(
             lambda st, b: local_step(lay, st, b),
             mesh,
@@ -291,21 +314,31 @@ def make_data_parallel_step(
 
 
 _REASONS = ("elementwise", "wire_cast", "multi_step", "dim0")
+def _note_shares(on_shares: int) -> None:
+    """``optimizer.sharded_bytes`` and ``optimizer.forward_gather_bytes``
+    from one value, on every path: a leaf updated on its dim-0 shares
+    lies on them between steps and is gathered in the next forward."""
+    reg = get_registry()
+    for name in ("optimizer.sharded_bytes",
+                 "optimizer.forward_gather_bytes"):
+        reg.gauge(name).set(on_shares)
 
 
 @dataclasses.dataclass(frozen=True)
 class _UpdateLayout:
     """Where one parameter tree's update runs: the leaves (by index in
     the flattened tree) whose dim-0 share each worker updates and the
-    rest, their bucket plans, and where every leaf of the optimizer's
-    state lies (a share's moments ``P(scatter)`` on dim 0)."""
+    rest, their bucket plans, and where every leaf of the parameters and
+    of the optimizer's state lies between steps (a share's parameter
+    and moments ``P(scatter)`` on dim 0)."""
 
     sharded: Tuple[int, ...]
     rest: Tuple[int, ...]
     plan: Optional[partition.BucketPlan]        # of the sharded leaves
     rest_plan: Optional[partition.BucketPlan]   # flat, of the rest
-    opt_specs: Any                       # pytree of P, as opt_state
-    opt_shardings: Optional[List[Any]]   # per opt_state leaf; None = P()
+    specs: Any        # pytrees of P, as (params, opt_state)
+    # per leaf of ``(params, opt_state)``; None = P()
+    shardings: Optional[List[Any]]
     update: Any = None        # the caller's ``update``, traced on shares
 
 
@@ -369,8 +402,8 @@ class _UpdateLayouts:
                 sharded, reason = (), "elementwise"
         nbytes = [x.size * x.dtype.itemsize for x in leaves]
         on_shares = sum(nbytes[i] for i in sharded)
+        _note_shares(on_shares)
         reg = get_registry()
-        reg.gauge("optimizer.sharded_bytes").set(on_shares)
         for r in _REASONS:
             reg.gauge("optimizer.replicated_bytes", reason=r).set(
                 sum(nbytes) - on_shares if r == reason else 0)
@@ -379,12 +412,19 @@ class _UpdateLayouts:
             "%r (x%d), %d replicated (%s)", on_shares, self.scatter, shards,
             sum(nbytes) - on_shares, reason)
         if not sharded:
-            return _UpdateLayout((), (), None, None, P(), None)
+            return _UpdateLayout((), (), None, None, (P(), P()), None)
         front = state.opt_state[:-1]
-        opt_shardings = [None] * len(jax.tree_util.tree_leaves(front)) + [
+        held = set(sharded)
+        param_specs = [P(self.scatter) if i in held else P()
+                       for i in range(len(leaves))]
+        specs = (treedef.unflatten(param_specs),
+                 tuple(P() for _ in front) + (inner_specs,))
+        shardings = [
             None if spec == P() else self._on_shares
-            for spec in jax.tree_util.tree_leaves(inner_specs)]
-        rest = tuple(i for i in range(len(leaves)) if i not in set(sharded))
+            for spec in param_specs
+            + [P()] * len(jax.tree_util.tree_leaves(front))
+            + jax.tree_util.tree_leaves(inner_specs)]
+        rest = tuple(i for i in range(len(leaves)) if i not in held)
         rest_plan = partition.plan_buckets(
             [leaves[i] for i in rest], self.partition_bytes) if rest else None
         # (numbered after the rest's, whose flat pack names its buckets
@@ -392,24 +432,37 @@ class _UpdateLayouts:
         plan = partition.plan_share_buckets(
             [leaves[i] for i in sharded], shards, self.partition_bytes,
             first_id=rest_plan.num_buckets if rest else 0)
-        return _UpdateLayout(sharded, rest, plan, rest_plan,
-                             tuple(P() for _ in front) + (inner_specs,),
-                             opt_shardings, update)
+        return _UpdateLayout(sharded, rest, plan, rest_plan, specs,
+                             shardings, update)
 
-    def sharded_update(self, lay: _UpdateLayout, grads, opt_state, params):
+    def gather_shares(self, lay: _UpdateLayout, params):
+        """The whole parameters from ``params`` as they lie between steps:
+        the sharded leaves' shares all-gathered, bucket by bucket in
+        priority order, the rest as they are.  Runs under the step's
+        shard_map, ahead of the forward that needs them."""
+        p, treedef = jax.tree_util.tree_flatten(params)
+        gathered = all_gather_tree(
+            [p[i] for i in lay.sharded], lay.plan, self.scatter)
+        for i, x in zip(lay.sharded, gathered):
+            p[i] = x
+        return treedef.unflatten(p)
+
+    def sharded_update(self, lay: _UpdateLayout, grads, opt_state, whole,
+                       params):
         """``tx.update`` + ``apply_updates`` with the reduction split
         around the optimizer: what precedes the push_pull link runs as
-        the chain runs it, each bucket is reduce-scattered, the caller's
-        optimizer updates this worker's dim-0 share of every sharded leaf
-        (and the other leaves whole, from a reduction of today's kind),
-        and the new shares are all-gathered into whole parameters.
-        Runs under the step's shard_map; returns ``(params, opt_state)``."""
+        the chain runs it (on the ``whole`` parameters), each bucket is
+        reduce-scattered, and the caller's optimizer updates this
+        worker's dim-0 share of every sharded leaf — ``params`` holds the
+        shares, as they lie between steps — and the other leaves whole,
+        from a reduction of today's kind.  Runs under the step's
+        shard_map; returns ``(params, opt_state)``, the new parameters on
+        their shares."""
         front = self.links[:-2]
         states = list(opt_state)
         for k, link in enumerate(front):
-            grads, states[k] = link.update(grads, states[k], params)
+            grads, states[k] = link.update(grads, states[k], whole)
         g, treedef = jax.tree_util.tree_flatten(grads)
-        p = treedef.flatten_up_to(params)
         shares = reduce_scatter_tree(
             [g[i] for i in lay.sharded], lay.plan, self.scatter, self.sums)
         if lay.rest:
@@ -419,37 +472,28 @@ class _UpdateLayouts:
             for i, x in zip(lay.rest, reduced):
                 g[i] = x
         with jax.named_scope(SCOPE_OPTIMIZER):
-            r = jax.lax.axis_index(self.scatter)
             for i, x in zip(lay.sharded, shares):
                 g[i] = x
-                p[i] = jax.lax.dynamic_slice_in_dim(
-                    p[i], r * x.shape[0], x.shape[0], axis=0,
-                    allow_negative_indices=False)
-            p_mixed = treedef.unflatten(p)
             # (the program ``_build`` traced and found elementwise)
             updates, states[-1] = lay.update(
-                treedef.unflatten(g), states[-1], p_mixed)
-            new = treedef.flatten_up_to(
-                optax.apply_updates(p_mixed, updates))
-        gathered = all_gather_tree(
-            [new[i] for i in lay.sharded], lay.plan, self.scatter)
-        for i, x in zip(lay.sharded, gathered):
-            new[i] = x
-        return treedef.unflatten(new), tuple(states)
+                treedef.unflatten(g), states[-1], params)
+            new = optax.apply_updates(params, updates)
+        return new, tuple(states)
 
     def place(self, state, donate: bool):
-        """``state`` with every leaf of the optimizer's state that the
-        program wants on dim-0 shares put there: a local slice of a
-        replicated leaf, its whole copy given up leaf by leaf where the
-        step would donate it anyway.  A leaf already there is left alone
-        — all of them, from the step's second call on — and the check
-        touches no device."""
+        """``state`` with every leaf of the parameters and of the
+        optimizer's state that the program wants on dim-0 shares put
+        there: a local slice of a replicated leaf, its whole copy given
+        up leaf by leaf where the step would donate it anyway.  A leaf
+        already there is left alone — all of them, from the step's
+        second call on — and the check touches no device."""
         lay = self.of(state)
-        if lay.opt_shardings is None:
+        if lay.shardings is None:
             return state
-        leaves, treedef = jax.tree_util.tree_flatten(state.opt_state)
+        leaves, treedef = jax.tree_util.tree_flatten(
+            (state.params, state.opt_state))
         moved = False
-        for k, want in enumerate(lay.opt_shardings):
+        for k, want in enumerate(lay.shardings):
             x = leaves[k]
             have = getattr(x, "sharding", None)
             if want is None or have == want or (
@@ -465,21 +509,24 @@ class _UpdateLayouts:
             moved = True
         if not moved:
             return state
-        return state._replace(opt_state=treedef.unflatten(leaves))
+        params, opt_state = treedef.unflatten(leaves)
+        return state._replace(params=params, opt_state=opt_state)
 
     def abstract(self, state):
         """``state`` as the program is lowered against it, whatever it
         was handed (concrete, or abstract and replicated as
-        ``benchmark/aot_check.py`` builds it): a share's moments on
-        their shares, every other leaf as it came."""
+        ``benchmark/aot_check.py`` builds it): a share's parameter and
+        moments on their shares, every other leaf as it came."""
         lay = self.of(state)
-        if lay.opt_shardings is None:
+        if lay.shardings is None:
             return state
-        leaves, treedef = jax.tree_util.tree_flatten(state.opt_state)
-        return state._replace(opt_state=treedef.unflatten([
+        leaves, treedef = jax.tree_util.tree_flatten(
+            (state.params, state.opt_state))
+        params, opt_state = treedef.unflatten([
             x if want is None else jax.ShapeDtypeStruct(
                 x.shape, x.dtype, sharding=want)
-            for x, want in zip(leaves, lay.opt_shardings)]))
+            for x, want in zip(leaves, lay.shardings)])
+        return state._replace(params=params, opt_state=opt_state)
 
 
 # counts of steps already dispatched whose values the device has yet to
@@ -571,6 +618,15 @@ class TrainStep:
     def init_state(self, params, model_state=None) -> TrainState:
         state = create_train_state(params, self.tx, model_state=model_state)
         return self._place(replicate_state(state, self.mesh))
+
+    def whole_params(self, state):
+        """``state.params`` whole: every leaf the program keeps on its
+        dim-0 shares between steps all-gathered to a replicated array of
+        its global shape, the rest as they lie.  The way to read the
+        parameters outside the step; in a multi-process job no process
+        holds a share's leaf whole, and the gather is a collective: EVERY
+        process calls this, in the same order."""
+        return whole_on_every_process(state.params)
 
     def lower(self, state, batch):
         if self._layouts is not None:

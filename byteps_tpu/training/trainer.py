@@ -31,7 +31,6 @@ from .checkpoint import CheckpointManager
 from .step import (
     TrainState,
     make_data_parallel_step,
-    replicate_state,
     shard_batch,
 )
 
@@ -148,7 +147,8 @@ class Trainer:
             # registers + does the first-push-wins initial push (reference
             # InitTensor's blocking initial push, operations.cc:262-284)
             self._async_worker = AsyncWorker(
-                self.async_store, jax.device_get(state.params),
+                self.async_store,
+                jax.device_get(self.step_fn.whole_params(state)),
                 worker_id=self.worker_id,
             )
         return state
@@ -204,7 +204,8 @@ class Trainer:
                 # non-donated copy: the step donates state buffers, so the
                 # background thread must not read state.params directly
                 self._async_worker.begin_push_pull(
-                    jax.tree_util.tree_map(jnp.copy, state.params))
+                    jax.tree_util.tree_map(
+                        jnp.copy, self.step_fn.whole_params(state)))
             if self.log_every and seen % self.log_every == 0:
                 avg = average_metrics(
                     {k: v for k, v in metrics.items()}
@@ -238,9 +239,10 @@ class Trainer:
         if not self._async_worker.exchange_in_flight():
             return state
         pulled, submitted = self._async_worker.take_result()
+        # the delta lies where its leaf lies (on dim-0 shares or whole)
         new_params = jax.tree_util.tree_map(
-            lambda x, p, s: x + replicate_state(
-                jnp.asarray(p - s), self.mesh).astype(x.dtype),
+            lambda x, p, s: x + jax.device_put(
+                jnp.asarray(p - s), x.sharding).astype(x.dtype),
             state.params, pulled, submitted)
         return state._replace(params=new_params)
 

@@ -230,7 +230,7 @@ TRAIN_KERNELS = ("flash_fwd", "flash_bwd_dq_flash_bwd_dkv",
 def train_leg(rep: Reporter, size: Size, rehearse: bool) -> None:
     import jax
     import numpy as np
-    from jax.sharding import Mesh
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
     import byteps_tpu as bps
     from byteps_tpu.common import partition
@@ -268,11 +268,16 @@ def train_leg(rep: Reporter, size: Size, rehearse: bool) -> None:
                      batch["tokens"].addressable_shards}
         check(len(shard_dev) == world,
               f"batch shards sit on {len(shard_dev)} distinct devices")
+        # (a leaf whose dim 0 divides lies on its dim-0 shares between
+        # steps, any other replicated)
+        on_shares = NamedSharding(mesh, P(mesh.axis_names[-1]))
         for leaf in jax.tree_util.tree_leaves(state.params):
-            check(leaf.sharding.is_fully_replicated
-                  and len(leaf.sharding.device_set) == world,
-                  f"a parameter is not replicated on all {world} chips: "
-                  f"{leaf.sharding}")
+            check(len(leaf.sharding.device_set) == world
+                  and (leaf.sharding.is_fully_replicated
+                       or leaf.sharding.is_equivalent_to(on_shares,
+                                                         leaf.ndim)),
+                  f"a parameter is neither replicated on all {world} chips "
+                  f"nor on their dim-0 shares: {leaf.sharding}")
         # one cross-replica reduction per bucket leaves the tracer; what
         # XLA's combiner passes make of them is reported, not asserted
         n_buckets = partition.plan_buckets(

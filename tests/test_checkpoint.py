@@ -94,16 +94,10 @@ def test_resume_training_continuity(tmp_path):
                                rtol=1e-6)
 
 
-def test_sharded_update_resumes_from_a_checkpoint_at_world_4(tmp_path):
-    """The data-parallel step at world 4 keeps a share's moments on dim-0
-    shares; a checkpoint holds them whole (logical shapes, the chain's
-    treedef), the restore broadcasts them replicated, and the step's
-    first call puts them back on their shares: the three losses after a
-    save / restore equal an uninterrupted run's."""
-    from jax.sharding import PartitionSpec
-
+def _world4_step():
+    """``(step, params, batch, mesh)``: the data-parallel step at world 4
+    over a tree whose every leaf divides by 4, and its batches."""
     from byteps_tpu.training import make_data_parallel_step, shard_batch
-    from byteps_tpu.training.step import TrainState
 
     bps.init(devices=jax.devices()[:4])
     mesh = bps.mesh()
@@ -124,6 +118,21 @@ def test_sharded_update_resumes_from_a_checkpoint_at_world_4(tmp_path):
 
     step = make_data_parallel_step(loss_fn, optax.adamw(1e-2), mesh,
                                    partition_bytes=256)
+    return step, params, batch, mesh
+
+
+def test_sharded_update_resumes_from_a_checkpoint_at_world_4(tmp_path):
+    """The data-parallel step at world 4 keeps a share's parameter and
+    moments on dim-0 shares; a checkpoint holds them whole (logical
+    shapes, the chain's treedef), the restore broadcasts them
+    replicated, and the step's first call puts them back on their
+    shares: the three losses after a save / restore equal an
+    uninterrupted run's."""
+    from jax.sharding import PartitionSpec
+
+    from byteps_tpu.training.step import TrainState
+
+    step, params, batch, _ = _world4_step()
 
     def run(state, first, n):
         losses = []
@@ -138,6 +147,7 @@ def test_sharded_update_resumes_from_a_checkpoint_at_world_4(tmp_path):
     assert head == want[:3]
     mu = state.opt_state[-1][0].mu["w1"]
     assert mu.sharding.spec == PartitionSpec("dp") and mu.shape == (8, 32)
+    assert state.params["w1"].sharding.spec == PartitionSpec("dp")
     path = save_checkpoint(str(tmp_path / "mid"), tuple(state))
     template = step.init_state(params)
     restored = restore_checkpoint(path, template=tuple(template),
@@ -147,11 +157,40 @@ def test_sharded_update_resumes_from_a_checkpoint_at_world_4(tmp_path):
         jax.tree_util.tree_leaves(restored))
     assert isinstance(restored, TrainState)
     assert restored.opt_state[-1][0].mu["w1"].sharding.is_fully_replicated
+    assert restored.params["w1"].sharding.is_fully_replicated
     resumed, tail = run(restored, 3, 3)
     assert tail == want[3:]
     assert resumed.opt_state[-1][0].mu["w1"].sharding.spec == (
         PartitionSpec("dp"))
+    assert resumed.params["w1"].sharding.spec == PartitionSpec("dp")
     assert int(resumed.step) == 6
+
+
+def test_parameters_on_shares_round_trip_whole(tmp_path):
+    """After a step at world 4 every parameter lies on its dim-0 shares;
+    ``save_checkpoint`` writes each whole, at its logical shape, and
+    ``restore_checkpoint`` gives back the same values bit for bit, as
+    the gathered state reads them."""
+    from jax.sharding import PartitionSpec
+
+    from byteps_tpu.training.checkpoint import whole_on_every_process
+
+    step, params, batch, _ = _world4_step()
+    state, _ = step(step.init_state(params), batch(0))
+    for leaf in jax.tree_util.tree_leaves(state.params):
+        assert leaf.sharding.spec == PartitionSpec("dp")
+        assert not leaf.is_fully_replicated
+    path = save_checkpoint(str(tmp_path / "shares"), tuple(state))
+    restored = restore_checkpoint(path, broadcast=False)
+    whole = whole_on_every_process(state.params)
+    for k, x in whole.items():
+        assert x.is_fully_replicated
+        got = np.asarray(restored[0][k])
+        assert got.shape == params[k].shape
+        np.testing.assert_array_equal(got, np.asarray(x))
+    # the save took nothing from the state: the run goes on
+    state, m = step(state, batch(1))
+    assert np.isfinite(float(m["loss"])) and int(state.step) == 2
 
 
 def test_save_gathers_sharded_leaves_before_the_root_only_write(

@@ -184,6 +184,12 @@ _SHARDED_SAVE_WORKER = textwrap.dedent(
     mu = state.opt_state[-1][0].mu["w1"]
     assert mu.shape == (8, 32)
     assert not mu.is_fully_addressable and not mu.is_fully_replicated
+    # ... and the parameters, between steps
+    w1 = state.params["w1"]
+    assert w1.shape == (8, 32)
+    assert not w1.is_fully_addressable and not w1.is_fully_replicated
+    # the step's accessor gathers them whole, on every process
+    host = jax.device_get(step.whole_params(state))
 
     # every process calls; the gather inside is a collective, the write
     # is the root's
@@ -196,9 +202,21 @@ _SHARDED_SAVE_WORKER = textwrap.dedent(
             assert np.asarray(a).shape == b.shape
             np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
+    for k, x in host.items():
+        assert isinstance(x, np.ndarray) and x.shape == params[k].shape
+        np.testing.assert_array_equal(x, np.asarray(whole.params[k]))
+
     # the save took nothing from the state: the run goes on
     state, m = step(state, batch)
     assert np.isfinite(float(m["loss"])) and int(state.step) == 2
+
+    # a broadcast from the root reads the shares whole first; the next
+    # step puts them back on their shares
+    state = bps.broadcast_parameters(state, root_rank=0)
+    assert state.params["w1"].is_fully_replicated
+    state, m = step(state, batch)
+    assert np.isfinite(float(m["loss"])) and int(state.step) == 3
+    assert not state.params["w1"].is_fully_replicated
 
     print(f"SAVE_WORKER_{jax.process_index()}_OK")
     bps.shutdown()
@@ -266,10 +284,12 @@ def test_two_process_push_pull(tmp_path):
 
 @pytest.mark.slow
 def test_two_process_save_of_dim0_sharded_moments(tmp_path):
-    """The data-parallel step keeps a share's moments ``P(dp)``; across
-    two real processes no one of them can read such a leaf whole, so
-    ``save_checkpoint`` (called by both, written by the root) gathers
-    first — and the checkpoint holds every leaf whole."""
+    """The data-parallel step keeps a share's parameter and moments
+    ``P(dp)``; across two real processes no one of them can read such a
+    leaf whole, so ``save_checkpoint`` (called by both, written by the
+    root), the trainer's host read of the parameters and
+    ``broadcast_parameters`` gather first — and the checkpoint holds
+    every leaf whole."""
     _run_two_workers(tmp_path, _SHARDED_SAVE_WORKER, "SAVE_WORKER_{wid}_OK")
 
 

@@ -7,8 +7,11 @@ compiles (``is_scheduled=true`` — instruction order in the entry
 computation is the order of issue), and hold two structural properties of
 the steps:
 
-  * synchronous step: collectives are issued with backward compute still
-    scheduled behind them (nothing forces them to the end of the program);
+  * synchronous step: the gradient collectives are issued with backward
+    compute still scheduled behind them (nothing forces them to the end
+    of the program), and the parameter all-gathers — the parameters lie
+    on their dim-0 shares between steps — are all issued ahead of the
+    backward pass's first reduce-scatter;
   * delayed-grad step: the whole reduce chain (through the final all-gather)
     is issued with the batch's forward+backward still pending — impossible
     for a synchronous step, whose update is terminal.
@@ -35,6 +38,9 @@ from byteps_tpu.training.step import create_train_state
 
 COLLECTIVES = ("all-reduce", "all-gather", "reduce-scatter",
                "collective-permute")
+# what the backward pass and the step's metrics issue: every collective
+# but the forward's parameter gathers
+GRADIENT_COLLECTIVES = ("all-reduce", "reduce-scatter", "collective-permute")
 COMPUTE = ("fusion", "dot", "convolution", "custom-call")
 
 
@@ -60,11 +66,12 @@ def entry_schedule(compiled_text: str):
     return events
 
 
-def overlap_stats(compiled_text: str):
+def overlap_stats(compiled_text: str, kinds=COLLECTIVES):
     """(first grad-collective index, #compute before it, #compute after it,
-    last collective index, #compute after last collective)."""
+    last collective index, #compute after last collective), over the
+    collectives of ``kinds``."""
     ev = entry_schedule(compiled_text)
-    coll = [i for i, o in ev if o.startswith(COLLECTIVES)]
+    coll = [i for i, o in ev if o.startswith(kinds)]
     comp = [i for i, o in ev if o in COMPUTE]
     assert coll, "no collectives in compiled module"
     assert comp, "no compute in compiled module"
@@ -109,12 +116,39 @@ def test_sync_step_buckets_straddle_backward(mesh):
     txt = step._fn.lower(state, _BATCH).compile().as_text()
     assert "is_scheduled=true" in txt
 
-    first, before, after, _, _ = overlap_stats(txt)
+    first, before, after, _, _ = overlap_stats(txt, GRADIENT_COLLECTIVES)
     # schedule sandwiches the collectives: real compute on both sides
     assert before >= 2, f"no compute before first collective (idx {first})"
     assert after >= 3, (
         f"collectives scheduled after essentially all compute "
         f"({after} compute ops after)")
+
+
+@pytest.mark.parametrize("partition_bytes", [4096, 1 << 22])
+def test_sync_step_gathers_the_parameters_ahead_of_the_backward(
+        mesh, partition_bytes):
+    """Data-parallel step, parameters on their dim-0 shares between
+    steps: every parameter all-gather is issued before the backward
+    pass's first reduce-scatter, and none after its last — the gathers
+    belong to the forward, nothing of the update is left to gather."""
+    tx = optax.sgd(0.1, momentum=0.9)
+    step = make_data_parallel_step(_loss_fn, tx, mesh,
+                                   partition_bytes=partition_bytes)
+    state = jax.eval_shape(lambda p: create_train_state(p, step.tx), _PARAMS)
+    txt = step.lower(state, _BATCH).compile().as_text()
+    assert "is_scheduled=true" in txt
+    ev = entry_schedule(txt)
+    gathers = [i for i, o in ev if o.startswith("all-gather")]
+    scatters = [i for i, o in ev if o.startswith("reduce-scatter")]
+    assert gathers and scatters
+    # one gather and one scatter a bucket
+    assert len(gathers) == len(scatters)
+    assert max(gathers) < min(scatters), (gathers, scatters)
+    assert not [i for i in gathers if i > max(scatters)]
+    # and the forward's compute lies between the last gather and the
+    # first scatter
+    assert sum(1 for i, o in ev if o in COMPUTE
+               and max(gathers) < i < min(scatters)) >= 3
 
 
 def test_delayed_step_collectives_straddle_whole_batch_compute(mesh):

@@ -158,10 +158,10 @@ STAGE_SCOPES = (
 
 def test_every_op_of_the_step_lies_under_one_of_the_seven_scopes():
     """``benchmark/harness/scopes.py`` knows seven stages and reads
-    anything else as ``unscoped``: the sharded update's own ops — the
-    parameter shares cut out for the optimizer, the small leaves' new
-    shares packed, the all-gathers, both unpacks — each carry one of
-    them."""
+    anything else as ``unscoped``: the sharded step's own ops — the
+    small leaves' shares packed for the forward's all-gathers, the
+    all-gathers, both unpacks, the update of the shares — each carry one
+    of them."""
     # the compiled program's ``op_name``s: the whole name stack of each
     # instruction, as the device trace carries it
     text = lowered_step(4)[0].compile().as_text()
@@ -182,8 +182,10 @@ def test_every_op_of_the_step_lies_under_one_of_the_seven_scopes():
     assert {"reduce_scatter", "all_gather", "div"} <= ops(
         tracing.SCOPE_PUSH_PULL + "/reduce/")
     assert "slice" in ops(tracing.SCOPE_UNPACK)
-    # the optimizer cuts its share out of the replicated parameters
-    assert {"axis_index", "dynamic_slice", "sqrt"} <= ops(
+    # the optimizer updates the parameter shares as they lie between
+    # steps: nothing is cut out of a whole parameter
+    assert "sqrt" in ops(tracing.SCOPE_OPTIMIZER)
+    assert not {"axis_index", "dynamic_slice"} & ops(
         tracing.SCOPE_OPTIMIZER)
 
 

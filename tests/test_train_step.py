@@ -238,6 +238,9 @@ def _step_run(tx, mesh, steps, **kw):
 
 def _gauges():
     g = get_registry().snapshot()["gauges"]
+    # every byte updated on shares lies on them between steps too, and
+    # is gathered in the next forward
+    assert g["optimizer.forward_gather_bytes"] == g["optimizer.sharded_bytes"]
     return g["optimizer.sharded_bytes"], {
         k.split("reason=")[1].rstrip("}"): v for k, v in g.items()
         if k.startswith("optimizer.replicated_bytes") and v}
@@ -269,10 +272,10 @@ def _ndim_mask(params):
     ("sgd-momentum", optax.sgd(0.05, momentum=0.9)),
 ])
 def test_sharded_update_equals_the_replicated_path(name, tx):
-    """After 3 steps at world 4: parameters and (gathered) moments equal
-    the replicated path's, the losses match, parameters stay replicated,
-    the moments of dividing leaves lie on dim-0 shares and the leaf that
-    does not divide stays whole on every worker."""
+    """After 3 steps at world 4: parameters and moments (both gathered)
+    equal the replicated path's, the losses match, the parameters and
+    moments of dividing leaves lie on dim-0 shares between steps and the
+    leaf that does not divide stays whole on every worker."""
     mesh = _mesh(WORLD)
     got, losses, _ = _step_run(tx, mesh, 3)
     want, want_losses = _replicated_run(tx, mesh, 3)
@@ -282,10 +285,9 @@ def test_sharded_update_equals_the_replicated_path(name, tx):
     _assert_close_ulp(got.opt_state, want.opt_state, maxulp=8)
     assert (jax.tree_util.tree_structure(got.opt_state)
             == jax.tree_util.tree_structure(want.opt_state))
-    for leaf in jax.tree_util.tree_leaves(got.params):
-        assert leaf.sharding.is_fully_replicated
     on_shares = NamedSharding(mesh, PartitionSpec("dp"))
-    for path, leaf in jax.tree_util.tree_leaves_with_path(got.opt_state):
+    for path, leaf in jax.tree_util.tree_leaves_with_path(
+            (got.params, got.opt_state)):
         whole = leaf.ndim == 0 or "odd" in jax.tree_util.keystr(path)
         assert leaf.sharding.is_fully_replicated == whole, path
         if not whole:
@@ -341,6 +343,65 @@ def test_what_a_share_cannot_do_stays_on_the_replicated_path(
         reason: sum(v.size * 4 for v in _share_params().values())}
 
 
+def test_the_world1_step_keeps_whole_parameters_and_gathers_nothing():
+    """At world 1 the step runs no sharded update: its program takes
+    ``P()`` parameters, gives them back whole, and holds no all-gather;
+    the forward gathers nothing."""
+    mesh = _mesh(1)
+    step = make_data_parallel_step(_share_loss, optax.adamw(1e-2), mesh,
+                                   partition_bytes=PB)
+    gauges = get_registry().snapshot()["gauges"]
+    assert gauges["optimizer.forward_gather_bytes"] == 0
+    assert gauges["optimizer.sharded_bytes"] == 0
+    state = step.init_state(_share_params())
+    compiled = step.lower(state, _share_batch(mesh)).compile()
+    assert "all-gather" not in compiled.as_text()
+    for sharding in jax.tree_util.tree_leaves(
+            compiled.input_shardings[0][0].params):
+        assert sharding.is_equivalent_to(
+            NamedSharding(mesh, PartitionSpec()), 2)
+    state, _ = compiled(state, _share_batch(mesh))
+    for leaf in jax.tree_util.tree_leaves(state.params):
+        assert leaf.sharding.is_fully_replicated
+
+
+def test_whole_params_reads_the_shares_whole_and_an_exchange_keeps_them():
+    """``TrainStep.whole_params`` gives every parameter whole on every
+    worker, at the values its shares hold; the async trainer's catch-up
+    (``params += pulled - submitted``) adds its delta where each leaf
+    lies, so the next step finds the state already placed."""
+    from byteps_tpu.training.trainer import Trainer
+
+    mesh = _mesh(WORLD)
+    trainer = Trainer(_share_loss, optax.adamw(1e-2), mesh=mesh,
+                      log_every=0)
+    step = trainer.step_fn
+    state, _ = step(trainer.init_state(_share_params(), {}),
+                    _share_batch(mesh))
+    assert not state.params["w1"].sharding.is_fully_replicated
+    whole = step.whole_params(state)
+    host = jax.device_get(whole)
+    for k, x in whole.items():
+        assert x.sharding.is_fully_replicated and x.shape == host[k].shape
+        np.testing.assert_array_equal(host[k], np.asarray(state.params[k]))
+
+    class _Exchange:             # one pulled state, one unit ahead
+        def exchange_in_flight(self):
+            return True
+
+        def take_result(self):
+            return {k: v + 1 for k, v in host.items()}, host
+
+    trainer._async_worker = _Exchange()
+    adopted = trainer._adopt_exchange(state)
+    trainer._async_worker = None
+    for k, x in adopted.params.items():
+        assert x.sharding.is_equivalent_to(state.params[k].sharding, x.ndim)
+        np.testing.assert_allclose(np.asarray(x), host[k] + 1,
+                                   rtol=1e-6, atol=1e-6)
+    assert step._place(adopted) is adopted
+
+
 @pytest.mark.parametrize("abstract", [False, True],
                          ids=["concrete-state", "abstract-P()-state"])
 def test_the_benchmarks_call_pattern(abstract):
@@ -349,8 +410,9 @@ def test_the_benchmarks_call_pattern(abstract):
     ``out_shardings=P()``, ``step.lower(state, batch).compile()``, the
     compiled step fed its own output — and ``benchmark/aot_check.py``'s
     variant, lowering against abstract ``P()`` state.  The compiled
-    program wants the moments on dim-0 shares whatever it was lowered
-    with, and its wrapper puts a replicated state there."""
+    program wants the parameters and moments on dim-0 shares whatever
+    it was lowered with, and its wrapper puts a replicated state
+    there."""
     import functools
 
     mesh = _mesh(WORLD)
@@ -377,14 +439,14 @@ def test_the_benchmarks_call_pattern(abstract):
         state, m = compiled(state, _share_batch(mesh, i))
         np.testing.assert_allclose(float(m["loss"]), want_losses[i],
                                    rtol=1e-6)
-        for leaf in jax.tree_util.tree_leaves(state.params):
-            assert (leaf.sharding.is_fully_replicated
-                    and len(leaf.sharding.device_set) == WORLD)
         mu = state.opt_state[-1][0].mu
-        for k in ("w1", "b1", "w2"):
-            assert mu[k].sharding.is_equivalent_to(on_shares, mu[k].ndim)
-            assert mu[k].shape == _share_params()[k].shape
-        assert mu["odd"].sharding.is_fully_replicated
+        for tree in (state.params, mu):
+            for k in ("w1", "b1", "w2"):
+                assert tree[k].sharding.is_equivalent_to(on_shares,
+                                                         tree[k].ndim)
+                assert tree[k].shape == _share_params()[k].shape
+            assert (tree["odd"].sharding.is_fully_replicated
+                    and len(tree["odd"].sharding.device_set) == WORLD)
     for a, b in zip(jax.tree_util.tree_leaves(state.params),
                     jax.tree_util.tree_leaves(want.params)):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-5)
