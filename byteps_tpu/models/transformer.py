@@ -1040,9 +1040,10 @@ class ExpertLayer(nn.Module):
     block's normalised attention input under ``moe_router_pre_attn``).
     A non-gated expert (``moe_act`` of ``parallel/moe.py:UNGATED``) has
     no ``gate`` leaf, and the shared expert takes the same form.
-    Sows the layer's two counts (``assignments_held``,
-    ``rows_computed``) into the ``moe_stats`` collection (when the
-    caller makes it mutable)."""
+    Sows the layer's counts (``assignments_held``, ``rows_computed``,
+    and ``dma_rows{op=...}`` where a gather moves its rows by DMA:
+    ``parallel/moe.py:expert_layer``) into the ``moe_stats`` collection
+    (when the caller makes it mutable)."""
 
     cfg: TransformerConfig
 
@@ -1070,7 +1071,7 @@ class ExpertLayer(nn.Module):
             held=held, axis_name=cfg.moe_ep_axis,
             router_x=None if router_x is None else router_x.reshape(-1, d),
             scoring=cfg.moe_scoring, act=cfg.moe_act)
-        for name, n in zip(("assignments_held", "rows_computed"), counts):
+        for name, n in counts.items():
             self.sow("moe_stats", name, n, reduce_fn=lambda a, b: a + b,
                      init_fn=lambda: jnp.zeros((), jnp.int32))
         y = y.reshape(x.shape)

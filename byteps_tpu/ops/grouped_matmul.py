@@ -402,9 +402,10 @@ grouped_matmul_act.defvjp(_act_fwd, _act_bwd)
 # hold a wanted row (every tile of ``[T, d]``; the active tiles of the
 # buffer) through VMEM and writes them to an HBM copy laid ``[N, p, d /
 # p]`` — row ``n`` whole sublane rows, ``p = 4 / itemsize`` (a 16-bit
-# row travels as its two halves packed together).  Phase two moves each
-# wanted row of that copy by ONE DMA.  Every loop over rows, tokens and
-# ``k`` is a rolled ``pl.loop`` on indices in SMEM, so a kernel lowers
+# row travels as its two halves packed together).  Phase two walks ONE
+# flat list of the rows a tile moves (indices in SMEM) and moves each
+# row of that copy by ONE DMA, the starts eight to a loop step
+# (``_issue``).  Every loop is a rolled ``pl.loop``, so a kernel lowers
 # to the same few kilobytes whatever its tile or ``k``.
 
 
@@ -418,12 +419,25 @@ def dma_rows(d: int, dtype) -> int:
     return d // (4 // size)
 
 
-def _parts(buf, at):
-    """``(columns, [n, d / p])`` a part: the ``[n, p, d / p]`` rows of
-    ``buf`` (``at``: leading indices) back in the row's columns."""
-    per, h = buf.shape[-2:]
-    return [(slice(a * h, (a + 1) * h), buf[(*at, slice(None), a)])
-            for a in range(per)]
+def _parts(buf, rows=slice(None)):
+    """``(columns, [n, d / p])`` a part: the ``[n, p, d / p]`` rows
+    ``rows`` of ``buf`` back in the row's columns."""
+    per, h = buf.shape[1:]
+    return [(slice(a * h, (a + 1) * h), buf[rows, a]) for a in range(per)]
+
+
+def _issue(n, start):
+    """``start(e)`` for every ``e`` in ``[0, n)``: a rolled loop whose
+    step makes eight calls (Mosaic unrolls a ``pl.loop`` whole or not at
+    all), then one a step for the last ``n % 8``."""
+    @pl.loop(0, n // 8)
+    def _(b):
+        for u in range(8):
+            start(b * 8 + u)
+
+    @pl.loop(n - n % 8, n)
+    def _(e):
+        start(e)
 
 
 def _relay(tile_ref, lay, copy_ref, first, sem):
@@ -457,14 +471,11 @@ def _row_gather_kernel(n_active_ref, tok_ref, src_ref, o_ref, copy_ref,
 
     @pl.when((j >= 0) & (j < n_active_ref[0]))
     def _():
-        @pl.loop(0, tm)
-        def _(r):
-            pltpu.make_async_copy(copy_ref.at[tok_ref[0, r]], buf.at[r],
-                                  sem).start()
-
+        _issue(tm, lambda r: pltpu.make_async_copy(
+            copy_ref.at[tok_ref[0, r]], buf.at[r], sem).start())
         # a DMA semaphore counts bytes: one wait for the whole tile
         pltpu.make_async_copy(buf, buf, sem).wait()
-        for cols, part in _parts(buf, ()):
+        for cols, part in _parts(buf):
             o_ref[:, cols] = part
 
 
@@ -516,12 +527,12 @@ def row_gather(source, tok, n_active, tile: int, interpret=None):
         tok.reshape(R // tile, 1, tile).astype(jnp.int32), source)[0]
 
 
-def _sum_gather_kernel(n_active_ref, row_ref, n_ref, tot_ref, rows_ref,
-                       m_ref, *refs, weighted):
+def _sum_gather_kernel(n_active_ref, row_ref, at_ref, slot_ref, size_ref,
+                       rows_ref, m_ref, *refs, weighted):
     w_ref = refs[0] if weighted else None
     o_ref, copy_ref, lay, buf, acc, sem = refs[-6:]
-    i, tm = pl.program_id(0), lay.shape[0]
-    k, tt = buf.shape[0], buf.shape[1]
+    i, tm, tt = pl.program_id(0), lay.shape[0], acc.shape[0]
+    k = buf.shape[0] // tt
     j = i - copy_ref.shape[0] // tm
 
     @pl.when(i < n_active_ref[0])
@@ -530,18 +541,16 @@ def _sum_gather_kernel(n_active_ref, row_ref, n_ref, tot_ref, rows_ref,
 
     @pl.when(j >= 0)
     def _():
-        # token t's held rows, in the order j = 0 ... k - 1, to slots
-        # 0 ... n_t - 1: no loop step for a row not held
-        @pl.loop(0, tt)
-        def _(t):
-            @pl.loop(0, n_ref[0, t])
-            def _(q):
-                pltpu.make_async_copy(copy_ref.at[row_ref[0, t * k + q]],
-                                      buf.at[q, t], sem).start()
+        # the tile's held rows, one list in the order (t, j): token t's
+        # q-th held row (``row[t * k + q]`` of the copy) to slot
+        # ``q * tt + t`` of ``buf`` (``moves``)
+        total, top = size_ref[0, 0], size_ref[0, 1]
+        _issue(total, lambda e: pltpu.make_async_copy(
+            copy_ref.at[row_ref[0, at_ref[0, e]]], buf.at[slot_ref[0, e]],
+            sem).start())
 
         # a DMA semaphore counts bytes: one wait per eight rows moved
-        eight, one = buf.at[0, pl.ds(0, 8)], buf.at[0, pl.ds(0, 1)]
-        total = tot_ref[0, 0]
+        eight, one = buf.at[pl.ds(0, 8)], buf.at[pl.ds(0, 1)]
 
         @pl.loop(0, total // 8)
         def _(_):
@@ -551,12 +560,13 @@ def _sum_gather_kernel(n_active_ref, row_ref, n_ref, tot_ref, rows_ref,
         def _(_):
             pltpu.make_async_copy(one, one, sem).wait()
 
-        # in float32, slot by slot; a slot past n_t adds nothing (it
-        # holds what an earlier tile left there: a select keeps it out)
+        # in float32, slot by slot, up to the tile's fullest token
+        # (``top``); a slot past n_t adds nothing (it holds what an
+        # earlier tile left there: a select keeps it out)
         acc[...] = jnp.zeros(acc.shape, jnp.float32)
         lanes = lax.broadcasted_iota(jnp.int32, (tt, k), 1)
 
-        @pl.loop(0, k)
+        @pl.loop(0, top)
         def _(q):
             pick = lanes == q
             held = jnp.max(jnp.where(pick, m_ref[...], 0), axis=1,
@@ -564,11 +574,16 @@ def _sum_gather_kernel(n_active_ref, row_ref, n_ref, tot_ref, rows_ref,
             if weighted:
                 w = jnp.sum(jnp.where(pick, w_ref[...], 0.0), axis=1,
                             keepdims=True)
-            for cols, part in _parts(buf, (q,)):
+            for cols, part in _parts(buf, pl.ds(q * tt, tt)):
                 term = jnp.where(held, part.astype(jnp.float32), 0.0)
                 acc[:, cols] += term * w if weighted else term
 
-        o_ref[...] = acc[...].astype(o_ref.dtype)
+        # the result is the sum over all k slots, which adds a +0.0 to
+        # a token short of k rows after its last one (a -0.0 becomes
+        # +0.0): the loop leaves that out where it stopped at top < k,
+        # so it is added here (and x + -0.0 is x)
+        zero = jnp.where(top < k, 0.0, -0.0)
+        o_ref[...] = (acc[...] + zero).astype(o_ref.dtype)
 
 
 @functools.lru_cache(maxsize=64)
@@ -592,15 +607,16 @@ def _sum_gather_call(T, R, d, k, tm, dtype, weighted, interpret):
         name="sum_gather",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1, grid=(row_tiles + T // tt,),
-            # the token tile's held rows, their counts and its total in
-            # SMEM a tile at a time (as in row_gather)
-            in_specs=[smem(tt * k), smem(tt), smem(1),
+            # the token tile's held rows, its list of moves and its
+            # (length, fullest token) in SMEM a tile at a time (as in
+            # row_gather)
+            in_specs=[smem(tt * k), smem(tt * k), smem(tt * k), smem(2),
                       pl.BlockSpec((tm, d), rows)]
             + [pl.BlockSpec((tt, k), tok)] * (1 + weighted),
             out_specs=[pl.BlockSpec((tt, d), tok),
                        pl.BlockSpec(memory_space=pl.ANY)],
             scratch_shapes=[lay,
-                            pltpu.VMEM((k, tt, per, d // per), dtype),
+                            pltpu.VMEM((k * tt, per, d // per), dtype),
                             pltpu.VMEM((tt, d), jnp.float32),
                             pltpu.SemaphoreType.DMA(())]),
         out_shape=[jax.ShapeDtypeStruct((T, d), dtype), copy],
@@ -613,6 +629,30 @@ def _sum_gather_call(T, R, d, k, tm, dtype, weighted, interpret):
             + 8 * tt * d + 2 * (1 + weighted) * tt * 128 * 4),
         interpret=interpret,
     )
+
+
+def moves(n, k: int, tile: int):
+    """A token tile's held rows as ONE list in the order ``(t, j)``, from
+    ``n [T]`` (token t's held rows) and built without a sort, a scatter
+    or a gather: ``(at, slot [T / tile, tile k], size [T / tile, 2])`` —
+    entry ``e < length`` is row ``q`` of token t, ``at[e] = t k + q``
+    (into the tile's ``[tile, k]`` held rows, token t's first), bound
+    for slot ``slot[e] = q tile + t``; ``size`` is each tile's
+    ``(length, fullest token's n_t)``.  Token t's rows are the entries
+    from its running count's start on, so entry e is the last token's
+    whose start is not past it: one compare and one max over the
+    tile's tokens."""
+    n = n.reshape(-1, tile)
+    ends = jnp.cumsum(n, axis=1)
+    start = ends - n
+    e = jnp.arange(tile * k, dtype=ends.dtype)
+    # [tiles, tile k]: start * tile + t of entry e's token (rising in t)
+    last = jnp.max(jnp.where(start[:, :, None] <= e, (
+        start * tile + jnp.arange(tile, dtype=ends.dtype))[:, :, None], 0),
+        axis=1)
+    t, q = last % tile, e - last // tile
+    return t * k + q, q * tile + t, jnp.stack([ends[:, -1],
+                                               jnp.max(n, axis=1)], axis=1)
 
 
 def sum_gather(rows, dest, held, n_active, weights=None, tile: int = 256,
@@ -637,10 +677,10 @@ def sum_gather(rows, dest, held, n_active, weights=None, tile: int = 256,
 
     m = (jnp.arange(k) < n[:, None]).astype(jnp.int32)
     extra = () if weights is None else (packed(weights.astype(jnp.float32)),)
+    at, slot, size = moves(n, k, tile)
     return _sum_gather_call(T, R, d, k, tile, rows.dtype, weights is not None,
                             interpret)(
         jnp.reshape(n_active, (1,)).astype(jnp.int32),
         packed(dest).astype(jnp.int32).reshape(tiles, 1, tile * k),
-        n.reshape(tiles, 1, tile),
-        jnp.sum(n.reshape(tiles, tile), axis=1).reshape(tiles, 1, 1),
-        rows, m, *extra)[0]
+        at.reshape(tiles, 1, tile * k), slot.reshape(tiles, 1, tile * k),
+        size.reshape(tiles, 1, 2), rows, m, *extra)[0]

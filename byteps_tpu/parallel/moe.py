@@ -101,7 +101,9 @@ layer is traced; counters ``moe.assignments_held`` and ``moe.rows_computed``
 grow by a train step's metrics of those names, step by step
 (``training/step.py``): the first is what the router chose on held
 experts, the second the rows of the buffer that hold a real
-assignment — equal unless something was dropped.
+assignment — equal unless something was dropped.  ``moe.dma_rows``
+(label ``op``) grows the same way, by the rows that gather moved by DMA
+(``moved``): only a layer whose gathers take the kernels reports it.
 """
 
 from __future__ import annotations
@@ -376,6 +378,23 @@ def experts_ffn(rows, gate, up, down, p: Plan, interpret=None,
     return grouped_matmul(h, down.astype(rows.dtype), **where)
 
 
+def moved(x, rows, p: Plan, free: int):
+    """``{op: rows}``: the rows each gather that the shape rule hands to
+    a kernel (``from_hbm``, as ``_gather`` and ``_sum_held`` ask it)
+    moves by DMA a call — every row of the active tiles for ``dispatch``
+    and ``combine_bwd`` (``x [T, d]`` and ``dy`` its like), the held
+    assignments, the lengths of ``sum_gather``'s lists, for
+    ``combine`` and ``dispatch_bwd`` (``rows [R, d]`` and ``drows``).
+    The backward's two are the forward's plan's; a recomputed
+    ``dispatch`` moves its rows again, uncounted."""
+    tile, n = _tile(p), {}
+    if from_hbm(x, free, x.shape[0], tile):
+        n["dispatch"] = n["combine_bwd"] = p.n_active * tile
+    if from_hbm(rows, free, p.dest.shape[0], tile):
+        n["combine"] = n["dispatch_bwd"] = jnp.sum(p.held, dtype=jnp.int32)
+    return n
+
+
 def served(idx, first, count: int, p: Plan):
     """``(chosen, rows)``: the assignments of ``idx [T, k]`` the router
     put on the held experts, counted from ``idx`` alone, and the rows of
@@ -393,7 +412,8 @@ def served(idx, first, count: int, p: Plan):
 def _held_part(x, idx, weights, first, gate, up, down, tile, interpret,
                act):
     """The held experts' part for tokens ``x [T, d]`` already routed
-    (``idx, weights [T, k]``), and its two counts."""
+    (``idx, weights [T, k]``), and its counts by name: ``served``'s two,
+    and ``moved``'s rows as ``dma_rows{op=...}``."""
     count = up.shape[0]
     # the VMEM XLA may hold a gather's source in beside this layer's
     # kernels: the shape rule's (``from_hbm``)
@@ -409,7 +429,11 @@ def _held_part(x, idx, weights, first, gate, up, down, tile, interpret,
         out = experts_ffn(rows, gate, up, down, p, interpret, act)
     with jax.named_scope("combine"):
         y = combine(out, weights, p, free, interpret)
-    return y, served(idx, first, count, p)
+    counts = dict(zip(("assignments_held", "rows_computed"),
+                      served(idx, first, count, p)))
+    for op, n in moved(x, rows, p, free).items():
+        counts[f"dma_rows{{op={op}}}"] = n
+    return y, counts
 
 
 def expert_layer(x, router_kernel, router_bias, gate, up, down, *,
@@ -418,10 +442,12 @@ def expert_layer(x, router_kernel, router_bias, gate, up, down, *,
                  axis_name: Optional[str] = None, tile: int = ROW_TILE,
                  interpret=None, router_x=None, scoring: str = "sigmoid",
                  act: str = "silu"):
-    """``(y [T, d], (chosen, served))``: the routed part of the layer
-    for tokens ``x [T, d]`` — the sum over each token's chosen experts
-    that THIS rank holds (``up.shape[0]`` of them, from ``held[0]``;
-    all of them by default) — and the two counts of ``served``.  The
+    """``(y [T, d], counts)``: the routed part of the layer for tokens
+    ``x [T, d]`` — the sum over each token's chosen experts that THIS
+    rank holds (``up.shape[0]`` of them, from ``held[0]``; all of them
+    by default) — and its counts by name: ``served``'s two
+    (``assignments_held``, ``rows_computed``) and, for each gather that
+    moves its rows by DMA, ``dma_rows{op=...}`` (``moved``).  The
     shared expert is the caller's (every rank computes it alike: it
     counts once).  ``router_x [T, d]`` is what the router scores where
     that is not ``x`` (the experts always read ``x``); ``scoring`` is
@@ -466,4 +492,4 @@ def expert_layer(x, router_kernel, router_bias, gate, up, down, *,
     with jax.named_scope("exchange"):
         back = lax.all_to_all(part.reshape(ranks, T, d), axis_name, 0, 0)
         y = jnp.sum(back.astype(jnp.float32), axis=0).astype(x.dtype)
-    return y, tuple(lax.psum(c, axis_name) for c in counts)
+    return y, {name: lax.psum(c, axis_name) for name, c in counts.items()}

@@ -142,7 +142,8 @@ def make_data_parallel_step(
     ``batch`` is a pytree whose leaves have the global batch on dim 0
     (sharded over ``axes``), and metrics = {"loss": mean loss}.  A
     ``loss_fn`` may return a third element, a dict of counts of its own
-    (``lm_loss_fn``: ``moe_assignments_held``, ``moe_rows_computed``)
+    (``lm_loss_fn``: ``moe_assignments_held``, ``moe_rows_computed``,
+    ``moe_dma_rows{op=...}``)
     or of means (a looped model's ``loop_ce<t>``, ``loop_exit_p<t>``);
     they join the metrics, a count summed over the workers and a mean
     averaged, and every call of the step adds a count to the registry's
@@ -537,17 +538,20 @@ _PENDING: collections.deque = collections.deque()
 def _count_step(counts) -> None:
     """Each count of one step grows the registry counter of its name
     (``moe_assignments_held`` -> ``moe.assignments_held``: the layer's
-    prefix becomes the registry's dotted one), ``train.steps_counted``
+    prefix becomes the registry's dotted one; ``moe_dma_rows{op=combine}``
+    -> ``moe.dma_rows`` labelled ``op=combine``), ``train.steps_counted``
     by one; a mean (``loop_ce1``) sets the gauge of its name to the
     step's value."""
     reg = get_registry()
     reg.counter("train.steps_counted", instants=False).inc()
     for name, n in counts.items():
-        dotted = name.replace("_", ".", 1)
+        dotted, _, label = name.replace("_", ".", 1).partition("{")
+        labels = dict(kv.split("=") for kv in label[:-1].split(",")
+                      if kv)
         if jnp.issubdtype(jnp.result_type(n), jnp.floating):
-            reg.gauge(dotted).set(float(n))
+            reg.gauge(dotted, **labels).set(float(n))
         else:
-            reg.counter(dotted, instants=False).inc(int(n))
+            reg.counter(dotted, instants=False, **labels).inc(int(n))
 
 
 def flush_step_counts(wait: bool = True) -> None:
@@ -811,8 +815,9 @@ def lm_loss_fn(model, fused_head: bool = False,
     module's cross-entropy on the token after next, through the same
     head; where it has expert layers the closure returns a third
     element, ``{"moe_assignments_held": n, "moe_rows_computed": r}``
-    (``parallel/moe.py:served``, summed over its expert layers), which
-    the step reports beside ``loss``.  A looped model
+    (``parallel/moe.py:served``, summed over its expert layers; with
+    ``moe_dma_rows{op=...}`` where a gather moves its rows by DMA:
+    ``moved``), which the step reports beside ``loss``.  A looped model
     (``cfg.loop_steps`` R > 1) reads its R states out through the same
     head (``loop_readouts``) and returns the exit distribution's
     expected cross-entropy less ``EXIT_ENTROPY_WEIGHT`` times its

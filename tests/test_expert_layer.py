@@ -254,7 +254,8 @@ def test_no_assignment_is_dropped_when_every_token_picks_one_expert(tile):
     w = layer_weights(10)
     w["router"]["bias"] = jnp.zeros((E,)).at[2].set(10.0)
     x = jax.random.normal(jax.random.PRNGKey(11), (300, D))
-    y, (n, served) = jax.jit(lambda x: program_layer(x, w, tile=tile))(x)
+    y, counts = jax.jit(lambda x: program_layer(x, w, tile=tile))(x)
+    n, served = counts["assignments_held"], counts["rows_computed"]
     idx, p = jax.jit(lambda x: (lambda idx: (idx, moe.plan(
         idx, 0, E, tile)))(moe.route(x, w["router"]["kernel"],
                                      w["router"]["bias"], K, 2.5)[0]))(x)
@@ -344,7 +345,8 @@ def test_the_ep_exchange_equals_the_single_rank_layer():
             w["experts"], x)
         (_, (y2, c2)), g2 = jax.jit(jax.value_and_grad(
             sharded, has_aux=True))(w["experts"], x)
-    assert [int(c) for c in c1 + c2] == [x.shape[0] * K] * 4
+    assert [int(c) for c in (*c1.values(), *c2.values())] == [
+        x.shape[0] * K] * 4
     np.testing.assert_allclose(y2, y1, rtol=1e-4, atol=1e-5)
     for a, b in zip(jax.tree_util.tree_leaves(g2),
                     jax.tree_util.tree_leaves(g1)):
@@ -468,8 +470,9 @@ def test_the_activation_is_looked_up_when_the_layer_is_traced(monkeypatch):
 def test_layer_counters_are_in_the_registry():
     w = layer_weights(17)
     x = jax.random.normal(jax.random.PRNGKey(18), (40, D))
-    _, (n, served) = jax.jit(
+    _, counts = jax.jit(
         lambda x: program_layer(x, w, held=(4, 8), tile=8))(x)
+    n, served = counts["assignments_held"], counts["rows_computed"]
     reg = get_registry()
     gauges = reg.snapshot()["gauges"]
     rows = moe.buffer_rows(40, K, 8, 8)
@@ -690,24 +693,52 @@ def test_expert_calls_gauge_is_what_a_layer_traces(form):
 
 GD = 256            # a width whose rows move alone in bf16 and float32
 
+# how many of token t's k assignments fall on the held experts (None: as
+# the router chooses) — the cases the flat walk of a tile's held rows
+# must get right, tile (8 tokens) by tile
+HELD = {
+    "routed": None,
+    # lists of 17, 14, 16, 18 ... rows at k 4: most not a multiple of 8
+    "ragged": lambda t, k: (3 * t + 1) % (k + 1),
+    # the second tile moves nothing
+    "empty_tile": lambda t, k: 0 if 8 <= t < 16 else (3 * t + 1) % (k + 1),
+    # each tile's first token holds all k
+    "full_token": lambda t, k: k if t % 8 == 0 else t % k,
+    # no token holds more than one: the sum walks one slot
+    "one_a_token": lambda t, k: t % 2,
+}
 
-def dma_case(k, dtype, seed=30):
-    """``(x, weights, plan, experts)`` at ``GD``: tokens partly held
-    (FIRST .. FIRST + 8 of ALL), one held group empty, tiles past
-    ``n_active``."""
+
+def dma_case(k, dtype, seed=30, d=GD, held="routed"):
+    """``(x, weights, plan, experts)`` at width ``d``: tokens partly held
+    (FIRST .. FIRST + 8 of ALL) — as routed (one held group empty) or
+    token by token as ``HELD[held]`` says — tiles past ``n_active``."""
     count, tile = 8, 8
     ks = jax.random.split(jax.random.PRNGKey(seed), 5)
-    x = jax.random.normal(ks[0], (64, GD)).astype(dtype)
-    bias = jnp.zeros((ALL,)).at[FIRST + 1].set(-1e3)
-    idx, weights = moe.route(x, jax.random.normal(ks[1], (GD, ALL)), bias,
-                             k, 2.5)
+    x = jax.random.normal(ks[0], (64, d)).astype(dtype)
+    if HELD[held] is None:
+        bias = jnp.zeros((ALL,)).at[FIRST + 1].set(-1e3)
+        idx, weights = moe.route(x, jax.random.normal(ks[1], (d, ALL)),
+                                 bias, k, 2.5)
+    else:
+        rng = np.random.default_rng(seed)
+        outside = [e for e in range(ALL) if not FIRST <= e < FIRST + count]
+        idx = jnp.asarray(np.stack([rng.permutation(np.concatenate([
+            FIRST + rng.choice(count, n, replace=False),
+            rng.choice(outside, k - n, replace=False)]))
+            for n in (HELD[held](t, k) for t in range(64))]), jnp.int32)
+        weights = 0.1 + jax.random.uniform(ks[1], (64, k))
     p = moe.plan(idx, FIRST, count, tile)
-    assert 0 < int(p.held.sum()) < p.held.size and int(p.count[1]) == 0
+    assert 0 < int(p.held.sum()) < p.held.size
     assert int(p.n_active) < p.tile_group.shape[0]
-    e = {n: (0.3 * jax.random.normal(kk, shape)).astype(dtype)
-         for n, kk, shape in (("gate", ks[2], (count, GD, F)),
-                              ("up", ks[3], (count, GD, F)),
-                              ("down", ks[4], (count, F, GD)))}
+    if held == "routed":
+        assert int(p.count[1]) == 0
+    # products of GD's size at every width
+    std = 0.3 * (GD / d) ** 0.5
+    e = {n: (std * jax.random.normal(kk, shape)).astype(dtype)
+         for n, kk, shape in (("gate", ks[2], (count, d, F)),
+                              ("up", ks[3], (count, d, F)),
+                              ("down", ks[4], (count, F, d)))}
     return x, weights, p, e
 
 
@@ -728,26 +759,50 @@ def assert_same(got, want, dtype, scaled=False):
                                    atol=1e-5 * np.max(np.abs(want)))
 
 
-@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
-                         ids=["bf16", "f32"])
-@pytest.mark.parametrize("k", [4, 6, 8])
-def test_the_dma_gathers_give_what_xla_gives(k, dtype):
+def assert_bits(got, want):
+    np.testing.assert_array_equal(np.asarray(got).view(np.uint8),
+                                  np.asarray(want).view(np.uint8))
+
+
+DMA_CASES = [pytest.param(k, dtype, GD, "routed", id=f"{k}-{name}")
+             for k in (4, 6, 8)
+             for dtype, name in ((jnp.bfloat16, "bf16"), (jnp.float32, "f32"))
+             ] + [pytest.param(k, dtype, d, held, id=f"{k}-{name}-{d}-{held}")
+                  for k, dtype, name, d, held in (
+                      (6, jnp.bfloat16, "bf16", 2048, "ragged"),
+                      (4, jnp.float32, "f32", 2048, "ragged"),
+                      (8, jnp.bfloat16, "bf16", 2560, "ragged"),
+                      (6, jnp.bfloat16, "bf16", 2048, "empty_tile"),
+                      (6, jnp.bfloat16, "bf16", 2048, "full_token"),
+                      (8, jnp.float32, "f32", 2560, "full_token"),
+                      (6, jnp.bfloat16, "bf16", 2048, "one_a_token"))]
+
+
+@pytest.mark.parametrize("k, dtype, d, held", DMA_CASES)
+def test_the_dma_gathers_give_what_xla_gives(k, dtype, d, held):
     """``row_gather`` is ``source[tok]`` on every row of an active tile
     (the rows past ``n_active`` are left unwritten — the interpreter
-    leaves NaN there); ``sum_gather`` is the XLA path's held sum, with
-    weights and without; and ``dispatch`` / ``combine`` around the
-    experts give the XLA path's values and gradients, so no reader
-    touches an unwritten row."""
-    x, weights, p, e = dma_case(k, dtype)
+    leaves NaN there); ``sum_gather`` is the XLA path's held sum bit for
+    bit, without weights and with weights whose products are exact (a
+    product that rounds may be fused into its add on one side only:
+    ``assert_same``); and ``dispatch`` / ``combine`` around the experts
+    give the XLA path's values and gradients, so no reader touches an
+    unwritten row."""
+    x, weights, p, e = dma_case(k, dtype, d=d, held=held)
     tile = moe._tile(p)
     live = int(p.n_active) * tile
     rows = row_gather(x, p.tok, p.n_active, tile)
     assert np.all(np.isfinite(np.asarray(rows[:live], np.float32)))
-    assert_same(rows[:live], x[p.tok][:live], dtype)
-    for w in (None, weights):
+    assert_bits(rows[:live], x[p.tok][:live])
+    exact = 2.0 ** -jax.random.randint(jax.random.PRNGKey(31), weights.shape,
+                                       0, 4).astype(jnp.float32)
+    for w in (None, exact, weights):
         got = sum_gather(rows, p.dest, p.held, p.n_active, w, tile)
-        assert_same(got, moe._sum_held("op", rows, p, 1 << 60, None, w),
-                    dtype)
+        want = moe._sum_held("op", rows, p, 1 << 60, None, w)
+        if w is weights:
+            assert_same(got, want, dtype)
+        else:
+            assert_bits(got, want)
 
     def loss(x, e, weights, free):
         rows = moe.dispatch(x, p, free)
@@ -762,6 +817,64 @@ def test_the_dma_gathers_give_what_xla_gives(k, dtype):
     for got, want in zip(dma, xla):
         assert np.all(np.isfinite(np.asarray(got, np.float32)))
         assert_same(got, want, dtype, scaled=True)
+
+
+def test_a_tiles_list_holds_its_held_rows_and_the_step_counts_them(
+        monkeypatch):
+    """``moves``: a tile's list is its held rows in the order ``(t, j)``,
+    each to its slot, its length ``held.sum()`` a tile and its fullest
+    token's count beside it.  And a train step whose expert layer moves
+    rows by DMA (here: every gather, VMEM taken as full) grows
+    ``moe.dma_rows{op}`` by that many rows a step — the lists' lengths
+    for the two sums, the active tiles' rows for the two row gathers."""
+    gm = sys.modules["byteps_tpu.ops.grouped_matmul"]
+    k, tile = 6, 8
+    _, _, p, _ = dma_case(k, jnp.float32, held="ragged")
+    held, dest = np.asarray(p.held), np.asarray(p.dest)
+    n = held.sum(axis=1)
+    order = np.argsort(~held, axis=1, kind="stable")      # held first
+    packed = np.where(np.arange(k) < n[:, None],
+                      np.take_along_axis(dest, order, axis=1), 0)
+    at, slot, size = (np.asarray(a) for a in gm.moves(
+        jnp.asarray(n, jnp.int32), k, tile))
+    tiles = held.reshape(-1, tile * k)
+    assert size[:, 0].tolist() == tiles.sum(axis=1).tolist()
+    assert size[:, 1].tolist() == n.reshape(-1, tile).max(axis=1).tolist()
+    assert any(length % 8 for length in size[:, 0])
+    rows = packed.reshape(-1, tile * k)
+    for i, length in enumerate(size[:, 0]):
+        want = [(packed[t, q], q * tile + t % tile)
+                for t in range(i * tile, (i + 1) * tile) for q in range(n[t])]
+        assert list(zip(rows[i][at[i, :length]], slot[i, :length])) == want
+
+    # one expert layer (the prediction module's) at a width whose rows
+    # move alone, over one tile of 256 tokens
+    cfg = dataclasses.replace(SMALL, d_model=128, max_seq_len=256,
+                              moe_experts=4, moe_top_k=2)
+    m, params = model_params(cfg, seed=3)
+    monkeypatch.setattr(moe, "VMEM_BYTES", 0)
+    tokens = jax.random.randint(jax.random.PRNGKey(4), (1, 256), 0, 128)
+    step = make_data_parallel_step(lm_loss_fn(m), optax.adamw(1e-3),
+                                   Mesh(np.array(jax.devices()[:1]), ("dp",)))
+    state = create_train_state(params, step.tx)
+    ops = ("dispatch", "combine", "dispatch_bwd", "combine_bwd")
+    reg = get_registry()
+    grown = []
+    for _ in range(2):
+        before = [reg.counter("moe.dma_rows", op=op).value for op in ops]
+        state, metrics = step(state, {"tokens": tokens})
+        flush_step_counts()
+        grown.append([reg.counter("moe.dma_rows", op=op).value - v
+                      for op, v in zip(ops, before)])
+        rows = int(metrics["moe_rows_computed"])
+        assert rows == int(metrics["moe_assignments_held"]) == 256 * 2
+        n_row = int(metrics["moe_dma_rows{op=dispatch}"])
+        assert n_row % 256 == 0 and rows <= n_row
+        assert grown[-1] == [n_row, rows, rows, n_row]
+        assert {name: int(v) for name, v in metrics.items()
+                if "dma_rows" in name} == dict(zip(
+                    (f"moe_dma_rows{{op={op}}}" for op in ops),
+                    grown[-1]))
 
 
 def _kernel_calls(text):

@@ -500,7 +500,8 @@ def test_the_ep_exchange_takes_the_non_gated_form():
         (_, (y2, c2)), g2 = jax.jit(jax.value_and_grad(
             sharded, has_aux=True))(w["experts"])
         want = ref.routed(x, w, SIZES, ref.router(x, w["router"], SIZES))
-    assert [int(c) for c in c1 + c2] == [x.shape[0] * K] * 4
+    assert [int(c) for c in (*c1.values(), *c2.values())] == [
+        x.shape[0] * K] * 4
     np.testing.assert_allclose(y1, want, rtol=1e-4, atol=1e-5)
     np.testing.assert_allclose(y2, y1, rtol=1e-4, atol=1e-5)
     assert set(g1) == {"up", "down"}

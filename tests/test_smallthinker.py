@@ -392,7 +392,8 @@ def test_the_ep_exchange_takes_the_new_rule_gate_and_router_input():
         (_, (y2, c2)), g2 = jax.jit(jax.value_and_grad(
             sharded, has_aux=True))(w["experts"])
         want = ref.routed(x, w, SIZES, ref.router(n1, w["router"], SIZES))
-    assert [int(c) for c in c1 + c2] == [x.shape[0] * K] * 4
+    assert [int(c) for c in (*c1.values(), *c2.values())] == [
+        x.shape[0] * K] * 4
     np.testing.assert_allclose(y1, want, rtol=1e-4, atol=1e-5)
     np.testing.assert_allclose(y2, y1, rtol=1e-4, atol=1e-5)
     for a, b in zip(jax.tree_util.tree_leaves(g2),
